@@ -182,7 +182,8 @@ def run(cache: Cache):
     for arch in E2E_ARCHS:
         c = cache.get_or(
             cache_key(f"jaxeng|e2e|ws-dev|{arch}|t{E2E_TENANTS}"
-                      f"|m{E2E_MSGS}", engine="jax"),
+                      f"|m{E2E_MSGS}", engine="jax",
+                      spec=_e2e_specs(arch, "jax")[0]),
             lambda arch=arch: _e2e_cell(arch))
         v, j = c["vectorized"], c["jax"]
         rows.append((

@@ -7,9 +7,10 @@ Row contract (benchmarks/run.py prints ``name,us_per_call,derived``):
   derived     - paper reference value + deviation, or the measured
                 secondary quantity
 
-Cache keys are versioned and carry the *engine name* plus a fingerprint
-of the fully-resolved :class:`SimParams` (defaults + overrides), so an
-engine switch or a simulator-default change can never silently serve
+Cache keys are versioned and carry the *engine name*, the execution
+path the cell resolves to (``RunResult.path``) and a fingerprint of the
+fully-resolved :class:`SimParams` (defaults + overrides), so an engine
+or path switch or a simulator-default change can never silently serve
 stale numbers.  Legacy-format keys (pre-``v2|``) make the cache fail
 loudly — see :class:`Cache`.
 """
@@ -65,6 +66,25 @@ def resolve_engine(engine: Optional[str] = None, spec=None) -> str:
     return eng
 
 
+def resolve_path(engine: str, spec=None, **params) -> str:
+    """Execution path (``RunResult.path``) of a cell on the resolved
+    ``engine``.  Only a jax cell has more than one: without its spec it
+    is the per-cohort path, and a cell requesting the whole-run device
+    program must pass its spec so the regime gate can be consulted."""
+    if engine != "jax":
+        return engine
+    if spec is None:
+        if params.get("jax_device_loop"):
+            raise ValueError("a jax cell requesting jax_device_loop needs "
+                             "its spec to resolve its execution path")
+        return "jax-cohort"
+    import dataclasses
+
+    from repro.core.campaign import resolved_path
+    return resolved_path(dataclasses.replace(
+        spec, params=dataclasses.replace(spec.params, engine=engine)))
+
+
 def params_fingerprint(engine: str, **params) -> str:
     """Short stable hash of the fully-resolved SimParams for a cell.
 
@@ -78,14 +98,17 @@ def params_fingerprint(engine: str, **params) -> str:
 
 def cache_key(name: str, engine: Optional[str] = None, spec=None,
               **params) -> str:
-    """Versioned cache key: ``v2|engine=<engine>|p=<fingerprint>|<name>``.
+    """Versioned cache key:
+    ``v2|engine=<engine>|path=<path>|p=<fingerprint>|<name>``.
 
     Use for every cell whose value depends on a simulator run; cells with
     no simulator dependence may use :func:`plain_key`.  Pass ``spec``
     (the cell's :class:`ExperimentSpec`) whenever it is known so the key
-    carries the *resolved* engine — see :func:`resolve_engine`."""
+    carries the *resolved* engine and path — see :func:`resolve_engine`
+    and :func:`resolve_path`."""
     eng = resolve_engine(engine, spec=spec)
-    return (f"{CACHE_KEY_VERSION}|engine={eng}|"
+    path = resolve_path(eng, spec, **params)
+    return (f"{CACHE_KEY_VERSION}|engine={eng}|path={path}|"
             f"p={params_fingerprint(eng, **params)}|{name}")
 
 
@@ -147,7 +170,7 @@ def sim_cell(cache: Cache, pattern: str, arch: str, workload: str,
                        engine=resolve_engine(engine), **params)
     eng = resolve_engine(engine, spec=rep)
     key = cache_key(f"{pattern}|{arch}|{workload}|{nc}|{msgs}|{n_runs}",
-                    engine=eng, **params)
+                    engine=eng, spec=rep, **params)
 
     def compute() -> dict:
         rs = run_pattern(pattern, arch, workload, nc, total_messages=msgs,

@@ -40,6 +40,7 @@ from benchmarks import (
     bench_roofline, bench_table1_workloads)
 from benchmarks import common
 from benchmarks.common import Cache, LegacyCacheError
+from repro.compat import enable_compile_cache
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "results")
 
@@ -240,7 +241,8 @@ def main() -> None:
                          "(default results/campaign_<name>.json)")
     ap.add_argument("--workers", type=int, default=None,
                     help="campaign process fan-out (default: one per "
-                         "CPU, capped by the group count)")
+                         "CPU, capped by the group count; a grid with "
+                         "jax cells runs in-process)")
     ap.add_argument("--check-artifacts", action="store_true",
                     help="validate results/BENCH_*.json against the "
                          "bench registry (stale artifacts, bare NaN) "
@@ -258,6 +260,7 @@ def main() -> None:
         ap.error("--campaign replaces the bench modules; drop the "
                  f"positional module argument {args.only!r}")
     common.DEFAULT_ENGINE = args.engine
+    enable_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     try:
         cache = Cache()
     except LegacyCacheError as e:

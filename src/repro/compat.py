@@ -1,73 +1,38 @@
-"""Version-compatibility shims over the installed JAX.
+"""Process-level JAX settings shared by the simulator and its entry points.
 
-The codebase targets the modern JAX surface (``jax.shard_map`` with
-``check_vma``, ``jax.sharding.AxisType``, ``jax.make_mesh(...,
-axis_types=...)``).  Older JAX releases (<= 0.4.x) expose the same
-functionality under different names/signatures:
-
-* ``jax.sharding.AxisType`` does not exist — meshes are implicitly
-  ``Auto``-typed, so the shim enum is accepted and dropped.
-* ``jax.make_mesh`` takes no ``axis_types`` keyword.
-* ``shard_map`` lives in ``jax.experimental.shard_map`` and spells the
-  replication check ``check_rep`` instead of ``check_vma``.
-
-Import the names from here instead of from ``jax`` directly; each resolves
-to the native implementation when the installed JAX has it.
+* :func:`scoped_x64` — the float64 context every StreamSim kernel and
+  device program runs under, leaving the process-global default at x32.
+* :func:`enable_compile_cache` — JAX's persistent compilation cache at a
+  stable path.  Only entry points (``chip_smoke.py``,
+  ``benchmarks/run.py``) call it; importing a library module never turns
+  the cache on.
 """
 
 from __future__ import annotations
 
-import enum
-from typing import Any, Optional, Sequence
+import os
+from typing import Any
 
-import jax
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-    _HAVE_AXIS_TYPE = True
-except ImportError:  # pragma: no cover - depends on installed jax
-    _HAVE_AXIS_TYPE = False
-
-    class AxisType(enum.Enum):
-        """Stand-in for ``jax.sharding.AxisType`` on older JAX.
-
-        Pre-AxisType meshes behave as ``Auto`` on every axis, which is the
-        only mode this repo requests, so carrying the value is enough."""
-
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
+#: directory name of the default compile cache, inside the checkout
+COMPILE_CACHE_DIRNAME = ".jax_cache"
 
 
-def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
-              axis_types: Optional[Sequence[Any]] = None,
-              **kw: Any) -> "jax.sharding.Mesh":
-    """``jax.make_mesh`` accepting ``axis_types`` on every JAX version."""
-    if _HAVE_AXIS_TYPE and axis_types is not None:
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                             axis_types=tuple(axis_types), **kw)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kw)
+def scoped_x64() -> Any:
+    """Context manager enabling float64 for the calls made inside it."""
+    import jax
+    return jax.enable_x64(True)
 
 
-def shard_map(f: Any = None, /, *, mesh: Any, in_specs: Any, out_specs: Any,
-              check_vma: Optional[bool] = None, **kw: Any) -> Any:
-    """``jax.shard_map`` with ``check_vma`` on any JAX version.
+def enable_compile_cache(checkout: str) -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
 
-    On older JAX this resolves to ``jax.experimental.shard_map.shard_map``
-    and translates ``check_vma`` to ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        if f is None:
-            return lambda g: jax.shard_map(g, mesh=mesh, in_specs=in_specs,
-                                           out_specs=out_specs, **kw)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    if f is None:
-        return lambda g: _sm(g, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the cache and nothing
+    else is configured (JAX reads the variable itself).  Otherwise the
+    cache lives at ``<checkout>/.jax_cache``: a fixed path, because the
+    path is part of what a later run must find again."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(os.path.abspath(checkout), COMPILE_CACHE_DIRNAME)
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

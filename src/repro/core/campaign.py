@@ -14,7 +14,9 @@ cell-at-a-time orchestration.  This module executes whole grids as
   cohort lanes of **one** batched engine run (a 3-seed cell costs barely
   more than one run; see ``docs/engines.md``);
 * heterogeneous groups fan out across a small process pool
-  (``workers``), largest first;
+  (``workers``), largest first — except on a grid with any jax cell,
+  which runs in the calling process: that process holds the
+  accelerator, and a forked child could not reach it;
 * every finished group is written through a fingerprinted cache (a
   ``benchmarks.common.Cache``-compatible object: a ``data`` dict plus
   ``save()``), so an interrupted campaign resumes where it stopped and
@@ -67,23 +69,38 @@ def params_fingerprint(params: SimParams) -> str:
     return hashlib.sha1(blob.encode()).hexdigest()[:12]
 
 
-def resolved_engine(spec: ExperimentSpec) -> str:
-    """The engine a spec will *actually* run on, after the ``run_many``
-    fallback: a requested ``"jax"`` cell that ``jax_supported`` rejects
-    executes on the vectorized engine, and must be cached as such.
+def resolved_path(spec: ExperimentSpec) -> str:
+    """The execution path a spec will *actually* take — the
+    ``RunResult.path`` its run records: ``"heap"``, ``"vectorized"``,
+    ``"jax-cohort"`` or ``"jax-device"``.  A requested ``"jax"`` cell
+    that ``jax_supported`` rejects executes on the vectorized engine
+    (the ``run_many`` fallback); one that requests the whole-run device
+    program but fails its regime gate runs the per-cohort jax kernels.
 
     Every cache key MUST be built from this, never from the requested
     ``spec.params.engine`` — keying a fallback cell under ``jax`` both
     poisons the jax namespace (a later run in a jax-capable environment
     is served vectorized numbers) and forks it from the identical
-    vectorized cell (same computation measured twice)."""
+    vectorized cell (same computation measured twice); keying the two
+    jax paths alike would serve one path's numbers as the other's."""
     eng = spec.params.engine
-    if eng == "jax":
-        from repro.core import jax_engine
-        ok, _why = jax_engine.jax_supported(spec)
-        if not ok:
-            return "vectorized"
-    return eng
+    if eng != "jax":
+        return eng
+    from repro.core import jax_engine
+    if not jax_engine.jax_supported(spec)[0]:
+        return "vectorized"
+    if spec.params.jax_device_loop:
+        from repro.core import jax_device_loop
+        if jax_device_loop.device_loop_supported(spec)[0]:
+            return "jax-device"
+    return "jax-cohort"
+
+
+def resolved_engine(spec: ExperimentSpec) -> str:
+    """The engine of :func:`resolved_path` (``"jax"`` for both jax
+    paths)."""
+    path = resolved_path(spec)
+    return "jax" if path.startswith("jax-") else path
 
 
 # ---------------------------------------------------------------------------
@@ -262,17 +279,19 @@ def cell_key(cell: CellSpec) -> str:
     Fingerprints the *fully-resolved* experiment params, including
     pattern-implied defaults like the broadcast-gather reply factor.
 
-    Keys on the :func:`resolved_engine`, not the requested one: a jax
+    Keys on the :func:`resolved_path`, not the requested engine: a jax
     cell that falls back to vectorized shares its key (tag *and*
     fingerprint) with the identical genuine-vectorized cell — it ran
-    the same computation — and never occupies the jax namespace."""
+    the same computation — and never occupies the jax namespace, and
+    the device program never shares a row with the per-cohort path."""
     exp = cell.experiment()
     p = exp.params
-    eng = resolved_engine(exp)
-    if eng != p.engine:
-        p = dataclasses.replace(p, engine=eng)
+    path = resolved_path(exp)
+    if path == "vectorized" and p.engine != path:
+        p = dataclasses.replace(p, engine=path)
     fp = params_fingerprint(p)
-    return (f"{CACHE_KEY_VERSION}|engine={eng}|p={fp}|campaign|"
+    return (f"{CACHE_KEY_VERSION}|engine={p.engine}|path={path}|p={fp}|"
+            f"campaign|"
             f"{cell.pattern}|{cell.arch}|{cell.workload}|"
             f"c{cell.n_consumers}|m{cell.total_messages}|"
             f"t{cell.tenants}.{cell.tenant_isolation}|s{cell.seed}")
@@ -335,15 +354,26 @@ def run_campaign(spec: CampaignSpec, *, cache: Optional[Any] = None,
     pilot lane, so a cached cell's numbers never depend on which cells
     happened to be computed before an interruption.  ``workers`` bounds
     the process fan-out across cell groups (``0``/``1`` = in-process;
-    ``None`` = one per CPU, capped by the group count).  Seeds within a
-    group never fan out — they run stacked in one engine loop, which is
-    where the batching win comes from."""
+    ``None`` = one per CPU, capped by the group count).  A grid with any
+    ``engine="jax"`` cell always runs in-process — one process per
+    accelerator — and an explicit ``workers > 1`` on it is a
+    ``ValueError``.  Seeds within a group never fan out — they run
+    stacked in one engine loop, which is where the batching win comes
+    from."""
     # streamlint: disable=SL403 -- wall_s is campaign telemetry (how long
     # the run took), reported alongside results, never fed into them
     t0 = time.time()
     cells = spec.cells()
-    for c in cells:
-        c.experiment()   # validate the whole grid before burning time
+    # validate the whole grid before burning time
+    on_jax = any(c.experiment().params.engine == "jax" for c in cells)
+    if on_jax:
+        if workers is not None and workers > 1:
+            raise ValueError(
+                f"campaign {spec.name!r} has engine='jax' cells, which "
+                f"run in the process that holds the accelerator; "
+                f"workers={workers} would fork children that cannot "
+                f"reach it.  Use workers=0/1 or None.")
+        workers = 1
     say = progress or (lambda msg: None)
     summaries: dict[int, Summary] = {}
     n_cached = 0

@@ -44,12 +44,12 @@ plain Python generation loop over the *same* step function).  The NumPy
 backend is the step-for-step oracle: ``tests/test_flow_control_props.py``
 property-tests that both backends produce the same per-generation trace.
 
-**Pallas.**  The hottest fused step — the pump window assignment
-(round-robin consumer pick + prefetch-ring gate + depart clamp) — has a
-Pallas TPU kernel (:func:`_pump_assign_pallas`) behind a
-:func:`pallas_enabled` capability gate with the XLA closed form as
-fallback; on CPU hosts the kernel is exercised in interpreter mode by
-the test suite (``REPRO_PALLAS=interpret``).
+**One pump.**  The pump window assignment (round-robin consumer pick +
+prefetch-ring gate + depart clamp) is the XLA closed form
+:func:`_pump_assign` on every backend.  The clocks are float64 by
+contract (scoped x64, see :mod:`repro.core.jax_engine`), and Mosaic
+compiles no 64-bit operands, so a Pallas port cannot compile for a TPU
+while that contract stands.
 
 Pad-and-mask: member axes pad to the next power of two with invalid
 members carrying ``+inf`` clocks, zero holds and dummy carry chains —
@@ -60,7 +60,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 from typing import Any, Callable, Optional
 
 import numpy as np
@@ -77,30 +76,8 @@ _IBIG = np.int64(2) ** 40
 
 
 # ---------------------------------------------------------------------------
-# Capability gates
+# Regime gate
 # ---------------------------------------------------------------------------
-
-
-def pallas_enabled() -> str:
-    """Capability gate for the Pallas pump-assignment kernel.
-
-    Returns ``"compiled"`` on a TPU backend, ``"interpret"`` when forced
-    via ``REPRO_PALLAS=interpret`` (CPU CI exercises the kernel this
-    way), and ``""`` (use the XLA fallback) otherwise."""
-    mode = os.environ.get("REPRO_PALLAS", "")
-    try:
-        import jax
-        from jax.experimental import pallas as pl  # noqa: F401
-    except Exception:
-        return ""
-    if mode == "interpret":
-        return "interpret"
-    try:
-        if jax.default_backend() == "tpu":
-            return "compiled"
-    except Exception:
-        return ""
-    return ""
 
 
 def device_loop_supported(spec: ExperimentSpec) -> tuple[bool, str]:
@@ -110,14 +87,19 @@ def device_loop_supported(spec: ExperimentSpec) -> tuple[bool, str]:
     flow-event-free regime (no credit blocking / reject-publish
     reachable — the wave program carries no retry machinery), and a
     generation size that keeps every consumer under half its prefetch
-    per generation.  Unsupported cells silently use the ordinary
-    per-cohort jax path; this is a *request*, not a demand."""
+    per generation.  Unsupported cells use the ordinary per-cohort jax
+    path (``RunResult.path`` says which ran); this is a *request*, not a
+    demand."""
     from repro.core.jax_engine import jax_available
+    from repro.core.simulator import InfeasibleConfiguration
     if not jax_available():
         return False, "jax is not importable in this environment"
     if spec.pattern not in ("work_sharing", "feedback"):
         return False, f"pattern {spec.pattern!r} is not wave-formulated"
-    sim = VectorizedStreamSim(spec)
+    try:
+        sim = VectorizedStreamSim(spec)
+    except InfeasibleConfiguration as e:
+        return False, f"infeasible deployment: {e}"
     return _device_loop_ok(sim)
 
 
@@ -638,6 +620,10 @@ class _NumpyOps:
         return np.lexsort(keys)
 
     @staticmethod
+    def cumsum(x: np.ndarray) -> np.ndarray:
+        return np.cumsum(x, axis=0)
+
+    @staticmethod
     def cummax(x: np.ndarray) -> np.ndarray:
         return np.maximum.accumulate(x, axis=0)
 
@@ -684,9 +670,16 @@ def _jax_ops() -> Any:
         def lexsort(keys: tuple):
             return jnp.lexsort(keys)
 
+        # associative scans, not jnp.cumsum / lax.cummax: those lower to
+        # a reduce-window whose 64-bit emulation the TPU compiler takes
+        # a minute to compile at 128 rows
+        @staticmethod
+        def cumsum(x):
+            return lax.associative_scan(jnp.add, x)
+
         @staticmethod
         def cummax(x):
-            return lax.cummax(x, axis=0)
+            return lax.associative_scan(jnp.maximum, x)
 
         @staticmethod
         def seg_cummax(x, start):
@@ -780,7 +773,7 @@ def _serve_leg(ops: Any, free: Array, a: Array, hold: Array,
     carry = free[chain2]
     a_eff = xp.where(res2[:, None], xp.maximum(a2, carry), a2)
     # segmented FIFO closed form: e = H + segcummax(a - (H - h))
-    c = xp.cumsum(h2, axis=0)
+    c = ops.cumsum(h2)
     basefill = ops.cummax(xp.where(start2[:, None], c - h2, -_INF))
     Hs = c - basefill
     e2 = Hs + ops.seg_cummax(a_eff - (Hs - h2), start2)
@@ -794,17 +787,22 @@ def _transit(ops: Any, free: Array, t: Array, slots: Array, jit: Array,
              valid: Array, side: Array, meta: dict, chain_base: Array,
              k_arr: Array) -> tuple:
     """Walk members through an aligned path: ``slots`` is
-    ``(S, Np, 4)`` rows of (kind, rid, hold_base, lat)."""
+    ``(S, Np, 4)`` rows of (kind, rid, hold_base, lat).  The slots run
+    as a scan, so the device program holds one copy of the serve."""
     xp = ops.xp
-    S = slots.shape[0]
-    for s in range(S):
-        kind = slots[s, :, 0].astype(xp.int64)
-        rid = slots[s, :, 1].astype(xp.int64)
+
+    def slot(carry: tuple, x: dict) -> tuple:
+        free, t = carry
+        row = x["row"]
+        kind = row[:, 0].astype(xp.int64)
+        rid = row[:, 1].astype(xp.int64)
         hold = xp.where((kind > 0) & valid,
-                        slots[s, :, 2], 0.0)[:, None] * (1.0 + jit[s])
-        free, t = _serve_leg(ops, free, t, hold, kind, rid,
-                             slots[s, :, 3], valid, side, meta,
-                             chain_base, k_arr)
+                        row[:, 2], 0.0)[:, None] * (1.0 + x["jit"])
+        return _serve_leg(ops, free, t, hold, kind, rid, row[:, 3],
+                          valid, side, meta, chain_base, k_arr), {}
+
+    (free, t), _ = ops.scan(slot, (free, t), {"row": slots, "jit": jit},
+                            slots.shape[0])
     return free, t
 
 
@@ -820,11 +818,10 @@ def _next_boundary(ops: Any, boundary: Array, start: Array,
     return nb_rev[::-1]
 
 
-def _pump_assign_xla(ops: Any, ring: Array, t_ready: Array, gid: Array,
-                     base_cnt: Array, idx_on: Array, valid: Array,
-                     meta: dict) -> Array:
-    """XLA fallback for the pump window assignment: gate each message on
-    the prefetch ring of its assigned consumer and clamp the depart.
+def _pump_assign(ops: Any, ring: Array, t_ready: Array, gid: Array,
+                 idx_on: Array, valid: Array, meta: dict) -> Array:
+    """The pump window assignment: gate each message on the prefetch
+    ring of its assigned consumer and clamp the depart.
     ``gid``: consumer id per member; ``idx_on``: the message's index in
     its consumer's total delivery order."""
     xp = ops.xp
@@ -832,36 +829,6 @@ def _pump_assign_xla(ops: Any, ring: Array, t_ready: Array, gid: Array,
     gate = ring[gid, idx_on % P]
     gate = xp.where((idx_on >= P)[:, None] & valid[:, None], gate, 0.0)
     return xp.maximum(t_ready, gate)
-
-
-def _pump_assign_pallas(ring: Array, t_ready: Array, gid: Array,
-                        idx_on: Array, valid: Array, P: int,
-                        interpret: bool) -> Array:
-    """Pallas port of the pump window assignment (single-block kernel,
-    in-kernel ``fori_loop`` over members, VMEM-resident prefetch ring).
-    Semantically identical to :func:`_pump_assign_xla`."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    Np, L = t_ready.shape
-
-    def kernel(ring_ref, t_ref, gid_ref, idx_ref, valid_ref, out_ref):
-        def body(m, _):
-            gidm = gid_ref[m]
-            idxm = idx_ref[m]
-            gate = ring_ref[gidm, idxm % P]
-            use = (idxm >= P) & valid_ref[m]
-            gate = jnp.where(use, gate, jnp.zeros_like(gate))
-            out_ref[m, :] = jnp.maximum(t_ref[m, :], gate)
-            return 0
-        jax.lax.fori_loop(0, Np, body, 0)
-
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((Np, L), t_ready.dtype),
-        interpret=interpret,
-    )(ring, t_ready, gid, idx_on, valid)
 
 
 def _seg_pos(ops: Any, key_sorted: Array, Np: int) -> tuple:
@@ -875,8 +842,8 @@ def _seg_pos(ops: Any, key_sorted: Array, Np: int) -> tuple:
     return start, pos
 
 
-def _wave_step(ops: Any, meta: dict, inv: dict, carry: dict, x: dict,
-               pump: Callable) -> tuple[dict, dict]:
+def _wave_step(ops: Any, meta: dict, inv: dict, carry: dict,
+               x: dict) -> tuple[dict, dict]:
     """One *pipelined* scan step.
 
     Step ``g`` publishes generation ``g``, delivers ``g-1``,
@@ -905,7 +872,7 @@ def _wave_step(ops: Any, meta: dict, inv: dict, carry: dict, x: dict,
     pub_start = xp.where(v_pub[:, None], gate + flush, _INF)
 
     # delivery(g-1): pump window assignment — per-queue arrival-order
-    # round robin with prefetch-ring gates (the Pallas-ported step)
+    # round robin with prefetch-ring gates
     v_del, q = x["del_valid"], x["del_q"]
     dlyp = x["dlyp"]
     t_enq_prev = carry["pend_pub"]["t_enq"][dlyp]
@@ -919,8 +886,8 @@ def _wave_step(ops: Any, meta: dict, inv: dict, carry: dict, x: dict,
               + posq) % kqv
     cons_s = inv["q_cons_tab"][xp.clip(q_s, 0, meta["nq"] - 1), slot_c]
     idx_on_c = x["del_cnt_c"][cons_s] + posq // kqv
-    depart_s = pump(carry["ack"], t_enq_prev[oq], cons_s, idx_on_c,
-                    v_del[oq], meta)
+    depart_s = _pump_assign(ops, carry["ack"], t_enq_prev[oq], cons_s,
+                            idx_on_c, v_del[oq], meta)
     cons = ops.at_set(xp.zeros(Np, dtype=cons_s.dtype), oq, cons_s)
     idxc = ops.at_set(xp.zeros(Np, dtype=idx_on_c.dtype), oq, idx_on_c)
     slotc = ops.at_set(xp.zeros(Np, dtype=slot_c.dtype), oq, slot_c)
@@ -980,7 +947,7 @@ def _wave_step(ops: Any, meta: dict, inv: dict, carry: dict, x: dict,
     a_eff = xp.where((ch_s < nC)[:, None],
                      xp.maximum(a[oc], carry_pf), a[oc])
     h_s = h[oc]
-    c = xp.cumsum(h_s, axis=0)
+    c = ops.cumsum(h_s)
     basefill = ops.cummax(xp.where(start_c[:, None], c - h_s, -_INF))
     Hs = c - basefill
     seen_s = Hs + ops.seg_cummax(a_eff - (Hs - h_s), start_c)
@@ -1015,8 +982,8 @@ def _wave_step(ops: Any, meta: dict, inv: dict, carry: dict, x: dict,
     pr_s = pch[opr]
     _, posp = _seg_pos(ops, pr_s, Np)
     idx_on_p = x["rp_cnt_p"][pr_s] + posp
-    rdep_s = pump(carry["prep"], t_renq[opr], pr_s, idx_on_p,
-                  v_rp[opr], meta)
+    rdep_s = _pump_assign(ops, carry["prep"], t_renq[opr], pr_s,
+                          idx_on_p, v_rp[opr], meta)
     rdep = ops.at_set(xp.zeros_like(t_renq), opr, rdep_s)
     rdep = xp.where(v_rp[:, None], rdep, _INF)
     idxp = ops.at_set(xp.zeros(Np, dtype=idx_on_p.dtype), opr, idx_on_p)
@@ -1080,11 +1047,8 @@ def run_wave_trace(ws: WaveStatic, jitter: dict,
         ops: Any = _NumpyOps
         inv = ws.inv
         xs = dict(ws.xs, **jitter)
-
-        def pump(ring, t, gid, idxo, v, m):
-            return _pump_assign_xla(ops, ring, t, gid, None, idxo, v, m)
         _, ys = ops.scan(
-            lambda c, x: _wave_step(ops, meta, inv, c, x, pump),
+            lambda c, x: _wave_step(ops, meta, inv, c, x),
             _init_carry(np, meta), xs, meta["nSteps"])
         return ys
     return _run_jax(ws, jitter)
@@ -1094,41 +1058,35 @@ def run_wave_trace(ws: WaveStatic, jitter: dict,
 def _compiled_program(sig: tuple, meta_items: tuple, n_cells: bool
                       ) -> Callable:
     """Jit (once per static signature / shape bucket) the whole-run
-    program; ``n_cells`` selects the vmap-over-cells variant."""
+    program; ``n_cells`` selects the vmap-over-cells variant.  Call it
+    through :func:`_run_program` (scoped x64)."""
     import jax
-    from jax.experimental import enable_x64
 
     ops = _jax_ops()
     meta = dict(meta_items)
     meta["pools"] = tuple(meta["pools"])
-    mode = pallas_enabled()
-
-    def pump(ring, t, gid, idxo, v, m):
-        if mode:
-            return _pump_assign_pallas(ring, t, gid, idxo, v, m["P"],
-                                       interpret=(mode == "interpret"))
-        return _pump_assign_xla(ops, ring, t, gid, None, idxo, v, m)
 
     def program(xs: dict, inv: dict, jitter: dict) -> dict:
         xs = dict(xs, **jitter)
         _, ys = ops.scan(
-            lambda c, x: _wave_step(ops, meta, inv, c, x, pump),
+            lambda c, x: _wave_step(ops, meta, inv, c, x),
             _init_carry(ops.xp, meta), xs, meta["nSteps"])
         return ys
 
-    fn = jax.vmap(program) if n_cells else program
-    jfn = jax.jit(fn)
+    return jax.jit(jax.vmap(program) if n_cells else program)
 
-    def call(*args: Any) -> Any:
-        with enable_x64():
-            return jfn(*args)
-    return call
+
+def _run_program(fn: Callable, *args: Any) -> dict:
+    """Run a compiled program under scoped x64; host NumPy outputs."""
+    from repro.compat import scoped_x64
+    with scoped_x64():
+        out = fn(*args)
+    return {k: np.asarray(v) for k, v in out.items()}
 
 
 def _run_jax(ws: WaveStatic, jitter: dict) -> dict:
     fn = _compiled_program(ws.signature(), _meta_key(ws.meta), False)
-    out = fn(ws.xs, ws.inv, jitter)
-    return {k: np.asarray(v) for k, v in out.items()}
+    return _run_program(fn, ws.xs, ws.inv, jitter)
 
 
 def _meta_key(meta: dict) -> tuple:
@@ -1173,10 +1131,10 @@ def _assemble(sim: VectorizedStreamSim, ws: WaveStatic,
         lane_spec = dataclasses.replace(
             sim.spec, params=dataclasses.replace(sim.p, seed=seed))
         sel = (slice(None),) if L == 1 else (slice(None), lane)
-        results.append(sim._result(
-            lane_spec, consume_t[sel],
-            rtts[sel] if rtts is not None else None,
-            pub[sel], lane=lane))
+        r = sim._result(lane_spec, consume_t[sel],
+                        rtts[sel] if rtts is not None else None,
+                        pub[sel], lane=lane)
+        results.append(dataclasses.replace(r, path="jax-device"))
     return results
 
 
@@ -1217,9 +1175,8 @@ def run_wave_cells(sims: list) -> list:
                for k in ws0.inv}
         draws = [draw_jitter(built[i][0], built[i][1]) for i in cells]
         jit = {k: np.stack([d[k] for d in draws]) for k in draws[0]}
-        fn = _compiled_program(sig, _meta_key(ws0.meta), True)
-        ys = fn(xs, inv, jit)
-        ys = {k: np.asarray(v) for k, v in ys.items()}
+        ys = _run_program(_compiled_program(sig, _meta_key(ws0.meta), True),
+                          xs, inv, jit)
         for c, i in enumerate(idxs):
             sim, ws = built[i]
             out[i] = _assemble(sim, ws, {k: v[c] for k, v in ys.items()})

@@ -5,8 +5,8 @@
 loop, the hop-graph resolution, the publish/deliver choreography — from
 :class:`~repro.core.vectorized.VectorizedStreamSim`:
 
-* **prefix-scan FIFO** — ``_fifo_scan`` becomes ``jnp.cumsum`` +
-  ``lax.cummax``, ``jax.vmap``-ed over the trailing lane axis, so one
+* **prefix-scan FIFO** — ``_fifo_scan`` becomes two
+  ``lax.associative_scan`` prefix scans (sum, max), ``jax.vmap``-ed over the trailing lane axis, so one
   device program serves every stacked seed-lane of a resource batch;
 * **cohort admission** — the per-message arrival-order admission walk
   (byte-cap rejects, credit-threshold crossings, high-water marks)
@@ -32,8 +32,9 @@ invariance.
 **x64 is forced, scoped.**  Time arithmetic must match the float64
 NumPy engines (under f32 a 1e-4 s service hold vanishes against a 1e3 s
 clock), but this repo's model/kernel stack runs JAX at default x32 —
-so every engine kernel runs under the ``jax.experimental.enable_x64``
-context instead of flipping the global flag.
+so every engine kernel runs under :func:`repro.compat.scoped_x64`
+(``jax.enable_x64(True)`` as a context) instead of flipping the global
+flag.  On a TPU, which has no native float64, XLA emulates it.
 
 The module imports without JAX installed; only constructing
 :class:`JaxStreamSim` (or calling a kernel) requires it.
@@ -53,10 +54,12 @@ from repro.core.vectorized import VectorizedStreamSim
 
 
 def jax_available() -> bool:
-    """True when ``import jax`` succeeds in this environment."""
+    """True when jax is installed.  Only a missing package reads as
+    unavailable: an installed jax that fails to import raises, rather
+    than turning jax cells into quiet vectorized runs."""
     try:
         import jax  # noqa: F401
-    except Exception:
+    except ImportError:
         return False
     return True
 
@@ -98,22 +101,26 @@ def _kernels() -> Any:
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from jax.experimental import enable_x64
+
+    from repro.compat import scoped_x64
 
     def x64(fn: Callable[..., Any]) -> Callable[..., Any]:
         jfn = jax.jit(fn)
 
         @functools.wraps(fn)
         def call(*args: Any) -> Any:
-            with enable_x64():
+            with scoped_x64():
                 return jfn(*args)
         return call
 
     def fifo1(a: Array, h: Array, carry: Array) -> Array:
         # e_j = max(a_j, e_{j-1}) + h_j in closed form (see _fifo_scan)
+        # associative scans, not jnp.cumsum / lax.cummax: those lower to
+        # a reduce-window whose 64-bit emulation the TPU compiler takes
+        # a minute to compile at 128 rows
         a = jnp.maximum(a, carry)
-        H = jnp.cumsum(h)
-        return H + lax.cummax(a - (H - h), axis=0)
+        H = lax.associative_scan(jnp.add, h)
+        return H + lax.associative_scan(jnp.maximum, a - (H - h))
 
     class K:
         fifo_scan_1d = x64(fifo1)
@@ -318,6 +325,8 @@ class JaxStreamSim(VectorizedStreamSim):
     #: groups stack 4x more seed-lanes per run than the NumPy engine
     STACK_MAX_LANES = 64
 
+    PATH = "jax-cohort"
+
     _scan_impl = staticmethod(_jax_fifo_scan)
 
     def __init__(self, *args: Any, **kwargs: Any) -> None:
@@ -333,6 +342,12 @@ class JaxStreamSim(VectorizedStreamSim):
                 "engine='vectorized' (run_many falls back automatically)")
         self._K = _kernels()
         super().__init__(*args, **kwargs)
+        #: why this cell does not run on the whole-run device program
+        #: ("" when it does); results record the path as ``RunResult.path``
+        self.device_loop_why = "jax_device_loop not requested"
+        if self.p.jax_device_loop:
+            from repro.core import jax_device_loop
+            self.device_loop_why = jax_device_loop._device_loop_ok(self)[1]
 
     # -- whole-run device program (opt-in; repro.core.jax_device_loop) -----
     def _use_device_loop(self) -> bool:
@@ -341,11 +356,7 @@ class JaxStreamSim(VectorizedStreamSim):
         default: the device loop trades the cohort engines' event
         ordering for one fused ``lax.scan``, so it matches them at the
         ``device_loop.*`` parity bands instead of bit-for-bit."""
-        if not self.p.jax_device_loop:
-            return False
-        from repro.core import jax_device_loop
-        ok, _why = jax_device_loop._device_loop_ok(self)
-        return ok
+        return not self.device_loop_why
 
     def run(self) -> RunResult:
         if self._use_device_loop():

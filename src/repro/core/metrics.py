@@ -41,6 +41,9 @@ class Summary:
     #: without jax installed runs on "vectorized"); "" when the result
     #: predates the field
     engine: str = ""
+    #: the execution path that produced the result (``RunResult.path``):
+    #: tells the jax device program apart from the per-cohort jax path
+    path: str = ""
 
 
 def throughput_msgs_per_s(result: RunResult, warmup_frac: float = 0.05) -> float:
@@ -67,7 +70,7 @@ def summarize(result: RunResult) -> Summary:
                 blocked=result.blocked_confirms,
                 n_messages=result.n_consumed,
                 tenants=spec.tenants,
-                engine=spec.params.engine)
+                engine=spec.params.engine, path=result.path)
     if not result.feasible:
         return s
     thr = throughput_msgs_per_s(result)

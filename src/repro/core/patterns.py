@@ -789,7 +789,8 @@ def average_summaries(ss: Sequence[Summary]) -> Summary:
     out.rejected = float(np.mean([s.rejected for s in feas]))
     out.blocked = float(np.mean([s.blocked for s in feas]))
     out.n_messages = int(np.mean([s.n_messages for s in feas]))
-    # surface a mixed-engine mean (e.g. some seeds fell back from jax)
-    engines = sorted({s.engine for s in feas if s.engine})
-    out.engine = engines[0] if len(engines) == 1 else "+".join(engines)
+    # surface a mixed-engine/-path mean (e.g. some seeds fell back)
+    for f in ("engine", "path"):
+        vals = sorted({getattr(s, f) for s in feas if getattr(s, f)})
+        setattr(out, f, "+".join(vals))
     return out

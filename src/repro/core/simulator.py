@@ -219,6 +219,11 @@ class RunResult:
         default_factory=lambda: np.zeros(0, dtype=np.int64))
     rtt_producers: np.ndarray = dataclasses.field(
         default_factory=lambda: np.zeros(0, dtype=np.int64))
+    #: the execution path that produced the result: ``"heap"``,
+    #: ``"vectorized"``, ``"jax-cohort"`` (the per-cohort jax kernels)
+    #: or ``"jax-device"`` (the whole-run device program); ``""`` for an
+    #: infeasible cell, which ran nothing
+    path: str = ""
 
     @property
     def n_consumed(self) -> int:
@@ -877,7 +882,8 @@ class StreamSim:
             sim_time=self.now, n_events=self.n_events,
             consume_producers=np.asarray(self.consume_producers,
                                          dtype=np.int64),
-            rtt_producers=np.asarray(self.rtt_producers, dtype=np.int64))
+            rtt_producers=np.asarray(self.rtt_producers, dtype=np.int64),
+            path="heap")
 
 
 ENGINES["heap"] = StreamSim

@@ -258,6 +258,9 @@ class VectorizedStreamSim:
     #: (repro.core.jax_engine) swap in their own port
     _scan_impl = staticmethod(_fifo_scan)
 
+    #: ``RunResult.path`` of the results this engine's event loop makes
+    PATH = "vectorized"
+
     def __init__(self, spec: ExperimentSpec,
                  inventory: Optional[ClusterInventory] = None,
                  arch: Optional[Architecture] = None,
@@ -2272,7 +2275,7 @@ class VectorizedStreamSim:
             blocked_confirms=int(self.blocked[lane]),
             redelivered=int(self._redelivered),
             sim_time=top, n_events=self.n_events,
-            consume_producers=cp, rtt_producers=rp)
+            consume_producers=cp, rtt_producers=rp, path=self.PATH)
 
 
 ENGINES["vectorized"] = VectorizedStreamSim

@@ -12,19 +12,19 @@ Topology (TPU v5e pods):
 
 from __future__ import annotations
 
-from jax.sharding import Mesh
-
-from repro.compat import AxisType, make_mesh
+import jax
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(data: int = 1, model: int = 1) -> Mesh:
     """Small mesh over however many (host) devices exist — smoke tests and
     the subprocess multi-device tests."""
-    return make_mesh((data, model), ("data", "model"),
-                     axis_types=(AxisType.Auto, AxisType.Auto))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))

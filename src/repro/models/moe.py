@@ -176,8 +176,7 @@ def moe_ep(x: jax.Array, params: dict, k: int, n_experts: int,
                         tokens_replicated=replicated)
         return out.reshape(xb.shape)
 
-    from repro.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(None, None), P("model", None, None),
                   P("model", None, None)),

@@ -240,8 +240,7 @@ def mlstm_seq_parallel(q, k, v, i_gate, f_gate, *, mesh, batch_axes,
                                init_state=(inC, inN))
         return out
 
-    from repro.compat import shard_map
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(io_spec, io_spec, io_spec, g_spec, g_spec),
         out_specs=io_spec, check_vma=False)(q, k, v, i_gate, f_gate)

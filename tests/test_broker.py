@@ -1,7 +1,8 @@
 """Broker semantics: the RabbitMQ behaviors the paper's evaluation relies
 on (§4.2/§5.2)."""
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.broker import BrokerCluster, Message
 

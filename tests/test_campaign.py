@@ -389,6 +389,32 @@ def test_campaign_fallback_counted_and_warned(monkeypatch):
     assert all("engine=vectorized" in c["key"] for c in blob["cells"])
 
 
+def test_campaign_jax_grid_runs_in_process(monkeypatch):
+    """A grid with jax cells never forks: the process that holds the
+    accelerator runs it, and an explicit ``workers > 1`` is refused.
+    Vectorized grids keep their process pool."""
+    import concurrent.futures
+
+    pools = []
+
+    class Pool(concurrent.futures.ThreadPoolExecutor):
+        def __init__(self, max_workers=None):
+            pools.append(max_workers)
+            super().__init__(max_workers=max_workers)
+
+    monkeypatch.setattr(concurrent.futures, "ProcessPoolExecutor", Pool)
+    grid = dict(patterns=("work_sharing",), architectures=("dts", "mss"),
+                consumers=(2,), n_runs=1, total_messages=64)
+    jax_grid = CampaignSpec(name="j", params={"engine": "jax"}, **grid)
+    with pytest.raises(ValueError, match="workers=2"):
+        run_campaign(jax_grid, workers=2)
+    res = run_campaign(jax_grid)            # workers=None: in-process
+    assert pools == []
+    assert all(s.engine == "jax" for s in res.summaries)
+    run_campaign(CampaignSpec(name="v", **grid), workers=2)
+    assert pools == [2]
+
+
 def test_campaign_no_fallback_no_warning():
     spec = CampaignSpec(name="t", patterns=("work_sharing",),
                         consumers=(2,), n_runs=1, total_messages=64)

@@ -184,7 +184,8 @@ def test_mini_multipod_dryrun_compiles():
     proves the pod axis shards end-to-end inside CI."""
     run_sub("""
         import dataclasses
-        from repro.compat import AxisType, make_mesh
+        from jax import make_mesh
+        from jax.sharding import AxisType
         from repro.configs import get_smoke_config
         from repro.launch.shardings import assemble, opt_state_shardings
         from repro.launch.steps import build_train_step

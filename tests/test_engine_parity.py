@@ -15,8 +15,8 @@ import functools
 
 import numpy as np
 import pytest
-
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.metrics import overhead_vs_baseline, summarize
 from repro.core.parity import band, factor_band

@@ -1,6 +1,5 @@
-"""Property tests for lane-resolved flow control in the vectorized
-engine (hypothesis when installed, the deterministic fallback
-otherwise — see tests/_hypothesis_compat.py).
+"""Property tests (hypothesis) for lane-resolved flow control in the
+vectorized engine.
 
 The invariants under test, per stacked seed-lane:
 
@@ -27,8 +26,8 @@ The invariants under test, per stacked seed-lane:
 
 import numpy as np
 import pytest
-
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.chaos import (
     AutoscalePolicy, ChaosSchedule, Injection, chaos_metrics)
@@ -102,7 +101,9 @@ def _mini_sim(n_lanes, engine="vectorized"):
 
 
 @pytest.mark.parametrize("engine", VEC_ENGINES)
-@settings(max_examples=25)
+# no deadline: on the jax engine an example's first call of each pow2
+# shape bucket includes that kernel's jit compile
+@settings(max_examples=25, deadline=None)
 @given(cap=st.integers(min_value=2, max_value=12),
        lanes=st.integers(min_value=1, max_value=3),
        batches=st.lists(
@@ -135,9 +136,11 @@ def test_enqueue_batch_per_lane_cap_and_conservation(engine, cap, lanes,
         assert (q["n_enq"] == admitted).all()
         assert (q["hwm"] <= cap).all()
         assert ((q["n_enq"] - q["departed"]) <= cap).all()
-        # drain a fraction of what each lane has admitted
-        backlog = q["n_enq"] - q["departed"]
-        n_drain = int(drain_frac * backlog.min())
+        # drain a fraction of what each lane has admitted and not yet
+        # released: recording more departs than admitted messages would
+        # let a lane's backlog go negative
+        unreleased = q["n_enq"] - q["released"]
+        n_drain = int(drain_frac * unreleased.min())
         if n_drain:
             d = np.cumsum(rng.uniform(0.1, 2.0, (n_drain, lanes)), axis=0) \
                 + float(np.max(t))

@@ -6,7 +6,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.configs import ARCH_NAMES, get_config, get_smoke_config
 from repro.models import layers as L
@@ -224,7 +225,9 @@ def test_moe_capacity_dropping_grace():
     """With capacity_factor << 1 the EP-style capacity math drops tokens;
     dropped tokens must pass through as zeros in the routed output (the
     residual carries them), never NaN."""
-    from repro.compat import AxisType, make_mesh, shard_map
+    from jax import make_mesh, shard_map
+    from jax.sharding import AxisType
+
     from repro.models.moe import _ep_local
 
     D, E, T = 16, 4, 32

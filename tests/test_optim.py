@@ -5,8 +5,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import make_mesh, shard_map
+from jax.sharding import AxisType
 
-from repro.compat import AxisType, make_mesh, shard_map
 from repro.optim import (
     AdamW, clip_by_global_norm, compressed_pod_mean, cosine_warmup,
     dequantize_int8, quantize_int8)

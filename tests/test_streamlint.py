@@ -234,10 +234,10 @@ def test_sl301_global_x64_flip(tmp_path):
     diags = lint(tmp_path, {"src/somewhere.py": bad}, only={"SL301"})
     assert rules_of(diags) == {"SL301"}
     good = """
-        from jax.experimental import enable_x64
+        from repro.compat import scoped_x64
 
         def build():
-            with enable_x64():
+            with scoped_x64():
                 return 1
     """
     assert not lint(tmp_path, {"src/somewhere.py": good}, only={"SL301"})

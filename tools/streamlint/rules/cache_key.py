@@ -16,13 +16,15 @@ cache entry — silent result poisoning across resumes.
   ``ExperimentSpec(...)`` construction inside ``CellSpec.experiment``
   (a spec knob campaigns could never set — and therefore never key).
 * SL204 — a cache-key builder that keys on the *requested* engine
-  instead of the *resolved* one.  ``run_many`` silently downgrades
-  unsupported jax cells to the vectorized engine; a key built before
-  that resolution caches vectorized numbers under the jax namespace
-  (poisoning later genuinely-jax runs) and forks them from the
-  identical vectorized cell.  ``campaign.cell_key`` and
-  ``benchmarks.common.resolve_engine`` must both route through
-  ``campaign.resolved_engine``.
+  instead of the *resolved* execution path.  ``run_many`` downgrades
+  unsupported jax cells to the vectorized engine, and the device
+  program's regime gate sends declined cells to the per-cohort jax
+  path; a key built before that resolution caches one path's numbers
+  under another's name (poisoning later runs of the other path) and
+  forks them from the identical cell.  ``campaign.cell_key`` and
+  ``benchmarks.common.resolve_path`` must route through
+  ``campaign.resolved_path``, and ``benchmarks.common.resolve_engine``
+  through ``campaign.resolved_engine``.
 """
 
 from __future__ import annotations
@@ -122,32 +124,34 @@ def _calls_name(func: ast.AST, name: str) -> bool:
     return False
 
 
-@rule("SL204", "cache keys must be built from the resolved engine, "
-               "never the requested one")
+@rule("SL204", "cache keys must be built from the resolved engine "
+               "and path, never the requested engine")
 def sl204(project: Project,
           scanned: list[SourceFile]) -> Iterable[Diagnostic]:
     camp = project.file(project.config.campaign)
     if camp is not None:
         func = find_func(camp.tree, "cell_key")
-        if func is not None and not _calls_name(func, "resolved_engine"):
+        if func is not None and not _calls_name(func, "resolved_path"):
             yield Diagnostic(
                 rule="SL204", file=camp.path, line=func.lineno,
-                message=("cell_key never calls resolved_engine: a jax "
+                message=("cell_key never calls resolved_path: a jax "
                          "cell the run_many fallback downgrades to "
-                         "vectorized would be cached under the jax "
-                         "namespace, poisoning later genuinely-jax "
-                         "runs"))
+                         "vectorized (or the device-program gate sends "
+                         "to the per-cohort path) would be cached under "
+                         "the path it did not run, poisoning later "
+                         "runs of that path"))
     bench = project.file(project.config.bench_common)
     if bench is not None:
-        func = find_func(bench.tree, "resolve_engine")
-        if func is not None and not _calls_name(func, "resolved_engine"):
-            yield Diagnostic(
-                rule="SL204", file=bench.path, line=func.lineno,
-                message=("benchmarks.common.resolve_engine never "
-                         "consults campaign.resolved_engine: bench "
-                         "cache keys for fallback cells would carry "
-                         "the requested engine instead of the one "
-                         "that actually ran"))
+        for name, helper in (("resolve_engine", "resolved_engine"),
+                             ("resolve_path", "resolved_path")):
+            func = find_func(bench.tree, name)
+            if func is not None and not _calls_name(func, helper):
+                yield Diagnostic(
+                    rule="SL204", file=bench.path, line=func.lineno,
+                    message=(f"benchmarks.common.{name} never consults "
+                             f"campaign.{helper}: bench cache keys would "
+                             f"carry the requested engine or path "
+                             f"instead of the one that actually ran"))
 
 
 @rule("SL203", "every ExperimentSpec field must be threaded through "

@@ -1,8 +1,8 @@
 """SL3xx — jit/x64 purity in the jax engine.
 
 The jax backend owes its parity story to two disciplines: x64 is
-enabled *scoped* (the ``enable_x64`` context inside the ``x64`` kernel
-wrapper), never via the process-global config flip that would silently
+enabled *scoped* (the ``repro.compat.scoped_x64`` context inside the
+``x64`` kernel wrapper), never via the process-global config flip that would silently
 retrace every other jax user in the process; and the jitted kernel
 seams stay pure — no host syncs, no Python control flow on traced
 values (shape/ndim dispatch is fine: it is resolved at trace time).
@@ -52,8 +52,8 @@ def _is_x64_flip(node: ast.AST) -> bool:
     return False
 
 
-@rule("SL301", "never flip jax_enable_x64 globally; use a scoped "
-               "enable_x64 context")
+@rule("SL301", "never flip jax_enable_x64 globally; use the scoped "
+               "repro.compat.scoped_x64 context")
 def sl301(project: Project,
           scanned: list[SourceFile]) -> Iterable[Diagnostic]:
     for sf in scanned:
@@ -62,8 +62,8 @@ def sl301(project: Project,
                 yield Diagnostic(
                     rule="SL301", file=sf.path, line=node.lineno,
                     message=("global jax_enable_x64 flip; use "
-                             "jax.experimental.enable_x64() scoped "
-                             "around kernel builds instead"))
+                             "repro.compat.scoped_x64() around kernel "
+                             "calls instead"))
 
 
 def _wrapper_hit(node: ast.AST, wrappers: tuple[str, ...]) -> bool:
