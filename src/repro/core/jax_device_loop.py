@@ -58,12 +58,14 @@ inert by the same contract as the kernel layer (property-tested).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Callable, Optional
 
 import numpy as np
 
+from repro.core import spans
 from repro.core.simulator import ExperimentSpec
 from repro.core.vectorized import VectorizedStreamSim, _align_paths
 
@@ -269,6 +271,7 @@ class WaveStatic:
                              for k, v in self.inv.items())))
 
 
+@spans.traced("streamsim.build_static")
 def build_static(sim: VectorizedStreamSim) -> WaveStatic:
     """Extract the wave program's static schedule from a constructed
     (but not yet run) engine instance."""
@@ -573,6 +576,7 @@ def build_static(sim: VectorizedStreamSim) -> WaveStatic:
     return WaveStatic(meta=meta, xs=xs, inv=inv_arrays, sizes=sizes)
 
 
+@spans.traced("streamsim.draw_jitter")
 def draw_jitter(sim: VectorizedStreamSim, ws: WaveStatic) -> dict:
     """Per-lane jitter draws for every (generation, slot, member), from
     the engine's per-seed streams.  One flat draw per lane in a fixed
@@ -614,6 +618,10 @@ class _NumpyOps:
     loop — the step-for-step oracle for the device program."""
 
     xp = np
+
+    @staticmethod
+    def scope(name: str) -> Any:
+        return contextlib.nullcontext()
 
     @staticmethod
     def lexsort(keys: tuple) -> np.ndarray:
@@ -665,6 +673,12 @@ def _jax_ops() -> Any:
 
     class _JaxOps:
         xp = jnp
+
+        # names the phase's ops in the HLO metadata, so a profile can
+        # attribute device time to it; no effect on the arithmetic
+        @staticmethod
+        def scope(name: str):
+            return jax.named_scope(name)
 
         @staticmethod
         def lexsort(keys: tuple):
@@ -866,148 +880,159 @@ def _wave_step(ops: Any, meta: dict, inv: dict, carry: dict,
 
     # ---- per-leg arrivals (all independent at step entry) -------------
     # publish(g): confirm-window admission gates + client flush
-    v_pub, pr = x["pub_valid"], x["pub_pr"]
-    gate = carry["conf"][pr, x["pub_conf_slot"]]
-    gate = xp.where(x["pub_has_gate"][:, None], gate, 0.0)
-    pub_start = xp.where(v_pub[:, None], gate + flush, _INF)
+    with ops.scope("publish_gate"):
+        v_pub, pr = x["pub_valid"], x["pub_pr"]
+        gate = carry["conf"][pr, x["pub_conf_slot"]]
+        gate = xp.where(x["pub_has_gate"][:, None], gate, 0.0)
+        pub_start = xp.where(v_pub[:, None], gate + flush, _INF)
 
     # delivery(g-1): pump window assignment — per-queue arrival-order
     # round robin with prefetch-ring gates
-    v_del, q = x["del_valid"], x["del_q"]
-    dlyp = x["dlyp"]
-    t_enq_prev = carry["pend_pub"]["t_enq"][dlyp]
-    pub_start_prev = carry["pend_pub"]["pub_start"][dlyp]
-    oq = ops.lexsort((idx, xp.where(v_del, t_enq_prev[:, 0], _INF),
-                      xp.where(v_del, q, meta["nq"])))
-    q_s = q[oq]
-    _, posq = _seg_pos(ops, xp.where(v_del[oq], q_s, meta["nq"]), Np)
-    kqv = inv["kq"][xp.clip(q_s, 0, meta["nq"] - 1)]
-    slot_c = (x["del_cnt_q"][xp.clip(q_s, 0, meta["nq"] - 1)]
-              + posq) % kqv
-    cons_s = inv["q_cons_tab"][xp.clip(q_s, 0, meta["nq"] - 1), slot_c]
-    idx_on_c = x["del_cnt_c"][cons_s] + posq // kqv
-    depart_s = _pump_assign(ops, carry["ack"], t_enq_prev[oq], cons_s,
-                            idx_on_c, v_del[oq], meta)
-    cons = ops.at_set(xp.zeros(Np, dtype=cons_s.dtype), oq, cons_s)
-    idxc = ops.at_set(xp.zeros(Np, dtype=idx_on_c.dtype), oq, idx_on_c)
-    slotc = ops.at_set(xp.zeros(Np, dtype=slot_c.dtype), oq, slot_c)
-    depart = ops.at_set(xp.zeros_like(t_enq_prev), oq, depart_s)
-    depart = xp.where(v_del[:, None], depart, _INF)
+    with ops.scope("pump_assign"):
+        v_del, q = x["del_valid"], x["del_q"]
+        dlyp = x["dlyp"]
+        t_enq_prev = carry["pend_pub"]["t_enq"][dlyp]
+        pub_start_prev = carry["pend_pub"]["pub_start"][dlyp]
+        oq = ops.lexsort((idx, xp.where(v_del, t_enq_prev[:, 0], _INF),
+                          xp.where(v_del, q, meta["nq"])))
+        q_s = q[oq]
+        _, posq = _seg_pos(ops, xp.where(v_del[oq], q_s, meta["nq"]), Np)
+        kqv = inv["kq"][xp.clip(q_s, 0, meta["nq"] - 1)]
+        slot_c = (x["del_cnt_q"][xp.clip(q_s, 0, meta["nq"] - 1)]
+                  + posq) % kqv
+        cons_s = inv["q_cons_tab"][xp.clip(q_s, 0, meta["nq"] - 1), slot_c]
+        idx_on_c = x["del_cnt_c"][cons_s] + posq // kqv
+        depart_s = _pump_assign(ops, carry["ack"], t_enq_prev[oq], cons_s,
+                                idx_on_c, v_del[oq], meta)
+        cons = ops.at_set(xp.zeros(Np, dtype=cons_s.dtype), oq, cons_s)
+        idxc = ops.at_set(xp.zeros(Np, dtype=idx_on_c.dtype), oq, idx_on_c)
+        slotc = ops.at_set(xp.zeros(Np, dtype=slot_c.dtype), oq, slot_c)
+        depart = ops.at_set(xp.zeros_like(t_enq_prev), oq, depart_s)
+        depart = xp.where(v_del[:, None], depart, _INF)
 
     # ---- combined transit: all legs, one serve per aligned slot -------
-    blocks = [
-        (pub_start, v_pub,
-         xp.swapaxes(inv["pub_tab"][x["pub_ci"]], 0, 1), x["pub_jit"]),
-        (depart, v_del,
-         xp.swapaxes(inv["del_tab"][q, slotc], 0, 1), x["del_jit"]),
-    ]
-    if fb:
-        # the delivery->reply delay line: slot ``dly`` holds the entry
-        # written ``delay`` steps ago (generation g-1-delay), which is
-        # exactly the generation this step reply-publishes
-        dly = x["dly"]
-        pend_b = {k: v[dly] for k, v in carry["pend_del"].items()}
-        pend_c = carry["pend_rep"]
-        v_rp, rp_pr = x["rp_valid"], x["rp_pr"]
-        v_rd, rd_pr = x["rd_valid"], x["rd_pr"]
-        blocks.append(
-            (pend_b["seen"], v_rp,
-             xp.swapaxes(inv["rp_tab"][xp.clip(pend_b["cons"], 0,
-                                               nC - 1), rp_pr], 0, 1),
-             x["rp_jit"]))
-        blocks.append(
-            (pend_c["rdep"], v_rd,
-             xp.swapaxes(inv["rd_tab"][rd_pr], 0, 1), x["rd_jit"]))
-    a_c = xp.concatenate([b[0] for b in blocks], axis=0)
-    v_c = xp.concatenate([b[1] for b in blocks], axis=0)
-    slots_c = xp.concatenate([b[2] for b in blocks], axis=1)
-    jit_c = xp.concatenate([b[3] for b in blocks], axis=1)
-    # direction per block: publish/reply-publish are ingress-bound (0),
-    # delivery/reply-delivery egress-bound (1)
-    side_c = xp.concatenate(
-        [xp.full(Np, s, dtype=xp.int64)
-         for s in ((0, 1, 0, 1) if fb else (0, 1))])
-    free, t_c = _transit(ops, carry["free"], a_c, slots_c, jit_c, v_c,
-                         side_c, meta, inv["chain_base"], inv["k_arr"])
-    t_enq = t_c[:Np]
-    t_land = t_c[Np:2 * Np]
+    with ops.scope("transit"):
+        blocks = [
+            (pub_start, v_pub,
+             xp.swapaxes(inv["pub_tab"][x["pub_ci"]], 0, 1), x["pub_jit"]),
+            (depart, v_del,
+             xp.swapaxes(inv["del_tab"][q, slotc], 0, 1), x["del_jit"]),
+        ]
+        if fb:
+            # the delivery->reply delay line: slot ``dly`` holds the
+            # entry written ``delay`` steps ago (generation g-1-delay),
+            # which is exactly the generation this step reply-publishes
+            dly = x["dly"]
+            pend_b = {k: v[dly] for k, v in carry["pend_del"].items()}
+            pend_c = carry["pend_rep"]
+            v_rp, rp_pr = x["rp_valid"], x["rp_pr"]
+            v_rd, rd_pr = x["rd_valid"], x["rd_pr"]
+            blocks.append(
+                (pend_b["seen"], v_rp,
+                 xp.swapaxes(inv["rp_tab"][xp.clip(pend_b["cons"], 0,
+                                                   nC - 1), rp_pr], 0, 1),
+                 x["rp_jit"]))
+            blocks.append(
+                (pend_c["rdep"], v_rd,
+                 xp.swapaxes(inv["rd_tab"][rd_pr], 0, 1), x["rd_jit"]))
+        a_c = xp.concatenate([b[0] for b in blocks], axis=0)
+        v_c = xp.concatenate([b[1] for b in blocks], axis=0)
+        slots_c = xp.concatenate([b[2] for b in blocks], axis=1)
+        jit_c = xp.concatenate([b[3] for b in blocks], axis=1)
+        # direction per block: publish/reply-publish are ingress-bound
+        # (0), delivery/reply-delivery egress-bound (1)
+        side_c = xp.concatenate(
+            [xp.full(Np, s, dtype=xp.int64)
+             for s in ((0, 1, 0, 1) if fb else (0, 1))])
+        free, t_c = _transit(ops, carry["free"], a_c, slots_c, jit_c, v_c,
+                             side_c, meta, inv["chain_base"], inv["k_arr"])
+        t_enq = t_c[:Np]
+        t_land = t_c[Np:2 * Np]
+        # the publish->delivery hand-off: this step's enqueues wait in
+        # the delay line for the delivery leg of a later step
+        pend_pub = dict(
+            t_enq=ops.at_set(carry["pend_pub"]["t_enq"], dlyp, t_enq),
+            pub_start=ops.at_set(carry["pend_pub"]["pub_start"], dlyp,
+                                 pub_start))
 
     # ---- publish(g) epilogue: confirms feed the admission ring --------
-    confirms = t_enq + ctrl
-    conf = ops.at_set(carry["conf"], (pr, x["pub_conf_slot"]), confirms)
+    with ops.scope("publish_gate"):
+        confirms = t_enq + ctrl
+        conf = ops.at_set(carry["conf"], (pr, x["pub_conf_slot"]), confirms)
 
     # ---- delivery(g-1) epilogue: consumer processing + batched acks ---
-    a = t_land + recv_req
-    h = (xp.where(v_del, proc_s, 0.0)[:, None] * (1.0 + x["proc_jit"]))
-    ch = xp.where(v_del, cons, nC)
-    oc = ops.lexsort((idx, xp.where(v_del, a[:, 0], _INF), ch))
-    ch_s = ch[oc]
-    start_c, posc = _seg_pos(ops, ch_s, Np)
-    carry_pf = carry["proc"][ch_s]
-    a_eff = xp.where((ch_s < nC)[:, None],
-                     xp.maximum(a[oc], carry_pf), a[oc])
-    h_s = h[oc]
-    c = ops.cumsum(h_s)
-    basefill = ops.cummax(xp.where(start_c[:, None], c - h_s, -_INF))
-    Hs = c - basefill
-    seen_s = Hs + ops.seg_cummax(a_eff - (Hs - h_s), start_c)
-    proc = ops.at_max(carry["proc"], ch_s, seen_s)
-    seen = ops.at_set(xp.zeros_like(a), oc, seen_s)
-    seen = xp.where(v_del[:, None], seen, _INF)
-    # acks: batch every B in processing order, force-flush at
-    # generation end; invalid members route to the dummy ring row nC
-    # (valid slots within a generation are distinct: load < P)
-    boundary = ((((posc + 1) % B) == 0)
-                | xp.concatenate([start_c[1:], xp.ones(1, dtype=bool)]))
-    nb = _next_boundary(ops, boundary | (ch_s >= nC), start_c, Np)
-    ack = ops.at_set(carry["ack"], (ch_s, idxc[oc] % P),
-                     seen_s[nb] + ctrl)
+    with ops.scope("consume_ack"):
+        a = t_land + recv_req
+        h = (xp.where(v_del, proc_s, 0.0)[:, None] * (1.0 + x["proc_jit"]))
+        ch = xp.where(v_del, cons, nC)
+        oc = ops.lexsort((idx, xp.where(v_del, a[:, 0], _INF), ch))
+        ch_s = ch[oc]
+        start_c, posc = _seg_pos(ops, ch_s, Np)
+        carry_pf = carry["proc"][ch_s]
+        a_eff = xp.where((ch_s < nC)[:, None],
+                         xp.maximum(a[oc], carry_pf), a[oc])
+        h_s = h[oc]
+        c = ops.cumsum(h_s)
+        basefill = ops.cummax(xp.where(start_c[:, None], c - h_s, -_INF))
+        Hs = c - basefill
+        seen_s = Hs + ops.seg_cummax(a_eff - (Hs - h_s), start_c)
+        proc = ops.at_max(carry["proc"], ch_s, seen_s)
+        seen = ops.at_set(xp.zeros_like(a), oc, seen_s)
+        seen = xp.where(v_del[:, None], seen, _INF)
+        # acks: batch every B in processing order, force-flush at
+        # generation end; invalid members route to the dummy ring row
+        # nC (valid slots within a generation are distinct: load < P)
+        boundary = ((((posc + 1) % B) == 0)
+                    | xp.concatenate([start_c[1:], xp.ones(1, dtype=bool)]))
+        nb = _next_boundary(ops, boundary | (ch_s >= nC), start_c, Np)
+        ack = ops.at_set(carry["ack"], (ch_s, idxc[oc] % P),
+                         seen_s[nb] + ctrl)
 
     ys = dict(pub_start=pub_start, confirms=confirms, depart=depart,
               seen=seen)
-    carry = dict(
-        carry, free=free, conf=conf, proc=proc, ack=ack,
-        pend_pub=dict(
-            t_enq=ops.at_set(carry["pend_pub"]["t_enq"], dlyp, t_enq),
-            pub_start=ops.at_set(carry["pend_pub"]["pub_start"], dlyp,
-                                 pub_start)))
+    carry = dict(carry, free=free, conf=conf, proc=proc, ack=ack,
+                 pend_pub=pend_pub)
     if not fb:
         ys["rtt"] = xp.full_like(seen, _INF)
         return carry, ys
 
     # ---- reply-publish(g-2) epilogue: per-producer reply pump ---------
-    t_renq = t_c[2 * Np:3 * Np]
-    pch = xp.where(v_rp, rp_pr, nP)
-    opr = ops.lexsort((idx, xp.where(v_rp, t_renq[:, 0], _INF), pch))
-    pr_s = pch[opr]
-    _, posp = _seg_pos(ops, pr_s, Np)
-    idx_on_p = x["rp_cnt_p"][pr_s] + posp
-    rdep_s = _pump_assign(ops, carry["prep"], t_renq[opr], pr_s,
-                          idx_on_p, v_rp[opr], meta)
-    rdep = ops.at_set(xp.zeros_like(t_renq), opr, rdep_s)
-    rdep = xp.where(v_rp[:, None], rdep, _INF)
-    idxp = ops.at_set(xp.zeros(Np, dtype=idx_on_p.dtype), opr, idx_on_p)
+    with ops.scope("reply_pump"):
+        t_renq = t_c[2 * Np:3 * Np]
+        pch = xp.where(v_rp, rp_pr, nP)
+        opr = ops.lexsort((idx, xp.where(v_rp, t_renq[:, 0], _INF), pch))
+        pr_s = pch[opr]
+        _, posp = _seg_pos(ops, pr_s, Np)
+        idx_on_p = x["rp_cnt_p"][pr_s] + posp
+        rdep_s = _pump_assign(ops, carry["prep"], t_renq[opr], pr_s,
+                              idx_on_p, v_rp[opr], meta)
+        rdep = ops.at_set(xp.zeros_like(t_renq), opr, rdep_s)
+        rdep = xp.where(v_rp[:, None], rdep, _INF)
+        idxp = ops.at_set(xp.zeros(Np, dtype=idx_on_p.dtype), opr,
+                          idx_on_p)
 
     # ---- reply-delivery(g-3) epilogue: RTTs + producer ack batching ---
-    t_seen = t_c[3 * Np:] + recv_rep
-    rtt = xp.where(v_rd[:, None], t_seen - pend_c["pub_start"], _INF)
-    pch_d = xp.where(v_rd, rd_pr, nP)
-    opd = ops.lexsort((idx, xp.where(v_rd, t_seen[:, 0], _INF), pch_d))
-    pd_s = pch_d[opd]
-    start_p, posd = _seg_pos(ops, pd_s, Np)
-    boundary = ((((posd + 1) % B) == 0)
-                | xp.concatenate([start_p[1:], xp.ones(1, dtype=bool)]))
-    nb = _next_boundary(ops, boundary | (pd_s >= nP), start_p, Np)
-    prep = ops.at_set(carry["prep"],
-                      (pd_s, pend_c["idx_on_p"][opd] % P),
-                      t_seen[opd][nb] + ctrl)
+    with ops.scope("reply_ack"):
+        t_seen = t_c[3 * Np:] + recv_rep
+        rtt = xp.where(v_rd[:, None], t_seen - pend_c["pub_start"], _INF)
+        pch_d = xp.where(v_rd, rd_pr, nP)
+        opd = ops.lexsort((idx, xp.where(v_rd, t_seen[:, 0], _INF),
+                           pch_d))
+        pd_s = pch_d[opd]
+        start_p, posd = _seg_pos(ops, pd_s, Np)
+        boundary = ((((posd + 1) % B) == 0)
+                    | xp.concatenate([start_p[1:], xp.ones(1, dtype=bool)]))
+        nb = _next_boundary(ops, boundary | (pd_s >= nP), start_p, Np)
+        prep = ops.at_set(carry["prep"],
+                          (pd_s, pend_c["idx_on_p"][opd] % P),
+                          t_seen[opd][nb] + ctrl)
+        new_b = dict(seen=seen, cons=cons, pub_start=pub_start_prev)
+        pend_del = {k: ops.at_set(carry["pend_del"][k], dly, new_b[k])
+                    for k in new_b}
 
     ys["rtt"] = rtt
-    new_b = dict(seen=seen, cons=cons, pub_start=pub_start_prev)
     carry = dict(
-        carry, prep=prep,
-        pend_del={k: ops.at_set(carry["pend_del"][k], dly, new_b[k])
-                  for k in new_b},
+        carry, prep=prep, pend_del=pend_del,
         pend_rep=dict(rdep=rdep, idx_on_p=idxp,
                       pub_start=pend_b["pub_start"]))
     return carry, ys
@@ -1062,6 +1087,7 @@ def _compiled_program(sig: tuple, meta_items: tuple, n_cells: bool
     through :func:`_run_program` (scoped x64)."""
     import jax
 
+    spans.count("streamsim.program_builds", 1)
     ops = _jax_ops()
     meta = dict(meta_items)
     meta["pools"] = tuple(meta["pools"])
@@ -1077,21 +1103,52 @@ def _compiled_program(sig: tuple, meta_items: tuple, n_cells: bool
 
 
 def _run_program(fn: Callable, *args: Any) -> dict:
-    """Run a compiled program under scoped x64; host NumPy outputs."""
+    """Run a compiled program under scoped x64; host NumPy outputs.
+
+    Spans: ``dispatch`` is the call up to its return (inputs handed to
+    the device, the program enqueued), ``wait`` the device running it,
+    ``fetch`` the copies of the outputs back to the host."""
+    import jax
+
     from repro.compat import scoped_x64
-    with scoped_x64():
-        out = fn(*args)
-    return {k: np.asarray(v) for k, v in out.items()}
+    with spans.span("streamsim.program"):
+        with scoped_x64(), spans.span("streamsim.dispatch"):
+            out = fn(*args)
+        with spans.span("streamsim.wait"):
+            jax.block_until_ready(out)
+        with spans.span("streamsim.fetch"):
+            host = {k: np.asarray(v) for k, v in out.items()}
+    if spans.on():
+        spans.count("streamsim.programs", 1)
+        spans.count("streamsim.h2d_bytes",
+                    sum(a.nbytes for d in args for a in d.values()))
+        spans.count("streamsim.d2h_bytes",
+                    sum(a.nbytes for a in host.values()))
+    return host
+
+
+def _count_cells(wss: list, n_padded: int) -> None:
+    """Counters of one program run over the cells ``wss``, ``n_padded``
+    of them after the pow2 pad: member-steps that carry a message, and
+    all the program computes (pow2 member and cell pads, drain steps)."""
+    if not spans.on():
+        return
+    m = wss[0].meta
+    spans.count("streamsim.cells", len(wss))
+    spans.count("streamsim.member_steps",
+                m["L"] * sum(int(ws.sizes["valid"].sum()) for ws in wss))
+    spans.count("streamsim.padded_member_steps",
+                m["Np"] * m["L"] * m["nSteps"] * n_padded)
 
 
 def _run_jax(ws: WaveStatic, jitter: dict) -> dict:
     fn = _compiled_program(ws.signature(), _meta_key(ws.meta), False)
+    _count_cells([ws], 1)
     return _run_program(fn, ws.xs, ws.inv, jitter)
 
 
 def _meta_key(meta: dict) -> tuple:
-    return tuple(sorted((k, v if not isinstance(v, tuple) else v)
-                        for k, v in meta.items()))
+    return tuple(sorted(meta.items()))
 
 
 # ---------------------------------------------------------------------------
@@ -1099,6 +1156,7 @@ def _meta_key(meta: dict) -> tuple:
 # ---------------------------------------------------------------------------
 
 
+@spans.traced("streamsim.assemble")
 def _assemble(sim: VectorizedStreamSim, ws: WaveStatic,
               ys: dict) -> list:
     """Per-lane RunResults from the generation trace, through the
@@ -1175,6 +1233,7 @@ def run_wave_cells(sims: list) -> list:
                for k in ws0.inv}
         draws = [draw_jitter(built[i][0], built[i][1]) for i in cells]
         jit = {k: np.stack([d[k] for d in draws]) for k in draws[0]}
+        _count_cells([built[i][1] for i in idxs], Cp)
         ys = _run_program(_compiled_program(sig, _meta_key(ws0.meta), True),
                           xs, inv, jit)
         for c, i in enumerate(idxs):

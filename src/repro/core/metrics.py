@@ -9,6 +9,7 @@ from typing import Sequence
 
 import numpy as np
 
+from repro.core import spans
 from repro.core.simulator import RunResult
 
 
@@ -60,6 +61,7 @@ def throughput_msgs_per_s(result: RunResult, warmup_frac: float = 0.05) -> float
     return float((ts.size - 1) / span)
 
 
+@spans.traced("streamsim.summarize")
 def summarize(result: RunResult) -> Summary:
     spec = result.spec
     s = Summary(arch=spec.arch, pattern=spec.pattern,

@@ -103,6 +103,7 @@ from typing import Any, Callable, Iterable, Iterator, Optional, Sequence
 
 import numpy as np
 
+from repro.core import spans
 from repro.core.architectures import (
     Architecture, PathElement, ResourceSpec, make_architecture)
 from repro.core.broker import ClassicQueue
@@ -2301,6 +2302,7 @@ def _stack_key(spec: ExperimentSpec) -> tuple:
 STACK_MAX_LANES = 16
 
 
+@spans.traced("streamsim.run_many")
 def run_many(specs: Sequence[ExperimentSpec],
              inventory: Optional[ClusterInventory] = None
              ) -> list[RunResult]:
@@ -2361,7 +2363,9 @@ def run_many(specs: Sequence[ExperimentSpec],
         # one probe per group: feasibility is structural, identical
         # across the seeds
         try:
-            cls(specs[idxs[0]], inventory)
+            with spans.span("streamsim.engine"):
+                cls(specs[idxs[0]], inventory)
+            spans.count("streamsim.engines", 1)
         except InfeasibleConfiguration as e:
             for i in idxs:
                 results[i] = RunResult(spec=specs[i], feasible=False,
@@ -2375,7 +2379,9 @@ def run_many(specs: Sequence[ExperimentSpec],
                                                    inventory)
                 continue
             seeds = [specs[i].params.seed for i in chunk]
-            sim = cls(specs[chunk[0]], inventory, stack_seeds=seeds)
+            with spans.span("streamsim.engine"):
+                sim = cls(specs[chunk[0]], inventory, stack_seeds=seeds)
+            spans.count("streamsim.engines", 1)
             if getattr(sim, "_use_device_loop", lambda: False)():
                 # whole-run device programs batch across *cells* too
                 # (vmap-over-cells; see repro.core.jax_device_loop) —

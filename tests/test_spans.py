@@ -1,0 +1,231 @@
+"""The span-and-counter recorder (``repro.core.spans``) and the request
+path it instruments: off by default at the cost of a null context, on
+inside ``recording()``, and never a change to a result."""
+
+from __future__ import annotations
+
+import dataclasses
+
+import numpy as np
+import pytest
+
+from repro.core import jax_device_loop as dl
+from repro.core import spans
+from repro.core.jax_engine import JaxStreamSim
+from repro.core.metrics import summarize
+from repro.core.simulator import ExperimentSpec, SimParams
+from repro.core.vectorized import VectorizedStreamSim, run_many
+from repro.core.workloads import get_workload
+
+ARCHS = ("dts", "prs-haproxy", "mss")
+SEEDS = (1, 2)
+
+
+def _spec(arch: str, seed: int, jitter: float = 0.1) -> ExperimentSpec:
+    return ExperimentSpec(
+        pattern="work_sharing", workload=get_workload("dstream"), arch=arch,
+        n_producers=2, n_consumers=2, total_messages=32,
+        params=SimParams(seed=seed, engine="jax", jax_device_loop=True,
+                         jitter=jitter))
+
+
+def _specs() -> list:
+    return [_spec(a, s) for a in ARCHS for s in SEEDS]
+
+
+def _sims() -> list:
+    return [JaxStreamSim(_spec(a, SEEDS[0]), None, stack_seeds=list(SEEDS))
+            for a in ARCHS]
+
+
+def _request(specs: list) -> list:
+    with spans.span("request"):
+        results = run_many(specs)
+        for r in results:
+            summarize(r)
+    return results
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    """One untimed request (compiles), then one recorded."""
+    off = run_many(_specs())
+    with spans.recording() as rec:
+        on = _request(_specs())
+    return off, on, rec
+
+
+def test_off_by_default_is_one_null_context():
+    assert not spans.on()
+    a, b = spans.span("x"), spans.span("y")
+    assert a is b
+    with a:
+        spans.count("streamsim.engines", 5)
+    with spans.recording() as rec:
+        pass
+    assert rec.spans == [] and rec.counters == {} and rec.n_requests == 0
+    assert not spans.on()
+
+
+def test_recording_does_not_nest():
+    with spans.recording():
+        with pytest.raises(RuntimeError):
+            with spans.recording():
+                pass
+
+
+def test_roots_parents_and_self_time():
+    with spans.recording() as rec:
+        with spans.span("a"):
+            with spans.span("b"):
+                spans.count("n", 2)
+            with spans.span("c"):
+                pass
+        with spans.span("a"):
+            spans.count("n", 3)
+    assert [(s.name, s.parent, s.request) for s in rec.spans] == [
+        ("a", -1, 0), ("b", 0, 0), ("c", 0, 0), ("a", -1, 1)]
+    assert rec.n_requests == 2 and rec.counters == {"n": 5}
+    assert all(s.end_ns >= s.start_ns for s in rec.spans)
+    d = {s.name: s.end_ns - s.start_ns for s in rec.spans[:3]}
+    per = rec.requests()
+    assert per[0]["a"] == d["a"] - d["b"] - d["c"]
+    assert per[1] == {"a": rec.spans[3].end_ns - rec.spans[3].start_ns}
+    assert rec.self_ns()["a"] == per[0]["a"] + per[1]["a"]
+
+
+def test_counts_are_kept_per_request():
+    """A count made under a span adds to its request's counters too; one
+    made with no span open adds to the totals only."""
+    with spans.recording() as rec:
+        with spans.span("a"):
+            with spans.span("b"):
+                spans.count("n", 2)
+        spans.count("n", 7)
+        with spans.span("a"):
+            spans.count("n", 3)
+            spans.count("m", 1)
+    assert rec.counters == {"n": 12, "m": 1}
+    assert rec.request_counters == {0: {"n": 2}, 1: {"n": 3, "m": 1}}
+
+
+def test_span_tree_of_a_request(recorded):
+    _, _, rec = recorded
+    spans_ = rec.spans
+    assert rec.n_requests == 1
+    assert {s.request for s in spans_} == {0}
+    parent = {i: (spans_[s.parent].name if s.parent >= 0 else None)
+              for i, s in enumerate(spans_)}
+    names = [s.name for s in spans_]
+    want_parent = {
+        "request": None, "streamsim.run_many": "request",
+        "streamsim.summarize": "request",
+        "streamsim.engine": "streamsim.run_many",
+        "streamsim.build_static": "streamsim.run_many",
+        "streamsim.draw_jitter": "streamsim.run_many",
+        "streamsim.program": "streamsim.run_many",
+        "streamsim.assemble": "streamsim.run_many",
+        "streamsim.dispatch": "streamsim.program",
+        "streamsim.wait": "streamsim.program",
+        "streamsim.fetch": "streamsim.program"}
+    assert set(names) == set(want_parent)
+    for i, n in enumerate(names):
+        assert parent[i] == want_parent[n], n
+    # the three phases of a program run, in order, inside it
+    for i, n in enumerate(names):
+        if n == "streamsim.program":
+            kids = [j for j in range(len(spans_)) if spans_[j].parent == i]
+            assert [names[j] for j in kids] == [
+                "streamsim.dispatch", "streamsim.wait", "streamsim.fetch"]
+            assert spans_[kids[0]].start_ns >= spans_[i].start_ns
+            assert spans_[kids[-1]].end_ns <= spans_[i].end_ns
+    assert names.count("streamsim.summarize") == len(ARCHS) * len(SEEDS)
+
+
+def test_counters_match_what_was_built(recorded):
+    _, _, rec = recorded
+    c = rec.counters
+    groups = len(ARCHS)      # the seeds of one architecture stack
+    assert c["streamsim.engines"] == 2 * groups
+    # the architectures' programs differ in signature: one cell each
+    assert c["streamsim.programs"] == groups
+    assert c["streamsim.cells"] == groups
+    assert sum(s.name == "streamsim.engine" for s in rec.spans) == 2 * groups
+    sims = _sims()
+    wss = [dl.build_static(sim) for sim in sims]
+    assert len({ws.signature() for ws in wss}) == groups
+    assert c["streamsim.member_steps"] == sum(
+        int(ws.sizes["valid"].sum()) * ws.meta["L"] for ws in wss)
+    assert c["streamsim.padded_member_steps"] == sum(
+        ws.meta["Np"] * ws.meta["L"] * ws.meta["nSteps"] for ws in wss)
+    assert 0 < c["streamsim.member_steps"] < c["streamsim.padded_member_steps"]
+    assert c["streamsim.h2d_bytes"] == sum(
+        a.nbytes for ws in wss for d in (ws.xs, ws.inv) for a in d.values()
+    ) + sum(a.nbytes for ws, sim in zip(wss, sims)
+            for a in dl.draw_jitter(sim, ws).values())
+    assert c["streamsim.d2h_bytes"] > 0
+    # every program was already compiled by the unrecorded request
+    assert c.get("streamsim.program_builds", 0) == 0
+
+
+def test_request_counters_of_a_request(recorded):
+    _, _, rec = recorded
+    assert rec.request_counters == {0: rec.counters}
+
+
+def test_cells_of_one_signature_share_a_program():
+    """Two stack groups that differ only in jitter build one signature,
+    so they run as one program over two cells."""
+    specs = [_spec("mss", s, jitter=j) for j in (0.1, 0.2) for s in SEEDS]
+    run_many(specs)
+    with spans.recording() as rec:
+        run_many(specs)
+    c = rec.counters
+    assert c["streamsim.programs"] == 1 and c["streamsim.cells"] == 2
+    assert c["streamsim.engines"] == 4
+    ws = dl.build_static(JaxStreamSim(specs[0], None,
+                                      stack_seeds=list(SEEDS)))
+    assert c["streamsim.padded_member_steps"] == (
+        2 * ws.meta["Np"] * ws.meta["L"] * ws.meta["nSteps"])
+    assert c["streamsim.member_steps"] == (
+        2 * int(ws.sizes["valid"].sum()) * ws.meta["L"])
+
+
+def test_results_identical_with_recording_on_and_off(recorded):
+    off, on, _ = recorded
+    assert len(off) == len(on)
+    for a, b in zip(off, on):
+        assert a.path == b.path == "jax-device"
+        for f in dataclasses.fields(a):
+            x, y = getattr(a, f.name), getattr(b, f.name)
+            if isinstance(x, np.ndarray):
+                assert np.array_equal(x, y, equal_nan=True), f.name
+            else:
+                assert x == y, f.name
+
+
+def test_scan_phases_are_named_in_the_program():
+    """The six phases of the scan step carry named scopes into the
+    lowered program (feedback cells have all four legs)."""
+    from repro.compat import scoped_x64
+    spec = ExperimentSpec(
+        pattern="feedback", workload=get_workload("dstream"), arch="dts",
+        n_producers=4, n_consumers=2, total_messages=256,
+        params=SimParams(seed=0, confirm_window=32))
+    sim = VectorizedStreamSim(spec)
+    assert dl._device_loop_ok(sim)[0]
+    ws = dl.build_static(sim)
+    fn = dl._compiled_program(ws.signature(), dl._meta_key(ws.meta), False)
+    with scoped_x64():
+        text = fn.lower(ws.xs, ws.inv, dl.draw_jitter(sim, ws)).as_text(
+            debug_info=True)
+    for scope in ("publish_gate", "pump_assign", "transit", "consume_ack",
+                  "reply_pump", "reply_ack"):
+        assert f'loc("{scope}/' in text, scope
+
+
+def test_meta_key_is_the_sorted_items():
+    ws = dl.build_static(VectorizedStreamSim(_spec("mss", 1)))
+    key = dl._meta_key(ws.meta)
+    assert key == tuple(sorted(ws.meta.items()))
+    assert hash(key) == hash(dl._meta_key(dict(ws.meta)))
