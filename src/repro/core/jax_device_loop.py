@@ -61,6 +61,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+from collections.abc import Iterator, Mapping
 from typing import Any, Callable, Optional
 
 import numpy as np
@@ -1076,7 +1077,7 @@ def run_wave_trace(ws: WaveStatic, jitter: dict,
             lambda c, x: _wave_step(ops, meta, inv, c, x),
             _init_carry(np, meta), xs, meta["nSteps"])
         return ys
-    return _run_jax(ws, jitter)
+    return dict(_run_jax(ws, jitter))
 
 
 @functools.lru_cache(maxsize=64)
@@ -1102,29 +1103,76 @@ def _compiled_program(sig: tuple, meta_items: tuple, n_cells: bool
     return jax.jit(jax.vmap(program) if n_cells else program)
 
 
-def _run_program(fn: Callable, *args: Any) -> dict:
-    """Run a compiled program under scoped x64; host NumPy outputs.
+class Outputs(Mapping):
+    """The outputs of one dispatched program, read as host arrays.
 
-    Spans: ``dispatch`` is the call up to its return (inputs handed to
-    the device, the program enqueued), ``wait`` the device running it,
-    ``fetch`` the copies of the outputs back to the host."""
-    import jax
+    The first read waits on the program (``streamsim.wait``); each
+    output is copied to the host on its first read (``streamsim.fetch``,
+    counted in ``streamsim.d2h_bytes``), so an output nobody reads is
+    never copied."""
 
+    def __init__(self, dev: dict) -> None:
+        self._dev = dev
+        self._host: dict = {}
+
+    def prefetch(self, keys: tuple) -> None:
+        """Start copying ``keys`` to the host as soon as the program
+        ends, so their reads find them there."""
+        for k in keys:
+            self._dev[k].copy_to_host_async()
+
+    def __getitem__(self, key: str) -> np.ndarray:
+        a = self._host.get(key)
+        if a is None:
+            dev = self._dev[key]
+            if not self._host:
+                import jax
+                with spans.span("streamsim.wait"):
+                    jax.block_until_ready(self._dev)
+            with spans.span("streamsim.fetch"):
+                a = self._host[key] = np.asarray(dev)
+            spans.count("streamsim.d2h_bytes", a.nbytes)
+        return a
+
+    def __iter__(self) -> Iterator[str]:
+        return iter(self._dev)
+
+    def __len__(self) -> int:
+        return len(self._dev)
+
+
+class _Cell(Mapping):
+    """Cell ``c`` of a vmapped program's outputs, sliced as it is read."""
+
+    def __init__(self, ys: Mapping, c: int) -> None:
+        self._ys, self._c = ys, c
+
+    def __getitem__(self, key: str) -> np.ndarray:
+        return self._ys[key][self._c]
+
+    def __iter__(self) -> Iterator[str]:
+        return iter(self._ys)
+
+    def __len__(self) -> int:
+        return len(self._ys)
+
+
+def _run_program(fn: Callable, *args: Any) -> Outputs:
+    """Enqueue a compiled program under scoped x64 and return its
+    :class:`Outputs` without waiting on it.
+
+    Span ``dispatch`` is the call up to its return: inputs handed to
+    the device, the program enqueued.  Reading the outputs waits and
+    copies them back (``wait``, ``fetch``)."""
     from repro.compat import scoped_x64
     with spans.span("streamsim.program"):
         with scoped_x64(), spans.span("streamsim.dispatch"):
             out = fn(*args)
-        with spans.span("streamsim.wait"):
-            jax.block_until_ready(out)
-        with spans.span("streamsim.fetch"):
-            host = {k: np.asarray(v) for k, v in out.items()}
     if spans.on():
         spans.count("streamsim.programs", 1)
         spans.count("streamsim.h2d_bytes",
                     sum(a.nbytes for d in args for a in d.values()))
-        spans.count("streamsim.d2h_bytes",
-                    sum(a.nbytes for a in host.values()))
-    return host
+    return Outputs(out)
 
 
 def _count_cells(wss: list, n_padded: int) -> None:
@@ -1141,7 +1189,7 @@ def _count_cells(wss: list, n_padded: int) -> None:
                 m["Np"] * m["L"] * m["nSteps"] * n_padded)
 
 
-def _run_jax(ws: WaveStatic, jitter: dict) -> dict:
+def _run_jax(ws: WaveStatic, jitter: dict) -> Outputs:
     fn = _compiled_program(ws.signature(), _meta_key(ws.meta), False)
     _count_cells([ws], 1)
     return _run_program(fn, ws.xs, ws.inv, jitter)
@@ -1156,11 +1204,18 @@ def _meta_key(meta: dict) -> tuple:
 # ---------------------------------------------------------------------------
 
 
+def _read_keys(meta: dict) -> tuple:
+    """The outputs :func:`_assemble` reads."""
+    return ("seen", "pub_start") + (("rtt",) if meta["feedback"] else ())
+
+
 @spans.traced("streamsim.assemble")
 def _assemble(sim: VectorizedStreamSim, ws: WaveStatic,
-              ys: dict) -> list:
+              ys: Mapping) -> list:
     """Per-lane RunResults from the generation trace, through the
-    engine's own ``_result`` contract (attribution, counters, sort)."""
+    engine's own ``_result`` contract (attribution, counters, sort).
+    Reading ``ys`` (an :class:`Outputs`) waits on the program and
+    copies back the outputs read here (:func:`_read_keys`)."""
     s = ws.sizes
     nP, M, L, nGen = s["nP"], s["M"], s["L"], s["nGen"]
     mem = s["mem_id"].ravel()
@@ -1199,44 +1254,63 @@ def _assemble(sim: VectorizedStreamSim, ws: WaveStatic,
 def run_wave_results(sim: VectorizedStreamSim) -> list:
     """Whole-run device execution for one (possibly lane-stacked)
     engine instance; one RunResult per stacked seed-lane."""
-    ws = build_static(sim)
-    ys = _run_jax(ws, draw_jitter(sim, ws))
-    return _assemble(sim, ws, ys)
+    return run_wave_cells([sim])[0]
+
+
+def _dispatch(cells: list, sig: tuple) -> Mapping:
+    """Draw the jitter of ``cells`` (``(sim, WaveStatic)`` pairs of one
+    signature) and enqueue their program, vmapped over the cells when
+    there are several: the cell axis pow2-padded by replicating cell 0
+    (pads are dropped; inertness is property-tested).  Starts copying
+    back what assembly reads."""
+    sim0, ws0 = cells[0]
+    if len(cells) == 1:
+        ys = _run_jax(ws0, draw_jitter(sim0, ws0))
+    else:
+        Cp = 1 << (len(cells) - 1).bit_length()
+        padded = cells + cells[:1] * (Cp - len(cells))
+        xs = {k: np.stack([ws.xs[k] for _, ws in padded]) for k in ws0.xs}
+        inv = {k: np.stack([ws.inv[k] for _, ws in padded])
+               for k in ws0.inv}
+        draws = [draw_jitter(sim, ws) for sim, ws in padded]
+        jit = {k: np.stack([d[k] for d in draws]) for k in draws[0]}
+        _count_cells([ws for _, ws in cells], Cp)
+        ys = _run_program(_compiled_program(sig, _meta_key(ws0.meta), True),
+                          xs, inv, jit)
+    if isinstance(ys, Outputs):  # a wrapped _run_program may return host arrays
+        ys.prefetch(_read_keys(ws0.meta))
+    return ys
 
 
 def run_wave_cells(sims: list) -> list:
     """vmap-over-cells driver: batch structurally identical cells
-    (same :meth:`WaveStatic.signature`) into one device program, pow2
-    pad-and-mask on the cell axis (pads replicate cell 0 and are
-    dropped — inertness is property-tested).  Returns, per sim, the
-    per-lane RunResult list."""
+    (same :meth:`WaveStatic.signature`) into one device program.
+    Returns, per sim, the per-lane RunResult list.
+
+    The host runs one program ahead of the device: it enqueues the
+    next group's program before it waits on the current one and
+    assembles it, so draws, dispatch, copies and assembly overlap a
+    program already queued.  At most two programs' buffers are alive
+    on the device at once."""
     import jax.numpy as jnp  # noqa: F401  (jax required here)
     built = [(sim, build_static(sim)) for sim in sims]
-    out: list = [None] * len(sims)
     groups: dict = {}
-    for i, (sim, ws) in enumerate(built):
+    for i, (_, ws) in enumerate(built):
         groups.setdefault(ws.signature(), []).append(i)
-    for sig, idxs in groups.items():
-        if len(idxs) == 1:
-            i = idxs[0]
-            sim, ws = built[i]
-            out[i] = _assemble(sim, ws, _run_jax(ws, draw_jitter(sim, ws)))
-            continue
-        C = len(idxs)
-        Cp = 1 << max(0, C - 1).bit_length()
-        pad = [idxs[0]] * (Cp - C)
-        cells = idxs + pad
-        ws0 = built[idxs[0]][1]
-        xs = {k: np.stack([built[i][1].xs[k] for i in cells])
-              for k in ws0.xs}
-        inv = {k: np.stack([built[i][1].inv[k] for i in cells])
-               for k in ws0.inv}
-        draws = [draw_jitter(built[i][0], built[i][1]) for i in cells]
-        jit = {k: np.stack([d[k] for d in draws]) for k in draws[0]}
-        _count_cells([built[i][1] for i in idxs], Cp)
-        ys = _run_program(_compiled_program(sig, _meta_key(ws0.meta), True),
-                          xs, inv, jit)
+    out: list = [None] * len(sims)
+
+    def assemble(idxs: list, ys: Mapping) -> None:
         for c, i in enumerate(idxs):
             sim, ws = built[i]
-            out[i] = _assemble(sim, ws, {k: v[c] for k, v in ys.items()})
+            out[i] = _assemble(sim, ws, ys if len(idxs) == 1
+                               else _Cell(ys, c))
+
+    pending: list = []      # (idxs, outputs) enqueued, not yet assembled
+    for sig, idxs in groups.items():
+        pending.append((idxs, _dispatch([built[i] for i in idxs], sig)))
+        spans.count("streamsim.programs_ahead", len(pending) - 1)
+        if len(pending) > 1:
+            assemble(*pending.pop(0))
+    for p in pending:
+        assemble(*p)
     return out

@@ -288,6 +288,40 @@ def test_device_loop_cell_axis_pads_are_inert():
 
 
 @requires_jax
+@pytest.mark.parametrize("cells", [
+    pytest.param((("dts", 0), ("prs-haproxy", 0), ("mss", 0)),
+                 id="three-signatures"),
+    pytest.param((("mss", 0), ("dts", 1), ("mss", 2)),
+                 id="two-cell-group-and-a-singleton"),
+    pytest.param((("mss", 0), ("mss", 1), ("dts", 2), ("mss", 3)),
+                 id="padded-three-cell-group-and-a-singleton")])
+def test_device_loop_pipelined_cells_match_solo_runs(cells):
+    """run_wave_cells enqueues each signature group's program before it
+    waits on the one before and assembles it; every sim's lanes are
+    bit-identical to the sim's own run_wave_results, in input order."""
+    from repro.core import jax_device_loop as dl
+    from repro.core.vectorized import VectorizedStreamSim
+
+    def sims():
+        return [VectorizedStreamSim(
+            _dl_spec(s, pattern="work_sharing", arch=a, msgs=64,
+                     engine="vectorized", device=None, jitter=0.02),
+            None, stack_seeds=[s, s + 100]) for a, s in cells]
+    n_sigs = len({dl.build_static(sim).signature() for sim in sims()})
+    assert n_sigs == len({a for a, _ in cells})
+    batched = dl.run_wave_cells(sims())
+    assert len(batched) == len(cells)
+    for got, sim in zip(batched, sims()):
+        want = dl.run_wave_results(sim)
+        assert len(got) == len(want) == 2
+        for a, b in zip(got, want):
+            assert a.spec == b.spec and a.path == b.path == "jax-device"
+            for f in ("consume_times", "rtts", "publish_starts"):
+                np.testing.assert_array_equal(getattr(a, f), getattr(b, f),
+                                              err_msg=f)
+
+
+@requires_jax
 @pytest.mark.slow
 def test_device_loop_stacked_pilot_bit_identical():
     """Lane 0 of a seed-stacked device run equals the solo device run

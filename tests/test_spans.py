@@ -126,19 +126,28 @@ def test_span_tree_of_a_request(recorded):
         "streamsim.program": "streamsim.run_many",
         "streamsim.assemble": "streamsim.run_many",
         "streamsim.dispatch": "streamsim.program",
-        "streamsim.wait": "streamsim.program",
-        "streamsim.fetch": "streamsim.program"}
+        "streamsim.wait": "streamsim.assemble",
+        "streamsim.fetch": "streamsim.assemble"}
     assert set(names) == set(want_parent)
     for i, n in enumerate(names):
         assert parent[i] == want_parent[n], n
-    # the three phases of a program run, in order, inside it
+    # one program ahead: each architecture's program is enqueued before
+    # the one before it is waited on and assembled
+    top = [n for i, n in enumerate(names)
+           if parent[i] == "streamsim.run_many" and n != "streamsim.engine"]
+    draw, prog, asm = ("streamsim.draw_jitter", "streamsim.program",
+                       "streamsim.assemble")
+    assert top == ["streamsim.build_static"] * len(ARCHS) + [
+        draw, prog, draw, prog, asm, draw, prog, asm, asm]
+    # a program span is its dispatch; its assembly waits on it, then
+    # copies back the two outputs a work-sharing result reads
     for i, n in enumerate(names):
-        if n == "streamsim.program":
-            kids = [j for j in range(len(spans_)) if spans_[j].parent == i]
-            assert [names[j] for j in kids] == [
-                "streamsim.dispatch", "streamsim.wait", "streamsim.fetch"]
-            assert spans_[kids[0]].start_ns >= spans_[i].start_ns
-            assert spans_[kids[-1]].end_ns <= spans_[i].end_ns
+        kids = [names[j] for j in range(len(spans_)) if spans_[j].parent == i]
+        if n == prog:
+            assert kids == ["streamsim.dispatch"]
+        if n == asm:
+            assert kids == ["streamsim.wait", "streamsim.fetch",
+                            "streamsim.fetch"]
     assert names.count("streamsim.summarize") == len(ARCHS) * len(SEEDS)
 
 
@@ -163,7 +172,12 @@ def test_counters_match_what_was_built(recorded):
         a.nbytes for ws in wss for d in (ws.xs, ws.inv) for a in d.values()
     ) + sum(a.nbytes for ws, sim in zip(wss, sims)
             for a in dl.draw_jitter(sim, ws).values())
-    assert c["streamsim.d2h_bytes"] > 0
+    # work sharing: assembly reads ``seen`` and ``pub_start``, each
+    # (nSteps, Np, L) float64
+    assert c["streamsim.d2h_bytes"] == sum(
+        2 * 8 * ws.meta["nSteps"] * ws.meta["Np"] * ws.meta["L"]
+        for ws in wss)
+    assert c["streamsim.programs_ahead"] == groups - 1
     # every program was already compiled by the unrecorded request
     assert c.get("streamsim.program_builds", 0) == 0
 
@@ -182,6 +196,8 @@ def test_cells_of_one_signature_share_a_program():
         run_many(specs)
     c = rec.counters
     assert c["streamsim.programs"] == 1 and c["streamsim.cells"] == 2
+    # one program: none goes ahead, and the count says so
+    assert c["streamsim.programs_ahead"] == 0
     assert c["streamsim.engines"] == 4
     ws = dl.build_static(JaxStreamSim(specs[0], None,
                                       stack_seeds=list(SEEDS)))
@@ -189,6 +205,47 @@ def test_cells_of_one_signature_share_a_program():
         2 * ws.meta["Np"] * ws.meta["L"] * ws.meta["nSteps"])
     assert c["streamsim.member_steps"] == (
         2 * int(ws.sizes["valid"].sum()) * ws.meta["L"])
+
+
+def _feedback_spec(seed: int) -> ExperimentSpec:
+    # inside the device program's feedback corridor (see _device_loop_ok)
+    return ExperimentSpec(
+        pattern="feedback", workload=get_workload("dstream"), arch="dts",
+        n_producers=4, n_consumers=2, total_messages=256,
+        params=SimParams(seed=seed, engine="jax", jax_device_loop=True,
+                         confirm_window=32, jitter=0.02))
+
+
+@pytest.mark.parametrize("pattern", ["work_sharing", "feedback"])
+def test_only_what_assembly_reads_is_copied_back(pattern):
+    """The results path copies back ``seen`` and ``pub_start``, and
+    ``rtt`` where the pattern has replies; never ``confirms`` or
+    ``depart``."""
+    spec = (_spec("mss", 1) if pattern == "work_sharing"
+            else _feedback_spec(1))
+    run_many([spec])
+    with spans.recording() as rec:
+        (r,) = run_many([spec])
+    assert r.path == "jax-device"
+    ws = dl.build_static(VectorizedStreamSim(spec))
+    m = ws.meta
+    read = ["seen", "pub_start"] + (["rtt"] if pattern == "feedback" else [])
+    assert list(dl._read_keys(m)) == read
+    assert rec.counters["streamsim.d2h_bytes"] == (
+        len(read) * 8 * m["nSteps"] * m["Np"] * m["L"])
+    fetches = [s for s in rec.spans if s.name == "streamsim.fetch"]
+    assert len(fetches) == len(read)
+
+
+def test_trace_returns_every_output():
+    """The step-for-step surface still copies back all five outputs."""
+    sim = VectorizedStreamSim(_spec("dts", 1))
+    ws = dl.build_static(sim)
+    ys = dl.run_wave_trace(ws, dl.draw_jitter(sim, ws), backend="jax")
+    assert set(ys) == {"pub_start", "confirms", "depart", "seen", "rtt"}
+    for k, a in ys.items():
+        assert isinstance(a, np.ndarray), k
+        assert a.shape[:2] == (ws.meta["nSteps"], ws.meta["Np"]), k
 
 
 def test_results_identical_with_recording_on_and_off(recorded):
