@@ -8,9 +8,12 @@ Two kinds of number are compared, each against a limit:
   more than once (the configuration's guarantee: each consumed exactly
   once).  All three have the limit 0;
 * over a sample of the window's requests drawn from ``--seed``: each
-  result's per-message consume times and publish starts, and its
-  summary throughput, against the plain reference of the configuration's
-  pattern (``chipbench/refs/<pattern>.py``), which sets those limits.
+  result's per-message consume times and publish starts, as a share of
+  the result's simulated span, and its summary throughput, against the
+  plain reference of the configuration's pattern
+  (``chipbench/refs/<pattern>.py``), which sets those limits.  Rounding
+  in a clock grows with the clock, so a gap in seconds would judge a
+  long simulation by a short one's limit.
 """
 
 from __future__ import annotations
@@ -59,10 +62,12 @@ def window_faults(cfg: dict, results: list) -> tuple[int, int, int]:
     return off, lost, dup
 
 
-def _gap(got: np.ndarray, want: np.ndarray) -> float:
+def _rel_gap(got: np.ndarray, want: np.ndarray, span: float) -> float:
+    """Widest gap of ``got`` to ``want`` as a share of ``span``; a shape
+    that differs, or any value not finite, reads ``inf``."""
     if got.shape != want.shape:
         return float("inf")
-    d = np.abs(np.asarray(got, dtype=np.float64) - want)
+    d = np.abs(np.asarray(got, dtype=np.float64) - want) / span
     return float(d.max()) if np.isfinite(d).all() else float("inf")
 
 
@@ -70,7 +75,9 @@ def sample_gaps(cfg: dict, ref, sample: list) -> dict:
     """Widest gaps to the reference over ``[(request, results,
     throughputs)]``: ``request`` is ``[(arch, seeds)]``, ``results``
     the program's results in the same order (architecture-major, one per
-    seed) and ``throughputs`` their summary throughputs."""
+    seed) and ``throughputs`` their summary throughputs.  A per-message
+    gap is taken as a share of its result's span, the reference's last
+    consume time in that lane."""
     consume = publish = thr = 0.0
     for request, results, throughputs in sample:
         i = 0
@@ -79,14 +86,16 @@ def sample_gaps(cfg: dict, ref, sample: list) -> dict:
             for lane in range(len(seeds)):
                 r = results[i]
                 wc = want["consume"][:, lane]
-                consume = max(consume, _gap(r.consume_times, wc))
-                publish = max(publish, _gap(
-                    r.publish_starts, np.sort(want["publish"][:, lane])))
+                span = float(wc.max())
+                consume = max(consume, _rel_gap(r.consume_times, wc, span))
+                publish = max(publish, _rel_gap(
+                    r.publish_starts, np.sort(want["publish"][:, lane]),
+                    span))
                 t_ref = ref.throughput(wc)
                 rel = abs(throughputs[i] - t_ref) / t_ref
                 thr = max(thr, rel if np.isfinite(rel) else float("inf"))
                 i += 1
-    return {"consume_gap_s": consume, "publish_gap_s": publish,
+    return {"consume_rel_gap": consume, "publish_rel_gap": publish,
             "throughput_rel_gap": thr}
 
 

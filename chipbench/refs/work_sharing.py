@@ -34,9 +34,10 @@ import numpy as np
 GBIT = 1e9
 
 #: Limits of the comparison with the program (see PERF.md for the
-#: readings each was set from): the widest gap of a per-message time in
-#: seconds, and of a summary throughput as a share of the reference's.
-LIMITS = {"consume_gap_s": 1e-9, "publish_gap_s": 1e-13,
+#: readings each was set from): the widest gap of a per-message time as a
+#: share of its result's simulated span, and of a summary throughput as a
+#: share of the reference's.
+LIMITS = {"consume_rel_gap": 1e-9, "publish_rel_gap": 2e-12,
           "throughput_rel_gap": 1e-9}
 
 _SHARED = ("dsn_in", "dsn_out", "dsn_int", "tunnel", "dts_gw", "ttun")

@@ -17,8 +17,11 @@ def ref():
     return check.load_reference(str(ROOT), "work_sharing")
 
 
-def test_control_fails_and_program_passes(ref):
-    cfg = tiny_config()
+@pytest.mark.parametrize("messages", [256, 1024])
+def test_control_fails_and_program_passes(ref, messages):
+    """At 1024 messages each producer publishes twice its confirm window,
+    so publish starts wait on confirms and their gap is read too."""
+    cfg = dict(tiny_config(), total_messages=messages)
     traffic = generator.Traffic({"lanes": 3},
                                 cfg["architectures"], 2 ** 31 + 11)
     client = run.Client(cfg)
@@ -31,7 +34,11 @@ def test_control_fails_and_program_passes(ref):
     assert check.verdict(program, ref.LIMITS)[0], program
     ok, checks = check.verdict(ctl, ref.LIMITS)
     assert not ok, checks
-    assert ctl["consume_gap_s"] > 10 * ref.LIMITS["consume_gap_s"]
+    assert ctl["consume_rel_gap"] > 10 * ref.LIMITS["consume_rel_gap"]
+    window = cfg["params"]["confirm_window"]
+    if messages > window * cfg["n_producers"]:
+        assert program["publish_rel_gap"] > 0, program
+        assert ctl["publish_rel_gap"] > 10 * ref.LIMITS["publish_rel_gap"]
 
 
 @pytest.fixture
@@ -63,7 +70,7 @@ def test_step_that_returns_its_state_unchanged(tmp_path, monkeypatch,
     monkeypatch.setattr(dl, "_wave_step", stuck)
     out = _run(tmp_path, monkeypatch)
     assert not out["correct"]
-    assert out["checks"]["consume_gap_s"][0] > 1e-6
+    assert out["checks"]["consume_rel_gap"][0] > 1e-6
 
 
 def test_half_of_each_batch_left_out(tmp_path, monkeypatch):
@@ -135,5 +142,5 @@ def test_one_answer_altered_where_it_is_produced(tmp_path, monkeypatch):
     monkeypatch.setattr(dl, "_run_program", altered)
     out = _run(tmp_path, monkeypatch)
     assert not out["correct"]
-    assert out["checks"]["consume_gap_s"][0] > out["checks"][
-        "consume_gap_s"][1]
+    assert out["checks"]["consume_rel_gap"][0] > out["checks"][
+        "consume_rel_gap"][1]

@@ -15,7 +15,10 @@ import numpy as np
 
 from _tiny import CELL, ROOT, args, make_checkout, stub_chip, tiny_config
 
-from chipbench import check, run, trace
+from chipbench import check, generator, run, trace
+
+BENCH_CELLS = [w["name"] for w in json.loads(
+    (ROOT / "BENCHMARK.json").read_text())["workloads"]]
 
 
 def test_cli_refuses_without_a_tpu():
@@ -135,6 +138,35 @@ def test_result_off_the_device_program_fails_the_run(tmp_path, monkeypatch):
     assert not out["correct"]
     assert out["checks"]["off_device_path"][0] > 0
     assert out["failed"] == out["attempted"]
+
+
+@pytest.mark.parametrize("cell", BENCH_CELLS)
+def test_every_cell_loads_and_builds_its_specs(cell):
+    """Each cell's configuration states only calibration the program
+    runs, and a request becomes one spec per architecture and lane with
+    the file's fleet, messages, tenants, workload and params.  Nothing
+    is run: the larger cells take seconds a request on the CPU."""
+    c = run.load_cell(str(ROOT), cell)
+    cfg = c.cfg
+    assert run.calibration_gaps(cfg) == []
+    request = generator.Traffic(c.mix, cfg["architectures"],
+                                2 ** 33 + 7).request(0)
+    specs = run.Client(cfg).specs(request)
+    assert len(specs) == len(cfg["architectures"]) * c.mix["lanes"]
+    assert [(s.arch, s.params.seed) for s in specs] == [
+        (arch, seed) for arch, seeds in request for seed in seeds]
+    for s in specs:
+        assert (s.pattern, s.n_producers, s.n_consumers, s.total_messages,
+                s.tenants, s.tenant_isolation) == (
+            cfg["pattern"], cfg["n_producers"], cfg["n_consumers"],
+            cfg["total_messages"], cfg["tenants"], cfg["tenant_isolation"])
+        assert (s.params.engine, s.params.jax_device_loop) == ("jax", True)
+        for key, value in cfg["params"].items():
+            assert getattr(s.params, key) == value, key
+        wl = dict(cfg["workload"])
+        assert s.workload.name == wl.pop("name")
+        for key, value in wl.items():
+            assert getattr(s.workload, key) == value, key
 
 
 def test_unknown_workload_is_refused(tmp_path):
