@@ -26,8 +26,11 @@ makes exactness cheap and banded where it does not:
   earliest-free ordering — the vectorized engine's pool semantics), so
   capacity/work conservation is exact and throughput parity holds at
   the vectorized engine's own band.
-* Cross-phase service *order* inside one generation is
-  publish -> deliver -> reply rather than globally time-sorted, so
+* Work is ordered across scan steps by step, inside one by arrival:
+  a step's legs share one combined serve.  Feedback replies are not
+  scheduled ahead: each waits in a pending-reply buffer in the scan
+  carry until the step whose request-leg clocks have reached it
+  (:func:`_reply_admit`), and drain steps serve what is left.  So
   latency-sensitive metrics (RTT) on **saturated** cells carry a wider
   tolerance than the cohort engines (see ``repro.core.parity``
   ``device_loop.*`` bands and docs/engines.md).
@@ -88,11 +91,13 @@ def device_loop_supported(spec: ExperimentSpec) -> tuple[bool, str]:
 
     Requires JAX, a work-sharing/feedback pattern, a statically
     flow-event-free regime (no credit blocking / reject-publish
-    reachable — the wave program carries no retry machinery), and a
+    reachable — the wave program carries no retry machinery), a
     generation size that keeps every consumer under half its prefetch
-    per generation.  Unsupported cells use the ordinary per-cohort jax
-    path (``RunResult.path`` says which ran); this is a *request*, not a
-    demand."""
+    per generation, and for feedback a confirm window that never binds,
+    a square fleet of 32 to 96 producers and a run of at least 32
+    messages per producer.  Unsupported cells use the ordinary
+    per-cohort jax path (``RunResult.path`` says which ran); this is a
+    *request*, not a demand."""
     from repro.core.jax_engine import jax_available
     from repro.core.simulator import InfeasibleConfiguration
     if not jax_available():
@@ -106,15 +111,9 @@ def device_loop_supported(spec: ExperimentSpec) -> tuple[bool, str]:
     return _device_loop_ok(sim)
 
 
-#: calibration knobs for the parity harness (tests never set these):
-#: force the reply-lag / egress-lag generation offsets instead of the
-#: static estimate in build_static
-_FORCE_DELAY: Optional[int] = None
-_FORCE_DEGR: Optional[int] = None
-
-
 def _device_loop_ok(sim: VectorizedStreamSim) -> tuple[bool, str]:
     spec, p = sim.spec, sim.p
+    M = spec.total_messages // max(1, spec.n_producers)
     if spec.pattern not in ("work_sharing", "feedback"):
         return False, f"pattern {spec.pattern!r} is not wave-formulated"
     if spec.total_messages // max(1, spec.n_producers) < 1:
@@ -134,55 +133,51 @@ def _device_loop_ok(sim: VectorizedStreamSim) -> tuple[bool, str]:
     # 6.8% at 512, 8.1% at 1024 msgs/producer — crossing the 6% band
     # between 256 and 512).  Every validated cell (bench e2e rows,
     # parity suites, the calibration grid) sits at <= 256.
-    if spec.total_messages // max(1, spec.n_producers) > 256:
-        return False, (f"run length {spec.total_messages // max(1, spec.n_producers)}"
-                       " msgs/producer > 256: generation-barrier drift "
-                       "accumulates over long runs (throughput deviation "
-                       "grows with nGen past the parity band)")
+    if M > 256:
+        return False, (f"run length {M} msgs/producer > 256: "
+                       "generation-barrier drift accumulates over long "
+                       "runs (throughput deviation grows with nGen past "
+                       "the parity band)")
     if spec.pattern == "feedback":
-        # The wave formulation carries feedback replies through a static
-        # delay-line pipeline (a fixed reply lag in units of
-        # generations).  That approximation was calibrated against the
-        # cohort engines across the deployment grid and holds only in a
-        # specific regime; outside it the static schedule under-tracks
-        # the cohort loop's continuous pipelining by far more than any
-        # parity band, so those cells stay on the per-cohort path:
-        #
-        # * coarse generations (G >= 4) — at G < 4 the per-generation
-        #   reply-lag discretization error dominates the schedule (no
-        #   constant lag fits; measured 28-57%% throughput deviation);
-        # * a window that binds but does not saturate, on a run not
-        #   much longer than the window (2 * G < W < M <= 2 * W with
-        #   M = msgs/producer) — at W <= 2G the run is a hard window
-        #   stall the cadence floor only approximates, at W >= M the
-        #   window never binds (burst-then-drain, no generation
-        #   cadence at all), and at M > 2W the constant reply lag
-        #   drifts over the run (RTT deviation grows with nGen);
-        # * not the single-broker ``mss`` arch, whose feedback cells
-        #   keep structural residuals across the whole (G, W) plane.
-        M = spec.total_messages // max(1, spec.n_producers)
+        # A reply waits in the pending-reply buffer until the publishes'
+        # clock reaches it (_reply_admit), which keeps replies and
+        # publishes in arrival order at the ingress.  Its delivery comes
+        # at the earliest one step later, so where replies queue behind a
+        # long ingress backlog they reach the egress only after request
+        # deliveries that arrive there later than they do: the last
+        # deliveries finish early and the replies late.  The regime below
+        # is where that stays inside the device_loop bands, measured
+        # against the vectorized engine (CPU, seeds 0-1, jitter 0.03; the
+        # grids are in docs/engines.md, "Supported regime").
         size = spec.workload.payload_bytes
         W = max(2, min(p.confirm_window, p.window_bytes // size))
-        if spec.arch == "mss":
-            return False, ("feedback on the single-broker mss arch is "
-                           "outside the wave model's validated regime")
-        if G < 4:
-            return False, (f"feedback generations too fine (G={G} < 4): "
-                           "the static reply-lag pipeline cannot track "
-                           "the cohort loop at this granularity")
-        if W <= 2 * G:
-            return False, (f"confirm window W={W} <= 2G={2 * G}: "
-                           "hard window-stall regime, outside the wave "
-                           "model's validated feedback corridor")
-        if W >= M:
-            return False, (f"confirm window W={W} >= msgs/producer {M}: "
-                           "the window never binds (burst regime), "
-                           "outside the wave model's validated corridor")
-        if M > 2 * W:
-            return False, (f"run length {M} msgs/producer > 2W={2 * W}: "
-                           "the static reply lag drifts over runs much "
-                           "longer than the confirm window (measured "
-                           "RTT deviation grows with nGen)")
+        if M > W:
+            # +11% throughput at Fig 6 c16 on dts (192 msgs/producer,
+            # W=128), -19% median RTT at 4x2 / 1024 msgs / W=32, -11%
+            # throughput at 32x32 on prs-haproxy (256 msgs, W=128)
+            return False, (f"feedback with a binding confirm window "
+                           f"(W={W} < {M} msgs/producer): the reply "
+                           "schedule drifts from the cohort loop by up to "
+                           "11% throughput and 19% median RTT")
+        nP, nC = spec.n_producers, spec.n_consumers
+        if nP != nC or not 32 <= nP <= 96:
+            # square fleets below 32: +9% to +12% throughput on dts at
+            # 8x8 and 16x16, +10% to +16% on mss at 4x2, +15% median RTT
+            # on prs-haproxy at 4x2; at 128x128, -12% on mss and +9% on
+            # dts; producers != consumers: -19% to +27% on dts at 32 or
+            # 48 producers over 8 to 128 consumers (48 msgs/producer or
+            # more)
+            return False, (f"feedback on a {nP} x {nC} fleet: the reply "
+                           "schedule follows the cohort loop only with "
+                           "as many consumers as producers, 32 to 96 of "
+                           "each (elsewhere up to 27% throughput and 15% "
+                           "median RTT off)")
+        if M < 32:
+            # -10% (dts) to -16% (prs-haproxy) throughput at 64x64 with
+            # 16 msgs/producer
+            return False, (f"feedback run of {M} msgs/producer < 32: too "
+                           "short for the reply schedule (up to 16% "
+                           "throughput off the cohort loop)")
     return True, ""
 
 
@@ -270,6 +265,22 @@ class WaveStatic:
                              for k, v in self.xs.items())),
                 tuple(sorted((k, v.shape, str(v.dtype))
                              for k, v in self.inv.items())))
+
+
+def _crossed(tab: np.ndarray, every: bool = False) -> set:
+    """Resource ids that some path of ``tab`` holds, or with ``every``
+    that each path holds (rows holding none are padding)."""
+    paths = tab.reshape((-1,) + tab.shape[-2:])
+    held = paths[..., 0] > 0
+    live = held.any(axis=1)
+    if not live.any():
+        return set()
+    rid = paths[..., 1].astype(np.int64)
+    on = np.zeros((paths.shape[0], int(rid.max()) + 1), dtype=bool)
+    p_i, s_i = np.nonzero(held)
+    on[p_i, rid[p_i, s_i]] = True
+    return set(np.flatnonzero(on[live].all(axis=0) if every
+                              else on.any(axis=0)).tolist())
 
 
 @spans.traced("streamsim.build_static")
@@ -360,17 +371,23 @@ def build_static(sim: VectorizedStreamSim) -> WaveStatic:
 
     # -- reply paths (feedback) -----------------------------------------
     if feedback:
-        rp_paths = {(int(c), pr): arch.reply_publish_path(
-            int(c_node[c]), int(c_bnode[c]), int(reply_home[pr]),
-            *tkey(int(c) // cpt))
-            for pr in range(nP)
-            for c in sorted({int(x) for qi in prod_queues[pr]
-                             for x in q_consumers[qi]})}
-        rp_slots, S_rp = _path_slots(rp_paths, res_index, rid_of,
-                                     reply_size)
+        # a reply-publish path depends on the consumer's node, broker and
+        # tenant and the producer's reply home only: each distinct one is
+        # built and aligned once (alignment reads the distinct paths)
+        rp_key = {(int(c), pr): (int(c_node[c]), int(c_bnode[c]),
+                                 int(reply_home[pr])) + tkey(int(c) // cpt)
+                  for pr in range(nP)
+                  for c in sorted({int(x) for qi in prod_queues[pr]
+                                   for x in q_consumers[qi]})}
+        keys = sorted(set(rp_key.values()))
+        rp_slots, S_rp = _path_slots(
+            {k: arch.reply_publish_path(*k) for k in keys}, res_index,
+            rid_of, reply_size)
+        k_of = {k: i for i, k in enumerate(keys)}
+        cp = np.array(list(rp_key), dtype=np.int64).reshape(-1, 2)
         rp_tab = np.zeros((nC, nP, S_rp, 4))
-        for (c, pr), rows in rp_slots.items():
-            rp_tab[c, pr] = rows
+        rp_tab[cp[:, 0], cp[:, 1]] = np.array(
+            [rp_slots[k] for k in keys])[[k_of[k] for k in rp_key.values()]]
         rd_aligned = {}
         S_rd = 0
         for pr in range(nP):
@@ -458,83 +475,18 @@ def build_static(sim: VectorizedStreamSim) -> WaveStatic:
         [cnt_p, np.zeros((nGen, 1), dtype=np.int64)], axis=1)
 
     # software-pipelined scan inputs: step g publishes generation g and
-    # delivers generation g-1; the reply legs trail by an *adaptive*
-    # lag — replies for generation g re-enter the shared ingress
-    # resources roughly a delivery-path-plus-receive latency after the
-    # publishes, during which the confirm window lets publishes run up
-    # to W/G generations ahead.  Serving reply-publish at step
-    # g+1+DELAY (and reply-delivery one step later) keeps each step's
-    # combined serve populated with flows whose *arrival clocks*
-    # actually coexist, which is what makes arrival-order service at
-    # shared chains match the engines.  Every leg's static arrays are
-    # shifted by its offset, with all-False validity masks filling the
-    # prologue/drain steps.
-    # The reply lag DELAY (in generations) is physical, not a window
-    # artifact: rp(g) enqueues one publish+delivery+receive+process
-    # path-latency after pub(g), during which publishes advance one
-    # generation per tau — the per-generation cadence, itself the max
-    # of the busiest chain's per-generation work and the confirm-
-    # window stall cadence (when W binds, a generation can only clear
-    # admission every conf-roundtrip/(W/G)).  Serving reply-publish at
-    # step g+1+DELAY (and reply-delivery one step later) keeps each
-    # step's combined serve populated with flows whose *arrival
-    # clocks* actually coexist, which is what makes arrival-order
-    # service at shared chains match the engines.  Every leg's static
-    # arrays are shifted by its offset, with all-False validity masks
-    # filling the prologue/drain steps.
-    if feedback:
-        work = np.zeros((2, NR))
-        for m_i in range(N):
-            if not valid[0, m_i]:
-                continue
-            pr_i, q_i = int(pr_m[0][m_i]), int(q_m[0, m_i])
-            legs = [(0, pub_tab[pub_ci[0, m_i]]), (1, del_tab[q_i, 0]),
-                    (0, rp_tab[int(q_cons_tab[q_i, 0]), pr_i]),
-                    (1, rd_tab[pr_i])]
-            for sd, rows in legs:
-                for kk_, r_, h_, _l in rows:
-                    if kk_ > 0:
-                        work[sd, int(r_)] += (
-                            h_ / max(1, int(k_arr[int(r_)])))
-        tau = float(work.max())
-
-        def combo_sum(tab: np.ndarray) -> float:
-            t = tab.reshape(-1, tab.shape[-2], 4)
-            live = (t[:, :, 0] > 0).any(axis=1)
-            tot = (t[:, :, 2] + t[:, :, 3]).sum(axis=1)
-            return float(tot[live].mean()) if live.any() else 0.0
-
-        lag_pub = combo_sum(pub_tab)
-        # window-bound cadence floor: with at most W unconfirmed, a
-        # generation clears admission every pub-confirm-roundtrip per
-        # W/G outstanding generations
-        tau_gen = max(tau, lag_pub / max(1.0, W / G))
-        # pub enqueue -> reply-publish enqueue path latency
-        lag_rp = (lag_pub + combo_sum(del_tab)
-                  + sim._recv_latency(size) + sim._proc_s)
-        delay = (int(np.clip(round(lag_rp / tau_gen), 1, nGen))
-                 if tau_gen > 0 else 1)
-        if _FORCE_DELAY is not None:       # debug/calibration knob
-            delay = int(np.clip(_FORCE_DELAY, 1, nGen))
-        # egress alignment: reply-deliveries re-enter the egress
-        # resources a delivery + receive + reply-publish lag after the
-        # corresponding deliveries, so rd(g) genuinely contends with
-        # del(g + De) there.  The delivery leg is delayed by
-        # dlag = delay - De so the two flows meet in the same step's
-        # combined serve.  Cross-direction step offsets are free:
-        # pub/rp and del/rd live on different chain copies.
-        lag_e = (combo_sum(del_tab) + sim._recv_latency(size)
-                 + combo_sum(rp_tab))
-        d_egr = (int(np.clip(round(lag_e / tau_gen), 1,
-                             max(1, delay - 1)))
-                 if tau_gen > 0 else 1)
-        if _FORCE_DEGR is not None:        # debug/calibration knob
-            d_egr = int(np.clip(_FORCE_DEGR, 1, max(1, delay - 1)))
-        dlag = delay - d_egr
-    else:
-        delay, d_egr, dlag = 1, 1, 0
-    depth = (2 + delay) if feedback else 1
-    nSteps = nGen + depth
+    # delivers generation g-1.  Feedback replies are not scheduled
+    # here: each consumed message's reply waits in the scan carry's
+    # pending-reply buffer until the publish and delivery clocks reach
+    # it (``_reply_admit``).  After the nGen + 1 steps of the request
+    # legs come ``drain`` steps that serve whatever replies are left.
+    # A step serves at most Np replies per leg, all of them once no
+    # request leg is left, so nGen steps drain a full buffer through
+    # reply-publish, and each producer's reply-deliveries take at most
+    # ceil(M / P) more: its reply n waits on the ack of reply n - P.
+    drain = (nGen + -(-M // int(p.prefetch)) + 1) if feedback else 0
+    nSteps = nGen + 1 + drain
+    C = max(nP * M, Np) if feedback else 0
 
     def shift(a: np.ndarray, by: int) -> np.ndarray:
         out = np.zeros((nSteps,) + a.shape[1:], dtype=a.dtype)
@@ -545,24 +497,34 @@ def build_static(sim: VectorizedStreamSim) -> WaveStatic:
         Np=Np, L=L, S_pub=S_pub, S_del=S_del, S_rp=S_rp, S_rd=S_rd,
         S_max=S_max, feedback=feedback, NR=NR, NCH=NCH, nq=nq, nC=nC,
         nP=nP, kq_max=kq_max, P=int(p.prefetch), B=int(p.ack_batch),
-        W=W, G=G, nGen=nGen, nSteps=nSteps, delay=delay, dlag=dlag,
-        ring=d_egr, pools=tuple(pools))
+        W=W, G=G, nGen=nGen, nSteps=nSteps, pools=tuple(pools))
     xs = dict(
         pub_valid=shift(valid, 0), pub_pr=shift(pr_mat, 0),
         pub_ci=shift(pub_ci, 0), pub_has_gate=shift(has_gate, 0),
         pub_conf_slot=shift(np.where(valid, conf_slot, W), 0),
-        del_valid=shift(valid, 1 + dlag), del_q=shift(q_m, 1 + dlag),
-        del_cnt_q=shift(cnt_q, 1 + dlag),
-        del_cnt_c=shift(cnt_c, 1 + dlag),
-        dly=np.arange(nSteps) % d_egr,
-        dlyp=np.arange(nSteps) % (1 + dlag))
+        del_valid=shift(valid, 1), del_q=shift(q_m, 1),
+        del_cnt_q=shift(cnt_q, 1), del_cnt_c=shift(cnt_c, 1))
     xs["pub_conf_slot"][nGen:] = W      # drain steps hit the scratch slot
     if feedback:
-        xs.update(rp_valid=shift(valid, 1 + delay),
-                  rp_pr=shift(pr_mat, 1 + delay),
-                  rp_cnt_p=shift(cnt_p, 1 + delay),
-                  rd_valid=shift(valid, 2 + delay),
-                  rd_pr=shift(pr_mat, 2 + delay))
+        # a link on every path of a request leg that every path of a
+        # reply leg crosses the other way, and the other request leg
+        # never (PRS's tunnel: publishes in, reply-deliveries out), is
+        # one FIFO chain; every other resource keeps one chain per
+        # direction, as in work sharing (see _serve_leg)
+        pipes = {rid_of[k] for k in res_keys if res_index[k].kind == "pipe"}
+        pub_r, del_r = _crossed(pub_tab), _crossed(del_tab)
+        merged = tuple(sorted(pipes & (
+            ((_crossed(rd_tab, True) & _crossed(pub_tab, True)) - del_r)
+            | ((_crossed(rp_tab, True) & _crossed(del_tab, True)) - pub_r))))
+        # the ingress chains that replies share with publishes
+        shared = tuple(int(chain_base[r]) + j
+                       for r in sorted(_crossed(rp_tab) & pub_r)
+                       for j in range(int(k_arr[r])))
+        meta.update(M=M, C=C, drain=drain, merged=merged, shared=shared,
+                    horizon=float(sim._slack))
+        # the buffer row of each delivered message (producer * M +
+        # index); invalid members write the dummy row C
+        xs["del_mid"] = shift(np.where(valid, mem_id, C), 1)
     inv_arrays = dict(
         pub_tab=pub_tab, del_tab=del_tab, rp_tab=rp_tab, rd_tab=rd_tab,
         q_cons_tab=q_cons_tab, kq=kq, k_arr=k_arr, chain_base=chain_base,
@@ -583,7 +545,9 @@ def draw_jitter(sim: VectorizedStreamSim, ws: WaveStatic) -> dict:
     the engine's per-seed streams.  One flat draw per lane in a fixed
     layout keeps each lane's realization independent of how many other
     lanes are stacked (lane-addition inertness by construction).
-    Returned pre-shifted per pipeline leg, ready to merge into ``xs``."""
+    Returned pre-shifted per pipeline leg, ready to merge into ``xs``;
+    with feedback, the reply legs' draws by message row as well, which
+    the program reads whole rather than step by step."""
     s, m = ws.sizes, ws.meta
     j = sim.p.jitter
     raw = np.zeros((s["nGen"], s["n_jit"], s["Np"], s["L"]))
@@ -600,12 +564,17 @@ def draw_jitter(sim: VectorizedStreamSim, ws: WaveStatic) -> dict:
 
     S = m["S_max"]
     jit = dict(pub_jit=shift(raw[:, :S], 0),
-               del_jit=shift(raw[:, S:2 * S], 1 + m["dlag"]),
-               proc_jit=shift(raw[:, 2 * S], 1 + m["dlag"]))
+               del_jit=shift(raw[:, S:2 * S], 1),
+               proc_jit=shift(raw[:, 2 * S], 1))
     if m["feedback"]:
-        jit["rp_jit"] = shift(raw[:, 2 * S + 1:3 * S + 1],
-                              1 + m["delay"])
-        jit["rd_jit"] = shift(raw[:, 3 * S + 1:], 2 + m["delay"])
+        # a reply's draws follow its message, whichever step serves it:
+        # rows by message (``producer * M + index``), as in the buffer
+        g, mem = np.nonzero(s["valid"])
+        rows = s["mem_id"][g, mem]
+        for k, lo in (("rp_jit", 2 * S + 1), ("rd_jit", 3 * S + 1)):
+            by_row = np.zeros((m["C"] + 1, S, s["L"]))
+            by_row[rows] = raw[g, lo:lo + S, mem]
+            jit[k] = by_row
     return jit
 
 
@@ -813,8 +782,11 @@ def _transit(ops: Any, free: Array, t: Array, slots: Array, jit: Array,
         rid = row[:, 1].astype(xp.int64)
         hold = xp.where((kind > 0) & valid,
                         row[:, 2], 0.0)[:, None] * (1.0 + x["jit"])
+        sd = side
+        for r in meta.get("merged", ()):
+            sd = xp.where(rid == r, 0, sd)
         return _serve_leg(ops, free, t, hold, kind, rid, row[:, 3],
-                          valid, side, meta, chain_base, k_arr), {}
+                          valid, sd, meta, chain_base, k_arr), {}
 
     (free, t), _ = ops.scan(slot, (free, t), {"row": slots, "jit": jit},
                             slots.shape[0])
@@ -857,21 +829,90 @@ def _seg_pos(ops: Any, key_sorted: Array, Np: int) -> tuple:
     return start, pos
 
 
+def _first_eligible(ops: Any, arrival: Array, eligible: Array,
+                    n: int) -> tuple:
+    """Rows of the ``n`` eligible entries with the earliest pilot-lane
+    arrival (ties by row), and which of them are eligible at all."""
+    xp = ops.xp
+    rows = xp.arange(arrival.shape[0])
+    order = ops.lexsort((rows, xp.where(eligible, arrival[:, 0], _INF),
+                         (~eligible).astype(xp.int64)))
+    sel = order[:n]
+    return sel, eligible[sel]
+
+
+def _latest(xp: Any, t: Array, valid: Array) -> Array:
+    """Latest valid pilot-lane time, ``+inf`` where none is valid."""
+    return xp.where(valid.any(), xp.max(xp.where(valid, t[:, 0], -_INF)),
+                    _INF)
+
+
+def _reply_admit(ops: Any, meta: dict, rep: dict, hz_rp: Array,
+                 win_rp: tuple, hz_rd: Array) -> tuple:
+    """Take this step's replies out of the pending-reply buffer.
+
+    A reply is published once its consumer's processing end (``rp_t``)
+    is no later than ``hz_rp``, or lies inside ``win_rp``; it is
+    delivered once its departure from the producer's reply queue is no
+    later than ``hz_rd``.  Up to ``Np`` of each go, earliest first.
+    The departure is the reply's arrival at the queue, gated, past the
+    prefetch window, on the producer's ack of its reply ``n - P``: a
+    reply whose gate is not known yet (that ack not yet taken) waits."""
+    xp = ops.xp
+    Np, P, M, C, nP = meta["Np"], meta["P"], meta["M"], meta["C"], meta["nP"]
+    row_pr = xp.minimum(xp.arange(C + 1) // M, nP)
+    a = rep["rp_t"][:, 0]
+    rp_sel, v_rp = _first_eligible(
+        ops, rep["rp_t"], rep["rp_v"] & ((a <= hz_rp) | (
+            (a >= win_rp[0]) & (a <= win_rp[1]))), Np)
+    n_on = rep["rd_i"]
+    slot = n_on % P
+    gated = n_on >= P
+    dep = xp.where(gated[:, None],
+                   xp.maximum(rep["rd_t"], rep["prep"][row_pr, slot]),
+                   rep["rd_t"])
+    known = ~gated | (rep["ptag"][row_pr, slot] == n_on - P)
+    rd_sel, v_rd = _first_eligible(
+        ops, dep, rep["rd_v"] & known & (dep[:, 0] <= hz_rd), Np)
+    dummy = C
+    rp_rows = xp.where(v_rp, rp_sel, dummy)
+    rd_rows = xp.where(v_rd, rd_sel, dummy)
+    rep = dict(rep, rp_v=ops.at_set(rep["rp_v"], rp_rows, False),
+               rd_v=ops.at_set(rep["rd_v"], rd_rows, False))
+    rp = dict(row=rp_rows, valid=v_rp, t=rep["rp_t"][rp_sel],
+              cons=rep["rp_c"][rp_sel], pr=row_pr[rp_rows])
+    rd = dict(row=rd_rows, valid=v_rd, t=dep[rd_sel],
+              n_on=n_on[rd_sel], pr=row_pr[rd_rows])
+    return rep, rp, rd
+
+
+def _cat(xp: Any, blocks: list, lo: int = 0,
+         hi: Optional[int] = None) -> tuple:
+    """The combined member axis of ``blocks`` ``(arrival, valid, slots,
+    jitter, side)``, over their aligned slots ``[lo, hi)``."""
+    return (xp.concatenate([b[0] for b in blocks], axis=0),
+            xp.concatenate([b[2][lo:hi] for b in blocks], axis=1),
+            xp.concatenate([b[3][lo:hi] for b in blocks], axis=1),
+            xp.concatenate([b[1] for b in blocks], axis=0),
+            xp.concatenate([xp.full(b[1].shape[0], b[4], dtype=xp.int64)
+                            for b in blocks]))
+
+
 def _wave_step(ops: Any, meta: dict, inv: dict, carry: dict,
                x: dict) -> tuple[dict, dict]:
     """One *pipelined* scan step.
 
-    Step ``g`` publishes generation ``g``, delivers ``g-1``,
-    reply-publishes ``g-2`` and reply-delivers ``g-3`` — mirroring the
-    engines' steady state, where all four flows are concurrently in
-    flight with exactly these generation offsets.  Every leg's arrivals
-    are known at step entry (hand-off rides the ``pend_*`` carries), so
-    all four transits run as ONE combined serve over a concatenated
+    Step ``g`` publishes generation ``g`` and delivers ``g-1``; with
+    feedback it also reply-publishes and reply-delivers the replies
+    that :func:`_reply_admit` takes from the pending-reply buffer.
+    Every leg's arrivals are known at step entry (the publish ->
+    delivery hand-off rides the ``t_enq`` carry, replies the buffer),
+    so all transits run as ONE combined serve over a concatenated
     member axis: shared resources see the competing flows in true
     arrival order, not phase-convoy order — the property that keeps
     throughput and RTT inside the parity bands.  Scan length is
-    ``nGen`` plus the pipeline depth, shifted validity masks draining
-    the tail."""
+    ``nGen + 1`` plus, with feedback, the reply drain steps; shifted
+    validity masks drain the tail."""
     xp = ops.xp
     Np, L, P, B = meta["Np"], meta["L"], meta["P"], meta["B"]
     nC, nP, fb = meta["nC"], meta["nP"], meta["feedback"]
@@ -891,9 +932,7 @@ def _wave_step(ops: Any, meta: dict, inv: dict, carry: dict,
     # round robin with prefetch-ring gates
     with ops.scope("pump_assign"):
         v_del, q = x["del_valid"], x["del_q"]
-        dlyp = x["dlyp"]
-        t_enq_prev = carry["pend_pub"]["t_enq"][dlyp]
-        pub_start_prev = carry["pend_pub"]["pub_start"][dlyp]
+        t_enq_prev = carry["t_enq"]
         oq = ops.lexsort((idx, xp.where(v_del, t_enq_prev[:, 0], _INF),
                           xp.where(v_del, q, meta["nq"])))
         q_s = q[oq]
@@ -911,50 +950,61 @@ def _wave_step(ops: Any, meta: dict, inv: dict, carry: dict,
         depart = ops.at_set(xp.zeros_like(t_enq_prev), oq, depart_s)
         depart = xp.where(v_del[:, None], depart, _INF)
 
+    blocks = [
+        (pub_start, v_pub,
+         xp.swapaxes(inv["pub_tab"][x["pub_ci"]], 0, 1), x["pub_jit"], 0),
+        (depart, v_del,
+         xp.swapaxes(inv["del_tab"][q, slotc], 0, 1), x["del_jit"], 1),
+    ]
     # ---- combined transit: all legs, one serve per aligned slot -------
-    with ops.scope("transit"):
-        blocks = [
-            (pub_start, v_pub,
-             xp.swapaxes(inv["pub_tab"][x["pub_ci"]], 0, 1), x["pub_jit"]),
-            (depart, v_del,
-             xp.swapaxes(inv["del_tab"][q, slotc], 0, 1), x["del_jit"]),
-        ]
-        if fb:
-            # the delivery->reply delay line: slot ``dly`` holds the
-            # entry written ``delay`` steps ago (generation g-1-delay),
-            # which is exactly the generation this step reply-publishes
-            dly = x["dly"]
-            pend_b = {k: v[dly] for k, v in carry["pend_del"].items()}
-            pend_c = carry["pend_rep"]
-            v_rp, rp_pr = x["rp_valid"], x["rp_pr"]
-            v_rd, rd_pr = x["rd_valid"], x["rd_pr"]
-            blocks.append(
-                (pend_b["seen"], v_rp,
-                 xp.swapaxes(inv["rp_tab"][xp.clip(pend_b["cons"], 0,
-                                                   nC - 1), rp_pr], 0, 1),
-                 x["rp_jit"]))
-            blocks.append(
-                (pend_c["rdep"], v_rd,
-                 xp.swapaxes(inv["rd_tab"][rd_pr], 0, 1), x["rd_jit"]))
-        a_c = xp.concatenate([b[0] for b in blocks], axis=0)
-        v_c = xp.concatenate([b[1] for b in blocks], axis=0)
-        slots_c = xp.concatenate([b[2] for b in blocks], axis=1)
-        jit_c = xp.concatenate([b[3] for b in blocks], axis=1)
-        # direction per block: publish/reply-publish are ingress-bound
-        # (0), delivery/reply-delivery egress-bound (1)
-        side_c = xp.concatenate(
-            [xp.full(Np, s, dtype=xp.int64)
-             for s in ((0, 1, 0, 1) if fb else (0, 1))])
-        free, t_c = _transit(ops, carry["free"], a_c, slots_c, jit_c, v_c,
-                             side_c, meta, inv["chain_base"], inv["k_arr"])
-        t_enq = t_c[:Np]
-        t_land = t_c[Np:2 * Np]
-        # the publish->delivery hand-off: this step's enqueues wait in
-        # the delay line for the delivery leg of a later step
-        pend_pub = dict(
-            t_enq=ops.at_set(carry["pend_pub"]["t_enq"], dlyp, t_enq),
-            pub_start=ops.at_set(carry["pend_pub"]["pub_start"], dlyp,
-                                 pub_start))
+    # (direction per block: publish/reply-publish are ingress-bound (0),
+    # delivery/reply-delivery egress-bound (1))
+    if not fb:
+        with ops.scope("transit"):
+            free, t_c = _transit(ops, carry["free"], *_cat(xp, blocks),
+                                 meta, inv["chain_base"], inv["k_arr"])
+    else:
+        # the request legs take their first hop (the producer's NIC, the
+        # queue's broker) alone; replies then join the step whose
+        # request legs have reached them past it: the publishes for
+        # reply-publish, the deliveries for reply-delivery.  A step
+        # without request legs (the drain) takes any.
+        with ops.scope("transit"):
+            free, t_req = _transit(ops, carry["free"],
+                                   *_cat(xp, blocks, 0, 1), meta,
+                                   inv["chain_base"], inv["k_arr"])
+        with ops.scope("reply_admit"):
+            # the clocks the request legs reached past their first hop;
+            # replies trail the publishes by the cohort engines' own
+            # horizon, as their batches serve up to it ahead
+            hz_pub = _latest(xp, t_req[:Np], v_pub) - meta["horizon"]
+            hz_del = _latest(xp, t_req[Np:], v_del)
+            # a reply that finds the ingress chains it shares with the
+            # publishes idle cannot overtake a publish served before
+            # it: it goes once the deliveries' clock reaches it
+            idle = (xp.max(free[xp.asarray(meta["shared"]), 0])
+                    if meta["shared"] else -_INF)
+            rep, rp, rd = _reply_admit(ops, meta, carry["reply"], hz_pub,
+                                       (idle, hz_del), hz_del)
+            replies = [
+                (rp["t"], rp["valid"],
+                 xp.swapaxes(inv["rp_tab"][xp.clip(rp["cons"], 0, nC - 1),
+                                           xp.clip(rp["pr"], 0, nP - 1)],
+                             0, 1),
+                 xp.swapaxes(inv["rp_jit"][rp["row"]], 0, 1), 0),
+                (rd["t"], rd["valid"],
+                 xp.swapaxes(inv["rd_tab"][xp.clip(rd["pr"], 0, nP - 1)],
+                             0, 1),
+                 xp.swapaxes(inv["rd_jit"][rd["row"]], 0, 1), 1)]
+        with ops.scope("transit"):
+            free, t_rep = _transit(ops, free, *_cat(xp, replies, 0, 1),
+                                   meta, inv["chain_base"], inv["k_arr"])
+            t0 = (t_req[:Np], t_req[Np:], t_rep[:Np], t_rep[Np:])
+            blocks = [(t,) + b[1:] for t, b in zip(t0, blocks + replies)]
+            free, t_c = _transit(ops, free, *_cat(xp, blocks, 1), meta,
+                                 inv["chain_base"], inv["k_arr"])
+    t_enq = t_c[:Np]
+    t_land = t_c[Np:2 * Np]
 
     # ---- publish(g) epilogue: confirms feed the admission ring --------
     with ops.scope("publish_gate"):
@@ -992,31 +1042,32 @@ def _wave_step(ops: Any, meta: dict, inv: dict, carry: dict,
     ys = dict(pub_start=pub_start, confirms=confirms, depart=depart,
               seen=seen)
     carry = dict(carry, free=free, conf=conf, proc=proc, ack=ack,
-                 pend_pub=pend_pub)
+                 t_enq=t_enq)
     if not fb:
-        ys["rtt"] = xp.full_like(seen, _INF)
         return carry, ys
 
-    # ---- reply-publish(g-2) epilogue: per-producer reply pump ---------
+    # ---- reply-publish epilogue: number each reply in its producer's
+    #      reply queue, in arrival order; it waits there for delivery --
     with ops.scope("reply_pump"):
         t_renq = t_c[2 * Np:3 * Np]
-        pch = xp.where(v_rp, rp_pr, nP)
+        v_rp = rp["valid"]
+        pch = xp.where(v_rp, rp["pr"], nP)
         opr = ops.lexsort((idx, xp.where(v_rp, t_renq[:, 0], _INF), pch))
         pr_s = pch[opr]
         _, posp = _seg_pos(ops, pr_s, Np)
-        idx_on_p = x["rp_cnt_p"][pr_s] + posp
-        rdep_s = _pump_assign(ops, carry["prep"], t_renq[opr], pr_s,
-                              idx_on_p, v_rp[opr], meta)
-        rdep = ops.at_set(xp.zeros_like(t_renq), opr, rdep_s)
-        rdep = xp.where(v_rp[:, None], rdep, _INF)
-        idxp = ops.at_set(xp.zeros(Np, dtype=idx_on_p.dtype), opr,
-                          idx_on_p)
+        n_on_s = rep["cnt"][pr_s] + posp
+        n_on = ops.at_set(xp.zeros(Np, dtype=n_on_s.dtype), opr, n_on_s)
+        rep = dict(
+            rep, cnt=ops.at_max(rep["cnt"], pr_s, n_on_s + 1),
+            rd_t=ops.at_set(rep["rd_t"], rp["row"], t_renq),
+            rd_i=ops.at_set(rep["rd_i"], rp["row"], n_on),
+            rd_v=ops.at_set(rep["rd_v"], rp["row"], v_rp))
 
-    # ---- reply-delivery(g-3) epilogue: RTTs + producer ack batching ---
+    # ---- reply-delivery epilogue: receive times + producer ack batching
     with ops.scope("reply_ack"):
+        v_rd = rd["valid"]
         t_seen = t_c[3 * Np:] + recv_rep
-        rtt = xp.where(v_rd[:, None], t_seen - pend_c["pub_start"], _INF)
-        pch_d = xp.where(v_rd, rd_pr, nP)
+        pch_d = xp.where(v_rd, rd["pr"], nP)
         opd = ops.lexsort((idx, xp.where(v_rd, t_seen[:, 0], _INF),
                            pch_d))
         pd_s = pch_d[opd]
@@ -1024,60 +1075,94 @@ def _wave_step(ops: Any, meta: dict, inv: dict, carry: dict,
         boundary = ((((posd + 1) % B) == 0)
                     | xp.concatenate([start_p[1:], xp.ones(1, dtype=bool)]))
         nb = _next_boundary(ops, boundary | (pd_s >= nP), start_p, Np)
-        prep = ops.at_set(carry["prep"],
-                          (pd_s, pend_c["idx_on_p"][opd] % P),
-                          t_seen[opd][nb] + ctrl)
-        new_b = dict(seen=seen, cons=cons, pub_start=pub_start_prev)
-        pend_del = {k: ops.at_set(carry["pend_del"][k], dly, new_b[k])
-                    for k in new_b}
+        ring = (pd_s, rd["n_on"][opd] % P)
+        rep = dict(
+            rep, back=ops.at_set(rep["back"], rd["row"], t_seen),
+            prep=ops.at_set(rep["prep"], ring, t_seen[opd][nb] + ctrl),
+            ptag=ops.at_set(rep["ptag"], ring, rd["n_on"][opd]))
 
-    ys["rtt"] = rtt
-    carry = dict(
-        carry, prep=prep, pend_del=pend_del,
-        pend_rep=dict(rdep=rdep, idx_on_p=idxp,
-                      pub_start=pend_b["pub_start"]))
-    return carry, ys
+    # ---- this step's consumed messages join the buffer ----------------
+    with ops.scope("reply_admit"):
+        rows = x["del_mid"]
+        rep = dict(rep, rp_t=ops.at_set(rep["rp_t"], rows, seen),
+                   rp_c=ops.at_set(rep["rp_c"], rows, cons),
+                   rp_v=ops.at_set(rep["rp_v"], rows, v_del))
+        rep["peak"] = xp.maximum(
+            rep["peak"], (rep["rp_v"].sum() + rep["rd_v"].sum()).astype(
+                rep["peak"].dtype))
+    return dict(carry, reply=rep), ys
 
 
 def _init_carry(xp: Any, meta: dict) -> dict:
     # trailing dummy rows/slots absorb the masked writes of invalid
-    # pad members: conf slot W, ack row nC, proc row nC, prep row nP
+    # pad members: conf slot W, ack row nC, proc row nC, and with
+    # feedback buffer row C and producer row nP
     L, Np = meta["L"], meta["Np"]
-    return dict(
+    carry = dict(
         free=xp.zeros((2 * meta["NCH"] + 1, L)),
         conf=xp.zeros((meta["nP"], meta["W"] + 1, L)),
         ack=xp.zeros((meta["nC"] + 1, meta["P"], L)),
         proc=xp.zeros((meta["nC"] + 1, L)),
-        prep=xp.zeros((meta["nP"] + 1, meta["P"], L)),
-        # delay-line rings: publish->delivery trails by 1+dlag steps,
-        # delivery->reply-publish by ``ring`` steps; slot = step % len
-        pend_pub=dict(t_enq=xp.zeros((1 + meta["dlag"], Np, L)),
-                      pub_start=xp.zeros((1 + meta["dlag"], Np, L))),
-        pend_del=dict(seen=xp.zeros((meta["ring"], Np, L)),
-                      cons=xp.zeros((meta["ring"], Np),
-                                    dtype=xp.int64),
-                      pub_start=xp.zeros((meta["ring"], Np, L))),
-        pend_rep=dict(rdep=xp.zeros((Np, L)),
-                      idx_on_p=xp.zeros(Np, dtype=xp.int64),
-                      pub_start=xp.zeros((Np, L))))
+        t_enq=xp.zeros((Np, L)))
+    if meta["feedback"]:
+        C, nP, P = meta["C"], meta["nP"], meta["P"]
+        carry["reply"] = dict(
+            # awaiting reply-publish: consumer's processing end, consumer
+            rp_t=xp.zeros((C + 1, L)), rp_c=xp.zeros(C + 1, dtype=xp.int64),
+            rp_v=xp.zeros(C + 1, dtype=bool),
+            # awaiting reply-delivery: arrival at the producer's reply
+            # queue, its number in that queue
+            rd_t=xp.zeros((C + 1, L)), rd_i=xp.zeros(C + 1, dtype=xp.int64),
+            rd_v=xp.zeros(C + 1, dtype=bool),
+            # reply receive times (+inf: not received)
+            back=xp.full((C + 1, L), _INF),
+            # per producer: replies numbered so far, the ack ring and
+            # the reply number whose ack each slot holds
+            cnt=xp.zeros(nP + 1, dtype=xp.int64),
+            prep=xp.zeros((nP + 1, P, L)),
+            ptag=xp.full((nP + 1, P), -1, dtype=xp.int64),
+            peak=xp.zeros((), dtype=xp.int64))
+    return carry
+
+
+def _finals(meta: dict, carry: dict) -> dict:
+    """Outputs taken from the carry at the end of the scan: each
+    message's reply receive time, by message row, and the largest
+    pending-reply count."""
+    if not meta["feedback"]:
+        return {}
+    rep = carry["reply"]
+    return dict(back=rep["back"][:meta["nP"] * meta["M"]],
+                reply_peak=rep["peak"])
 
 
 def run_wave_trace(ws: WaveStatic, jitter: dict,
                    backend: str = "jax") -> dict:
     """Run the wave program, returning the full per-step trace
-    ``{pub_start, confirms, depart, seen, rtt}`` with leading axis
-    ``nSteps`` — the step-for-step comparison surface for the property
-    tests.  ``backend="numpy"`` runs the same step as a Python loop."""
+    ``{pub_start, confirms, depart, seen}`` with leading axis
+    ``nSteps`` (with feedback, also ``back`` and ``reply_peak``, see
+    :func:`_finals`) — the step-for-step comparison surface for the
+    property tests.  ``backend="numpy"`` runs the same step as a Python
+    loop."""
     meta = dict(ws.meta)
     if backend == "numpy":
         ops: Any = _NumpyOps
-        inv = ws.inv
-        xs = dict(ws.xs, **jitter)
-        _, ys = ops.scan(
+        xs, inv = _split_inputs(ws.xs, ws.inv, jitter)
+        carry, ys = ops.scan(
             lambda c, x: _wave_step(ops, meta, inv, c, x),
             _init_carry(np, meta), xs, meta["nSteps"])
-        return ys
+        return dict(ys, **_finals(meta, carry))
     return dict(_run_jax(ws, jitter))
+
+
+#: jitter draws read step by step; the rest are read whole
+_STEP_JIT = ("pub_jit", "del_jit", "proc_jit")
+
+
+def _split_inputs(xs: dict, inv: dict, jitter: dict) -> tuple:
+    return (dict(xs, **{k: v for k, v in jitter.items() if k in _STEP_JIT}),
+            dict(inv, **{k: v for k, v in jitter.items()
+                         if k not in _STEP_JIT}))
 
 
 @functools.lru_cache(maxsize=64)
@@ -1094,11 +1179,11 @@ def _compiled_program(sig: tuple, meta_items: tuple, n_cells: bool
     meta["pools"] = tuple(meta["pools"])
 
     def program(xs: dict, inv: dict, jitter: dict) -> dict:
-        xs = dict(xs, **jitter)
-        _, ys = ops.scan(
+        xs, inv = _split_inputs(xs, inv, jitter)
+        carry, ys = ops.scan(
             lambda c, x: _wave_step(ops, meta, inv, c, x),
             _init_carry(ops.xp, meta), xs, meta["nSteps"])
-        return ys
+        return dict(ys, **_finals(meta, carry))
 
     return jax.jit(jax.vmap(program) if n_cells else program)
 
@@ -1187,6 +1272,10 @@ def _count_cells(wss: list, n_padded: int) -> None:
                 m["L"] * sum(int(ws.sizes["valid"].sum()) for ws in wss))
     spans.count("streamsim.padded_member_steps",
                 m["Np"] * m["L"] * m["nSteps"] * n_padded)
+    spans.count("streamsim.scan_steps", m["nSteps"])
+    if m["feedback"]:
+        spans.count("streamsim.drain_steps", m["drain"])
+        spans.count("streamsim.reply_capacity", m["C"] * len(wss))
 
 
 def _run_jax(ws: WaveStatic, jitter: dict) -> Outputs:
@@ -1206,7 +1295,7 @@ def _meta_key(meta: dict) -> tuple:
 
 def _read_keys(meta: dict) -> tuple:
     """The outputs :func:`_assemble` reads."""
-    return ("seen", "pub_start") + (("rtt",) if meta["feedback"] else ())
+    return ("seen", "pub_start") + (("back",) if meta["feedback"] else ())
 
 
 @spans.traced("streamsim.assemble")
@@ -1222,20 +1311,21 @@ def _assemble(sim: VectorizedStreamSim, ws: WaveStatic,
     valid = s["valid"].ravel()
     lanes = () if L == 1 else (L,)
     consume_t = np.full((nP * M,) + lanes, np.nan)
-    rtts = (np.full((nP * M,) + lanes, np.nan)
-            if ws.meta["feedback"] else None)
     pub = np.zeros((nP * M,) + lanes)
-    # de-stagger the pipelined trace: step g carries publish(g),
-    # delivery(g-1), reply-publish(g-2), reply-delivery(g-3)
-    a0 = 1 + ws.meta["dlag"]
-    seen = ys["seen"][a0:nGen + a0].reshape(-1, L)[valid]
+    # de-stagger the pipelined trace: step g carries publish(g) and
+    # delivery(g-1)
+    seen = ys["seen"][1:nGen + 1].reshape(-1, L)[valid]
     consume_t[mem[valid]] = (seen if lanes else seen[:, 0])
     ps = ys["pub_start"][:nGen].reshape(-1, L)[valid]
     pub[mem[valid]] = (ps if lanes else ps[:, 0])
-    if rtts is not None:
-        d = 2 + ws.meta["delay"]
-        rv = ys["rtt"][d:nGen + d].reshape(-1, L)[valid]
-        rtts[mem[valid]] = (rv if lanes else rv[:, 0])
+    rtts = None
+    if ws.meta["feedback"]:
+        # by message row; a reply never received reads +inf
+        back = ys["back"]
+        rtts = (back if lanes else back[:, 0]) - pub
+        if spans.on():
+            spans.count("streamsim.reply_backlog_peak",
+                        int(ys["reply_peak"]))
     sim.n_events = int(valid.sum()) * max(
         1, ws.meta["S_pub"] + ws.meta["S_del"]
         + ws.meta["S_rp"] + ws.meta["S_rd"])

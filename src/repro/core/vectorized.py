@@ -2245,7 +2245,9 @@ class VectorizedStreamSim:
         # out of the finite-entry indices
         fin_c = np.isfinite(consume_t)
         consume_t = consume_t[fin_c]
-        fin_r = np.isfinite(rtts) if rtts is not None else None
+        # NaN marks a message with no reply to wait for; +inf (a reply
+        # the device program never received) stays, so it shows
+        fin_r = ~np.isnan(rtts) if rtts is not None else None
         r = rtts[fin_r] if rtts is not None else np.zeros(0)
         per_producer = max(1, spec.total_messages // spec.n_producers)
         if spec.pattern.startswith("broadcast"):

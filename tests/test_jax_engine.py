@@ -221,13 +221,19 @@ def test_jax_matches_vectorized_bitwise_on_smoke_cell():
 # vs the vectorized engine.
 
 
-def _dl_spec(seed, pattern="feedback", arch="dts", msgs=256, npr=4,
-             nc=2, engine="jax", device=True, **ov):
-    # confirm_window=32 puts the default feedback cell inside the wave
-    # model's validated corridor (2G < W < msgs/producer <= 2W; see
-    # _device_loop_ok) so the dispatch-path tests exercise the device
-    # program rather than silently falling back to the cohort loop
-    ov.setdefault("confirm_window", 32)
+def _dl_spec(seed, pattern="feedback", arch="dts", msgs=None, npr=None,
+             nc=None, engine="jax", device=True, **ov):
+    # feedback: 32 x 32 with 32 msgs/producer under a confirm window of
+    # 128 keeps the default cell in the regime the device program takes
+    # (a square fleet, the window never binds; see _device_loop_ok), so
+    # the dispatch-path tests exercise the device program rather than
+    # silently falling back to the cohort loop; work sharing keeps its
+    # 4 x 2 cell of 256 msgs under a window of 32, which binds
+    fb = pattern == "feedback"
+    npr = npr or (32 if fb else 4)
+    nc = nc or (npr if fb else 2)
+    msgs = msgs or (32 * npr if fb else 256)
+    ov.setdefault("confirm_window", 128 if fb else 32)
     return ExperimentSpec(
         pattern=pattern, workload=get_workload("dstream"), arch=arch,
         n_producers=npr, n_consumers=nc, total_messages=msgs,
@@ -244,7 +250,7 @@ def _dl_sim(seed, **kw):
 
 @requires_jax
 @pytest.mark.parametrize("pattern", [
-    # the feedback trace needs a larger (corridor) cell — jit-compile
+    # the feedback trace carries the pending-reply buffer — jit-compile
     # heavy, so it rides the nightly/jax-engine jobs only
     pytest.param("feedback", marks=pytest.mark.slow),
     "work_sharing"])
@@ -253,10 +259,11 @@ def test_device_loop_trace_jax_matches_numpy_mirror(pattern):
     (backend="numpy") produce identical per-step traces — any
     divergence is a jit/vmap artifact, never modeling noise."""
     from repro.core import jax_device_loop as dl
-    # feedback needs a corridor cell to pass the regime gate; the
-    # work_sharing trace stays tiny for compile time
+    # feedback: the default 32 x 32 cell, 32 msgs/producer under a
+    # window of 128 (replies held back and drained); the work_sharing
+    # trace stays tiny for compile time
     sim = _dl_sim(0, pattern=pattern, jitter=0.02,
-                  msgs=256 if pattern == "feedback" else 64)
+                  msgs=None if pattern == "feedback" else 64)
     ok, why = dl._device_loop_ok(sim)
     assert ok, why
     ws = dl.build_static(sim)
@@ -278,9 +285,10 @@ def test_device_loop_cell_axis_pads_are_inert():
     from repro.core import jax_device_loop as dl
     seeds = (0, 1, 2)
     batched = dl.run_wave_cells(
-        [_dl_sim(s, msgs=64, jitter=0.02) for s in seeds])
+        [_dl_sim(s, msgs=64, npr=4, nc=2, jitter=0.02) for s in seeds])
     for s, rs in zip(seeds, batched):
-        solo = dl.run_wave_results(_dl_sim(s, msgs=64, jitter=0.02))
+        solo = dl.run_wave_results(
+            _dl_sim(s, msgs=64, npr=4, nc=2, jitter=0.02))
         assert len(rs) == len(solo) == 1
         np.testing.assert_array_equal(rs[0].consume_times,
                                       solo[0].consume_times)
@@ -335,26 +343,55 @@ def test_device_loop_stacked_pilot_bit_identical():
     np.testing.assert_array_equal(stacked[0].rtts, solo.rtts)
 
 
+#: the paper's Fig 6 points (work sharing with feedback, Dstream, 3072
+#: messages, producers = consumers) the device program takes: the
+#: confirm window of 128 never binds at 96 and 48 msgs/producer.  At
+#: c64, mss reads -5.9% throughput against the vectorized engine (seed
+#: 0; -1.2% to -6.0% over seeds 0-7), whose own throughput there sits
+#: 3.6% to 8.8% above the heap engine's; the device program's, 2.1% to
+#: 2.3% above, with its median RTT within 0.1% of the heap's
+FIG6 = [pytest.param("feedback", a, nc, 3072, id=f"fig6-{a}-c{nc}")
+        for nc in (32, 64) for a in ("dts", "prs-haproxy", "mss")]
+
+
+def _mirror_results(spec):
+    """The device program's results, its step run by the NumPy mirror
+    (the same step function; ``test_device_loop_trace_jax_matches_numpy_
+    mirror`` holds the jit program to it) — no compile per shape."""
+    from repro.core import jax_device_loop as dl
+    from repro.core.vectorized import VectorizedStreamSim
+    sim = VectorizedStreamSim(spec)
+    ws = dl.build_static(sim)
+    ys = dl.run_wave_trace(ws, dl.draw_jitter(sim, ws), backend="numpy")
+    return dl._assemble(sim, ws, ys)
+
+
 @requires_jax
 @pytest.mark.slow
-@pytest.mark.parametrize("pattern,arch", [
-    # feedback rides the device loop only inside its validated
-    # corridor, and only on the multi-broker archs (mss feedback is
-    # regime-gated; see test_device_loop_regime_gate)
-    ("feedback", "dts"), ("work_sharing", "prs-haproxy"),
-    ("work_sharing", "dts"), ("work_sharing", "mss")])
-def test_device_loop_parity_vs_vectorized(pattern, arch):
+@pytest.mark.parametrize("pattern,arch,nc,msgs", [
+    # the default cells: feedback 32 x 32 with 32 msgs/producer, work
+    # sharing 4 x 2 with 256 msgs (the feedback shapes of the former
+    # corridor are declined; see test_device_loop_regime_gate)
+    pytest.param("feedback", "dts", None, None, id="feedback-dts"),
+    pytest.param("work_sharing", "prs-haproxy", None, None,
+                 id="work_sharing-prs-haproxy"),
+    pytest.param("work_sharing", "dts", None, None, id="work_sharing-dts"),
+    pytest.param("work_sharing", "mss", None, None, id="work_sharing-mss")]
+    + FIG6)
+def test_device_loop_parity_vs_vectorized(pattern, arch, nc, msgs):
     """End-to-end parity of the whole-run device program against the
     vectorized cohort loop, inside the device_loop.* bands."""
     from repro.core import jax_device_loop as dl
     from repro.core.parity import band
-    ok, why = dl._device_loop_ok(
-        _dl_sim(0, pattern=pattern, arch=arch))
+    kw = dict(pattern=pattern, arch=arch, npr=nc, nc=nc, msgs=msgs)
+    ok, why = dl._device_loop_ok(_dl_sim(0, **kw))
     assert ok, f"cell unexpectedly regime-gated: {why}"
-    v = run_many([_dl_spec(0, pattern=pattern, arch=arch,
-                           engine="vectorized", device=None)])[0]
-    j = run_many([_dl_spec(0, pattern=pattern, arch=arch)])[0]
-    assert summarize(j).engine == "jax"
+    v = run_many([_dl_spec(0, engine="vectorized", device=None, **kw)])[0]
+    if msgs is None:
+        j = run_many([_dl_spec(0, **kw)])[0]
+        assert summarize(j).engine == "jax"
+    else:
+        (j,) = _mirror_results(_dl_spec(0, **kw))
     sv, sj = summarize(v), summarize(j)
     thr_dev = (abs(sj.throughput_msgs_s - sv.throughput_msgs_s)
                / sv.throughput_msgs_s)
@@ -365,6 +402,35 @@ def test_device_loop_parity_vs_vectorized(pattern, arch):
         rtt_dev = abs(rj - rv) / rv
         assert rtt_dev <= band("device_loop.all.median_rtt"), (
             f"{pattern}/{arch}: rtt dev {rtt_dev:.4f}")
+
+
+@requires_jax
+@pytest.mark.parametrize("arch,nc,msgs,device", [
+    ("dts", 32, 1024, True), ("prs-haproxy", 32, 1024, True),
+    ("prs-haproxy", 64, 3072, False), ("dts", 32, 3072, False),
+    ("mss", 64, 3072, False)])
+def test_device_loop_receives_every_reply(arch, nc, msgs, device):
+    """Every consumed message's reply comes back before the scan ends:
+    no reply is left in the pending-reply buffer, which would read as a
+    non-finite RTT.  The 64-consumer cell holds almost every reply back
+    until the publishes are done, so its drain steps do the most."""
+    from repro.core import jax_device_loop as dl
+    npr = nc
+    spec = _dl_spec(0, arch=arch, npr=npr, nc=nc, msgs=msgs, jitter=0.03)
+    results = (run_many([spec, _dl_spec(1, arch=arch, npr=npr, nc=nc,
+                                        msgs=msgs, jitter=0.03)])
+               if device else _mirror_results(spec))
+    for r in results:
+        assert r.rtts.size == msgs and np.isfinite(r.rtts).all()
+        assert (r.rtts > 0).all()
+        assert np.array_equal(np.bincount(r.rtt_producers),
+                              np.full(npr, msgs // npr))
+        if device:
+            assert r.path == "jax-device"
+    ws = dl.build_static(_dl_sim(0, arch=arch, npr=npr, nc=nc, msgs=msgs))
+    m = ws.meta
+    assert m["nSteps"] == m["nGen"] + 1 + m["drain"]
+    assert m["C"] == max(msgs, m["Np"])
 
 
 @requires_jax
@@ -404,11 +470,11 @@ def test_recorded_path_tells_device_program_from_cohort_path():
 
 
 def test_device_loop_regime_gate():
-    """The regime gate rejects every shape class whose static
-    wave schedule measurably diverges from the cohort loop, each with
-    a reason naming the offending quantity (gated cells dispatch to
-    the per-cohort path; see test_device_loop_dispatch_requires_opt_in
-    for the dispatch side)."""
+    """The regime gate rejects every shape class whose wave schedule
+    measurably diverges from the cohort loop, each with a reason naming
+    the offending quantity (gated cells dispatch to the per-cohort
+    path; see test_device_loop_dispatch_requires_opt_in for the
+    dispatch side)."""
     from repro.core import jax_device_loop as dl
 
     def why_of(**kw):
@@ -416,17 +482,33 @@ def test_device_loop_regime_gate():
         assert not ok
         return why
 
-    # single-broker mss feedback: structural residuals everywhere
-    assert "mss" in why_of(arch="mss")
-    # fine generations (G < 4): p16c16 picks G=2
-    assert "too fine" in why_of(npr=16, nc=16, msgs=2048,
-                                confirm_window=64)
-    # hard window stall: W <= 2G
-    assert "window-stall" in why_of(confirm_window=16)
-    # burst regime: the window never binds (W >= msgs/producer)
-    assert "never binds" in why_of(confirm_window=128)
-    # reply-lag drift: run much longer than the window (M > 2W)
-    assert "drifts" in why_of(msgs=1024)
+    # the shapes the former feedback corridor was drawn around, all on
+    # 4 producers: a binding window (its own W=32, the hard stall W=16,
+    # a run of 8 windows) meets the binding-window clause; the burst
+    # shape (W=128 over 64 msgs/producer) and mss, the fleet clause
+    for msgs, w in ((256, 32), (256, 16), (1024, 32)):
+        assert "binding confirm window" in why_of(npr=4, nc=2, msgs=msgs,
+                                                  confirm_window=w)
+    for arch in ("dts", "mss"):
+        assert "4 x 2 fleet" in why_of(npr=4, nc=2, msgs=256, arch=arch)
+    # fine generations at 16x16 (2048 msgs, W=64; +11% throughput) and
+    # Fig 6 at 16 consumers (192 msgs/producer over a window of 128)
+    assert "binding confirm window" in why_of(npr=16, nc=16, msgs=2048,
+                                              confirm_window=64)
+    assert "binding confirm window" in why_of(npr=16, nc=16, msgs=3072)
+    # a square fleet below 32 or above 96, and one with fewer consumers
+    # than producers, with the window never binding
+    assert "16 x 16 fleet" in why_of(npr=16, nc=16, msgs=1024)
+    assert "128 x 128 fleet" in why_of(npr=128, nc=128, msgs=128 * 48)
+    assert "48 x 8 fleet" in why_of(npr=48, nc=8, msgs=48 * 48)
+    # a run too short for the reply schedule
+    assert "16 msgs/producer < 32" in why_of(npr=64, nc=64, msgs=1024)
+    # the device program's regime: Fig 6 at 32 and 64 consumers on every
+    # architecture, and the default 32 x 32 cell
+    for kw in [dict(npr=n, nc=n, msgs=3072, arch=a) for n in (32, 64)
+               for a in ("dts", "prs-haproxy", "mss")] + [dict()]:
+        ok, why = dl._device_loop_ok(_dl_sim(0, **kw))
+        assert ok, why
     # universal run-length clause (any pattern): generation-barrier
     # drift accumulates past 256 msgs/producer
     assert "generation-barrier drift" in why_of(

@@ -208,19 +208,21 @@ def test_cells_of_one_signature_share_a_program():
 
 
 def _feedback_spec(seed: int) -> ExperimentSpec:
-    # inside the device program's feedback corridor (see _device_loop_ok)
+    # a feedback cell the device program takes: a square fleet of 32,
+    # 32 msgs/producer under a confirm window that never binds (see
+    # _device_loop_ok)
     return ExperimentSpec(
         pattern="feedback", workload=get_workload("dstream"), arch="dts",
-        n_producers=4, n_consumers=2, total_messages=256,
+        n_producers=32, n_consumers=32, total_messages=1024,
         params=SimParams(seed=seed, engine="jax", jax_device_loop=True,
-                         confirm_window=32, jitter=0.02))
+                         confirm_window=128, jitter=0.02))
 
 
 @pytest.mark.parametrize("pattern", ["work_sharing", "feedback"])
 def test_only_what_assembly_reads_is_copied_back(pattern):
     """The results path copies back ``seen`` and ``pub_start``, and
-    ``rtt`` where the pattern has replies; never ``confirms`` or
-    ``depart``."""
+    ``back`` (each reply's receive time) where the pattern has replies;
+    never ``confirms`` or ``depart``."""
     spec = (_spec("mss", 1) if pattern == "work_sharing"
             else _feedback_spec(1))
     run_many([spec])
@@ -229,23 +231,63 @@ def test_only_what_assembly_reads_is_copied_back(pattern):
     assert r.path == "jax-device"
     ws = dl.build_static(VectorizedStreamSim(spec))
     m = ws.meta
-    read = ["seen", "pub_start"] + (["rtt"] if pattern == "feedback" else [])
+    read = ["seen", "pub_start"] + (["back"] if pattern == "feedback" else [])
     assert list(dl._read_keys(m)) == read
+    # ``seen`` and ``pub_start`` per step; ``back`` per message; with
+    # the recorder on, the pending-reply peak (one int64) too
     assert rec.counters["streamsim.d2h_bytes"] == (
-        len(read) * 8 * m["nSteps"] * m["Np"] * m["L"])
+        2 * 8 * m["nSteps"] * m["Np"] * m["L"]
+        + (8 * spec.total_messages * m["L"] + 8
+           if pattern == "feedback" else 0))
     fetches = [s for s in rec.spans if s.name == "streamsim.fetch"]
-    assert len(fetches) == len(read)
+    assert len(fetches) == len(read) + (pattern == "feedback")
+
+
+def test_reply_counters_of_a_feedback_program():
+    """A feedback program run counts its scan and drain steps and the
+    pending-reply buffer's size, and assembly its largest backlog; a
+    work-sharing run counts its scan steps alone."""
+    spec = _feedback_spec(2)
+    run_many([spec])
+    with spans.recording() as rec:
+        run_many([spec])
+    sim = VectorizedStreamSim(spec)
+    ws = dl.build_static(sim)
+    m, c = ws.meta, rec.counters
+    assert c["streamsim.scan_steps"] == m["nSteps"]
+    assert c["streamsim.drain_steps"] == m["drain"] > 0
+    assert c["streamsim.reply_capacity"] == m["C"]
+    ys = dl.run_wave_trace(ws, dl.draw_jitter(sim, ws), backend="numpy")
+    assert c["streamsim.reply_backlog_peak"] == int(ys["reply_peak"]) > 0
+    ws_spec = _spec("mss", 1)
+    run_many([ws_spec])
+    with spans.recording() as rec:
+        run_many([ws_spec])
+    assert rec.counters["streamsim.scan_steps"] == dl.build_static(
+        VectorizedStreamSim(ws_spec)).meta["nSteps"]
+    assert not {"streamsim.drain_steps", "streamsim.reply_capacity",
+                "streamsim.reply_backlog_peak"} & set(rec.counters)
 
 
 def test_trace_returns_every_output():
-    """The step-for-step surface still copies back all five outputs."""
+    """The step-for-step surface still copies back every output: the
+    four per-step traces, and with feedback each message's reply
+    receive time and the pending-reply peak."""
     sim = VectorizedStreamSim(_spec("dts", 1))
     ws = dl.build_static(sim)
     ys = dl.run_wave_trace(ws, dl.draw_jitter(sim, ws), backend="jax")
-    assert set(ys) == {"pub_start", "confirms", "depart", "seen", "rtt"}
+    assert set(ys) == {"pub_start", "confirms", "depart", "seen"}
     for k, a in ys.items():
         assert isinstance(a, np.ndarray), k
         assert a.shape[:2] == (ws.meta["nSteps"], ws.meta["Np"]), k
+    spec = _feedback_spec(1)
+    sim = VectorizedStreamSim(spec)
+    ws = dl.build_static(sim)
+    ys = dl.run_wave_trace(ws, dl.draw_jitter(sim, ws), backend="jax")
+    assert set(ys) == {"pub_start", "confirms", "depart", "seen", "back",
+                       "reply_peak"}
+    assert ys["back"].shape == (spec.total_messages, ws.meta["L"])
+    assert 0 < int(ys["reply_peak"]) <= ws.meta["C"]
 
 
 def test_results_identical_with_recording_on_and_off(recorded):
@@ -262,13 +304,14 @@ def test_results_identical_with_recording_on_and_off(recorded):
 
 
 def test_scan_phases_are_named_in_the_program():
-    """The six phases of the scan step carry named scopes into the
-    lowered program (feedback cells have all four legs)."""
+    """The seven phases of the scan step carry named scopes into the
+    lowered program (feedback cells have all four legs and the
+    pending-reply buffer)."""
     from repro.compat import scoped_x64
     spec = ExperimentSpec(
         pattern="feedback", workload=get_workload("dstream"), arch="dts",
-        n_producers=4, n_consumers=2, total_messages=256,
-        params=SimParams(seed=0, confirm_window=32))
+        n_producers=32, n_consumers=32, total_messages=1024,
+        params=SimParams(seed=0, confirm_window=128))
     sim = VectorizedStreamSim(spec)
     assert dl._device_loop_ok(sim)[0]
     ws = dl.build_static(sim)
@@ -277,7 +320,7 @@ def test_scan_phases_are_named_in_the_program():
         text = fn.lower(ws.xs, ws.inv, dl.draw_jitter(sim, ws)).as_text(
             debug_info=True)
     for scope in ("publish_gate", "pump_assign", "transit", "consume_ack",
-                  "reply_pump", "reply_ack"):
+                  "reply_admit", "reply_pump", "reply_ack"):
         assert f'loc("{scope}/' in text, scope
 
 

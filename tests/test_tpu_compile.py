@@ -96,13 +96,14 @@ def test_cohort_kernel_compiles_for_v5e(name, one_chip):
 
 
 def test_device_program_compiles_for_v5e(one_chip):
-    """The whole-run device program at the smallest feedback cell the
-    regime gate admits, so all four legs (publish, delivery,
-    reply-publish, reply-delivery) and both pumps are in it."""
+    """The whole-run device program at a small feedback cell the regime
+    gate admits, so all four legs (publish, delivery, reply-publish,
+    reply-delivery), both pumps and the pending-reply buffer are in
+    it."""
     spec = ExperimentSpec(
         pattern="feedback", workload=get_workload("dstream"), arch="dts",
-        n_producers=4, n_consumers=2, total_messages=256,
-        params=SimParams(seed=0, confirm_window=32))
+        n_producers=32, n_consumers=32, total_messages=1024,
+        params=SimParams(seed=0, confirm_window=128))
     sim = VectorizedStreamSim(spec)
     ok, why = dl._device_loop_ok(sim)
     assert ok, why
