@@ -64,7 +64,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
-from collections.abc import Iterator, Mapping
+from collections.abc import Iterable, Iterator, Mapping
 from typing import Any, Callable, Optional
 
 import numpy as np
@@ -1372,22 +1372,32 @@ def _dispatch(cells: list, sig: tuple) -> Mapping:
     return ys
 
 
-def run_wave_cells(sims: list) -> list:
+def run_wave_cells(sims: Iterable) -> list:
     """vmap-over-cells driver: batch structurally identical cells
     (same :meth:`WaveStatic.signature`) into one device program.
-    Returns, per sim, the per-lane RunResult list.
+    Returns, per sim, the per-lane RunResult list, in input order.
 
-    The host runs one program ahead of the device: it enqueues the
-    next group's program before it waits on the current one and
-    assembles it, so draws, dispatch, copies and assembly overlap a
-    program already queued.  At most two programs' buffers are alive
-    on the device at once."""
+    The host runs one program ahead of the device.  The first sim is
+    built and its program enqueued before the next sim is taken from
+    ``sims``, so the later sims' construction (lazy in ``run_many``)
+    and static builds run while the device computes; the first cell
+    therefore runs as a program of its own even where a later cell
+    shares its signature.  The rest are grouped by signature, and each
+    group's program is enqueued before the host waits on the one
+    before and assembles it, so draws, dispatch, copies and assembly
+    overlap a program already queued.  At most two programs' buffers
+    are alive on the device at once."""
     import jax.numpy as jnp  # noqa: F401  (jax required here)
-    built = [(sim, build_static(sim)) for sim in sims]
-    groups: dict = {}
-    for i, (_, ws) in enumerate(built):
-        groups.setdefault(ws.signature(), []).append(i)
-    out: list = [None] * len(sims)
+    built: list = []
+    out: dict = {}
+    pending: list = []      # (idxs, outputs) enqueued, not yet assembled
+
+    def build(sim: VectorizedStreamSim) -> tuple:
+        """Build ``sim`` as cell ``len(built)``; returns its signature."""
+        spans.count("streamsim.builds_ahead", int(bool(pending)))
+        ws = build_static(sim)
+        built.append((sim, ws))
+        return ws.signature()
 
     def assemble(idxs: list, ys: Mapping) -> None:
         for c, i in enumerate(idxs):
@@ -1395,12 +1405,21 @@ def run_wave_cells(sims: list) -> list:
             out[i] = _assemble(sim, ws, ys if len(idxs) == 1
                                else _Cell(ys, c))
 
-    pending: list = []      # (idxs, outputs) enqueued, not yet assembled
-    for sig, idxs in groups.items():
+    def dispatch(sig: tuple, idxs: list) -> None:
         pending.append((idxs, _dispatch([built[i] for i in idxs], sig)))
         spans.count("streamsim.programs_ahead", len(pending) - 1)
         if len(pending) > 1:
             assemble(*pending.pop(0))
+
+    rest = iter(sims)
+    first = next(rest, None)
+    if first is not None:       # the first cell goes alone, at once
+        dispatch(build(first), [0])
+    groups: dict = {}
+    for sim in rest:
+        groups.setdefault(build(sim), []).append(len(built) - 1)
+    for sig, idxs in groups.items():
+        dispatch(sig, idxs)
     for p in pending:
         assemble(*p)
-    return out
+    return [out[i] for i in range(len(built))]

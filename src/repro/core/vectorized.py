@@ -2331,13 +2331,18 @@ def run_many(specs: Sequence[ExperimentSpec],
 
     Infeasible specs come back as ``feasible=False`` results, like
     :func:`~repro.core.simulator.run_experiment`.  Returns one
-    :class:`RunResult` per spec, in input order."""
+    :class:`RunResult` per spec, in input order.
+
+    Engines the whole-run device program takes are handed to
+    :func:`~repro.core.jax_device_loop.run_wave_cells` lazily: it
+    enqueues the first one's program before the next engine is
+    constructed, so the later groups' engines and static builds (and
+    the groups run here on the host) overlap the device's work."""
     import dataclasses
 
     from repro.core.simulator import get_engine, run_experiment
     specs = list(specs)
     results: list = [None] * len(specs)
-    deferred: list = []
     for i, spec in enumerate(specs):
         if spec.params.engine == "jax":
             from repro.core import jax_engine
@@ -2355,48 +2360,57 @@ def run_many(specs: Sequence[ExperimentSpec],
             groups.setdefault(_stack_key(spec), []).append(i)
         else:
             groups[("solo", i)] = [i]
-    for idxs in groups.values():
-        stack = len(idxs) > 1
-        if not stack:
-            for i in idxs:
-                results[i] = run_experiment(specs[i], inventory)
-            continue
-        cls = get_engine(specs[idxs[0]].params.engine)
-        # one probe per group: feasibility is structural, identical
-        # across the seeds
-        try:
-            with spans.span("streamsim.engine"):
-                cls(specs[idxs[0]], inventory)
-            spans.count("streamsim.engines", 1)
-        except InfeasibleConfiguration as e:
-            for i in idxs:
-                results[i] = RunResult(spec=specs[i], feasible=False,
-                                       infeasible_reason=str(e))
-            continue
-        max_lanes = getattr(cls, "STACK_MAX_LANES", STACK_MAX_LANES)
-        for lo in range(0, len(idxs), max_lanes):
-            chunk = idxs[lo:lo + max_lanes]
-            if len(chunk) == 1:
-                results[chunk[0]] = run_experiment(specs[chunk[0]],
-                                                   inventory)
+    deferred: list = []     # the chunks of the engines yielded, in order
+
+    def device_sims() -> Iterator:
+        """Run every group the device program does not take; yield the
+        engines of those it does, each constructed only when the device
+        loop asks for its next cell."""
+        for idxs in groups.values():
+            stack = len(idxs) > 1
+            if not stack:
+                for i in idxs:
+                    results[i] = run_experiment(specs[i], inventory)
                 continue
-            seeds = [specs[i].params.seed for i in chunk]
-            with spans.span("streamsim.engine"):
-                sim = cls(specs[chunk[0]], inventory, stack_seeds=seeds)
-            spans.count("streamsim.engines", 1)
-            if getattr(sim, "_use_device_loop", lambda: False)():
-                # whole-run device programs batch across *cells* too
-                # (vmap-over-cells; see repro.core.jax_device_loop) —
-                # defer so structurally identical grids fuse
-                deferred.append((chunk, sim))
+            cls = get_engine(specs[idxs[0]].params.engine)
+            # one probe per group: feasibility is structural, identical
+            # across the seeds
+            try:
+                with spans.span("streamsim.engine"):
+                    cls(specs[idxs[0]], inventory)
+                spans.count("streamsim.engines", 1)
+            except InfeasibleConfiguration as e:
+                for i in idxs:
+                    results[i] = RunResult(spec=specs[i], feasible=False,
+                                           infeasible_reason=str(e))
                 continue
-            for i, r in zip(chunk, sim.run_stacked()):
-                results[i] = r
-    if deferred:
+            max_lanes = getattr(cls, "STACK_MAX_LANES", STACK_MAX_LANES)
+            for lo in range(0, len(idxs), max_lanes):
+                chunk = idxs[lo:lo + max_lanes]
+                if len(chunk) == 1:
+                    results[chunk[0]] = run_experiment(specs[chunk[0]],
+                                                       inventory)
+                    continue
+                seeds = [specs[i].params.seed for i in chunk]
+                with spans.span("streamsim.engine"):
+                    sim = cls(specs[chunk[0]], inventory, stack_seeds=seeds)
+                spans.count("streamsim.engines", 1)
+                if getattr(sim, "_use_device_loop", lambda: False)():
+                    # whole-run device programs batch across *cells* too
+                    # (vmap-over-cells; see repro.core.jax_device_loop)
+                    deferred.append(chunk)
+                    yield sim
+                    continue
+                for i, r in zip(chunk, sim.run_stacked()):
+                    results[i] = r
+
+    sims = device_sims()
+    first = next(sims, None)
+    if first is not None:
         from repro.core import jax_device_loop
         lane_results = jax_device_loop.run_wave_cells(
-            [sim for _, sim in deferred])
-        for (chunk, _sim), rs in zip(deferred, lane_results):
+            itertools.chain([first], sims))
+        for chunk, rs in zip(deferred, lane_results):
             for i, r in zip(chunk, rs):
                 results[i] = r
     return results

@@ -279,13 +279,21 @@ def test_device_loop_trace_jax_matches_numpy_mirror(pattern):
 @requires_jax
 @pytest.mark.slow
 def test_device_loop_cell_axis_pads_are_inert():
-    """run_wave_cells pads a 3-cell group to 4 by replicating cell 0;
-    every real cell's results are bit-identical to its solo device
-    run."""
+    """run_wave_cells runs the first cell alone and pads the 3-cell
+    group after it to 4 by replicating its cell 0; every real cell's
+    results are bit-identical to its solo device run."""
     from repro.core import jax_device_loop as dl
-    seeds = (0, 1, 2)
-    batched = dl.run_wave_cells(
-        [_dl_sim(s, msgs=64, npr=4, nc=2, jitter=0.02) for s in seeds])
+    from repro.core import spans
+    seeds = (0, 1, 2, 3)
+    with spans.recording() as rec:
+        batched = dl.run_wave_cells(
+            [_dl_sim(s, msgs=64, npr=4, nc=2, jitter=0.02) for s in seeds])
+    m = dl.build_static(_dl_sim(0, msgs=64, npr=4, nc=2)).meta
+    assert rec.counters["streamsim.programs"] == 2
+    assert rec.counters["streamsim.cells"] == 4
+    # the first cell alone, then three cells padded to four
+    assert rec.counters["streamsim.padded_member_steps"] == (
+        5 * m["Np"] * m["L"] * m["nSteps"])
     for s, rs in zip(seeds, batched):
         solo = dl.run_wave_results(
             _dl_sim(s, msgs=64, npr=4, nc=2, jitter=0.02))
@@ -296,28 +304,71 @@ def test_device_loop_cell_axis_pads_are_inert():
 
 
 @requires_jax
-@pytest.mark.parametrize("cells", [
+@pytest.mark.parametrize("cells,via,counts", [
     pytest.param((("dts", 0), ("prs-haproxy", 0), ("mss", 0)),
-                 id="three-signatures"),
-    pytest.param((("mss", 0), ("dts", 1), ("mss", 2)),
+                 "run_wave_cells", (3, 3, 2), id="three-signatures"),
+    pytest.param((("dts", 1), ("mss", 0), ("mss", 2)),
+                 "run_wave_cells", (2, 3, 2),
                  id="two-cell-group-and-a-singleton"),
-    pytest.param((("mss", 0), ("mss", 1), ("dts", 2), ("mss", 3)),
-                 id="padded-three-cell-group-and-a-singleton")])
-def test_device_loop_pipelined_cells_match_solo_runs(cells):
-    """run_wave_cells enqueues each signature group's program before it
-    waits on the one before and assembles it; every sim's lanes are
-    bit-identical to the sim's own run_wave_results, in input order."""
+    pytest.param((("dts", 2), ("mss", 0), ("mss", 1), ("mss", 3)),
+                 "run_wave_cells", (2, 4, 3),
+                 id="padded-three-cell-group-and-a-singleton"),
+    # after the lone first cell, the mss group (cells 1 and 3) runs
+    # before cell 2: programs run out of input order
+    pytest.param((("dts", 0), ("mss", 0), ("prs-haproxy", 0), ("mss", 1)),
+                 "run_wave_cells", (3, 4, 3),
+                 id="interleaved-group-after-a-lone-first"),
+    pytest.param((("dts", 0), ("prs-haproxy", 0), ("mss", 0)),
+                 "run_many", (3, 3, 2), id="run-many-three-architectures"),
+    # as above through run_many; the second mss group differs in jitter
+    # alone, so it stacks apart and fuses with the first by signature
+    pytest.param((("dts", 0), ("mss", 0), ("prs-haproxy", 0),
+                  ("mss", 1, 0.03)),
+                 "run_many", (3, 4, 3), id="run-many-interleaved-group"),
+    # prs-haproxy and prs-haproxy-c4 build one signature; the third
+    # group differs in jitter alone, so it does not stack with the first
+    pytest.param((("prs-haproxy", 0), ("prs-haproxy-c4", 0),
+                  ("prs-haproxy", 1, 0.03)),
+                 "run_many", (2, 3, 2), id="run-many-first-signature-shared"),
+    pytest.param((("mss", 0),), "run_many", (1, 1, 0),
+                 id="run-many-one-cell")])
+def test_device_loop_pipelined_cells_match_solo_runs(cells, via, counts):
+    """run_wave_cells enqueues the first cell's program before it builds
+    the rest, then each signature group's program before it waits on
+    the one before and assembles it; every sim's lanes are
+    bit-identical to the sim's own run_wave_results, in input order.
+    ``counts`` are the programs, cells and static builds that start
+    while a program is enqueued and not yet waited on."""
     from repro.core import jax_device_loop as dl
+    from repro.core import spans
+    from repro.core.jax_engine import JaxStreamSim
     from repro.core.vectorized import VectorizedStreamSim
 
+    engine = "jax" if via == "run_many" else "vectorized"
+    cls = JaxStreamSim if via == "run_many" else VectorizedStreamSim
+
+    def spec(a, s, jitter=0.02):
+        return _dl_spec(s, pattern="work_sharing", arch=a, msgs=64,
+                        engine=engine,
+                        device=True if via == "run_many" else None,
+                        jitter=jitter)
+
     def sims():
-        return [VectorizedStreamSim(
-            _dl_spec(s, pattern="work_sharing", arch=a, msgs=64,
-                     engine="vectorized", device=None, jitter=0.02),
-            None, stack_seeds=[s, s + 100]) for a, s in cells]
-    n_sigs = len({dl.build_static(sim).signature() for sim in sims()})
-    assert n_sigs == len({a for a, _ in cells})
-    batched = dl.run_wave_cells(sims())
+        return [cls(spec(*c), None, stack_seeds=[c[1], c[1] + 100])
+                for c in cells]
+    _, *rest = [dl.build_static(sim).signature() for sim in sims()]
+    with spans.recording() as rec:
+        if via == "run_many":
+            flat = run_many([spec(a, seed, *j) for a, s, *j in cells
+                             for seed in (s, s + 100)])
+            batched = [flat[k:k + 2] for k in range(0, len(flat), 2)]
+        else:
+            batched = dl.run_wave_cells(sims())
+    c = rec.counters
+    assert (c["streamsim.programs"], c["streamsim.cells"],
+            c["streamsim.builds_ahead"]) == counts
+    # the first cell runs alone; the rest fuse by signature
+    assert counts[0] == 1 + len(set(rest))
     assert len(batched) == len(cells)
     for got, sim in zip(batched, sims()):
         want = dl.run_wave_results(sim)

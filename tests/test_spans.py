@@ -131,14 +131,18 @@ def test_span_tree_of_a_request(recorded):
     assert set(names) == set(want_parent)
     for i, n in enumerate(names):
         assert parent[i] == want_parent[n], n
-    # one program ahead: each architecture's program is enqueued before
-    # the one before it is waited on and assembled
+    # the first architecture's program is enqueued before the next
+    # engines (probe and stacked) are constructed and built; then one
+    # program ahead: each is enqueued before the one before it is
+    # waited on and assembled
     top = [n for i, n in enumerate(names)
-           if parent[i] == "streamsim.run_many" and n != "streamsim.engine"]
-    draw, prog, asm = ("streamsim.draw_jitter", "streamsim.program",
-                       "streamsim.assemble")
-    assert top == ["streamsim.build_static"] * len(ARCHS) + [
-        draw, prog, draw, prog, asm, draw, prog, asm, asm]
+           if parent[i] == "streamsim.run_many"]
+    eng, build, draw, prog, asm = (
+        "streamsim.engine", "streamsim.build_static",
+        "streamsim.draw_jitter", "streamsim.program", "streamsim.assemble")
+    assert top == [eng, eng, build, draw, prog,
+                   eng, eng, build, eng, eng, build,
+                   draw, prog, asm, draw, prog, asm, asm]
     # a program span is its dispatch; its assembly waits on it, then
     # copies back the two outputs a work-sharing result reads
     for i, n in enumerate(names):
@@ -178,6 +182,8 @@ def test_counters_match_what_was_built(recorded):
         2 * 8 * ws.meta["nSteps"] * ws.meta["Np"] * ws.meta["L"]
         for ws in wss)
     assert c["streamsim.programs_ahead"] == groups - 1
+    # every build after the first starts under the first program
+    assert c["streamsim.builds_ahead"] == groups - 1
     # every program was already compiled by the unrecorded request
     assert c.get("streamsim.program_builds", 0) == 0
 
@@ -188,23 +194,28 @@ def test_request_counters_of_a_request(recorded):
 
 
 def test_cells_of_one_signature_share_a_program():
-    """Two stack groups that differ only in jitter build one signature,
-    so they run as one program over two cells."""
-    specs = [_spec("mss", s, jitter=j) for j in (0.1, 0.2) for s in SEEDS]
+    """Three stack groups that differ only in jitter build one
+    signature.  The first runs alone, enqueued before the other two are
+    built; those two run as one program over two cells."""
+    specs = [_spec("mss", s, jitter=j) for j in (0.1, 0.2, 0.3)
+             for s in SEEDS]
     run_many(specs)
     with spans.recording() as rec:
         run_many(specs)
     c = rec.counters
-    assert c["streamsim.programs"] == 1 and c["streamsim.cells"] == 2
-    # one program: none goes ahead, and the count says so
-    assert c["streamsim.programs_ahead"] == 0
-    assert c["streamsim.engines"] == 4
+    assert c["streamsim.programs"] == 2 and c["streamsim.cells"] == 3
+    # the second program goes ahead of the first's wait, and both
+    # later builds start while the first program is enqueued
+    assert c["streamsim.programs_ahead"] == 1
+    assert c["streamsim.builds_ahead"] == 2
+    assert c["streamsim.engines"] == 6
     ws = dl.build_static(JaxStreamSim(specs[0], None,
                                       stack_seeds=list(SEEDS)))
+    # one cell alone, then two cells (a pow2: no pad)
     assert c["streamsim.padded_member_steps"] == (
-        2 * ws.meta["Np"] * ws.meta["L"] * ws.meta["nSteps"])
+        3 * ws.meta["Np"] * ws.meta["L"] * ws.meta["nSteps"])
     assert c["streamsim.member_steps"] == (
-        2 * int(ws.sizes["valid"].sum()) * ws.meta["L"])
+        3 * int(ws.sizes["valid"].sum()) * ws.meta["L"])
 
 
 def _feedback_spec(seed: int) -> ExperimentSpec:
