@@ -1,37 +1,22 @@
-"""JAX engine benchmarks: compile-amortized kernel speedup + end-to-end
-engine comparison on the deployment grid.
+"""JAX engine benchmark: the whole-run device program against the
+vectorized engine on the deployment grid.
 
-Two cell families:
+``jaxeng/e2e/*`` rows run whole deployment-grid cells through
+``run_many`` on ``engine="jax"`` (with the whole-run device program
+requested via ``jax_device_loop=True``; see
+:mod:`repro.core.jax_device_loop`) vs ``engine="vectorized"``,
+wall-clock + throughput parity in 'derived'.  These rows **assert the
+>= 1x end-to-end gate**: with the cohort event loop lifted into one
+``lax.scan`` device program the jax engine must at least match the
+NumPy engine's wall clock on cells the wave model supports (jit compile
+time is reported separately and never counted — the compiled program
+is shape-bucketed and amortizes across a campaign).  Throughput parity
+is asserted at the ``device_loop.all.throughput`` band from
+:mod:`repro.core.parity`.
 
-* ``jaxeng/kernel/*`` — the headline gate.  The deployment campaign's
-  measured ``_fifo_scan`` call profile is ~26k calls per 3-seed group,
-  overwhelmingly tiny cohorts (1-16 steps x seed lanes): at those
-  shapes the NumPy engine's cost is per-call overhead, not arithmetic.
-  The JAX engine's pad-and-mask contract buckets every cohort to a
-  power-of-two shape, so a whole campaign round's worth of scans
-  batches through **one** ``fifo_scan_cells`` device program
-  (``vmap`` over the cell axis of an already lane-vmapped kernel).
-  These rows time that call — jit-compiled once, then amortized —
-  against the equivalent NumPy call loop, and **assert the >= 2x
-  speedup gate** the PR promises (measured ~4-10x on the profile
-  shapes; compile time is reported separately, never counted).
-
-* ``jaxeng/e2e/*`` — whole deployment-grid cells run through
-  ``run_many`` on ``engine="jax"`` (with the whole-run device program
-  requested via ``jax_device_loop=True``; see
-  :mod:`repro.core.jax_device_loop`) vs ``engine="vectorized"``,
-  wall-clock + throughput parity in 'derived'.  These rows **assert the
-  >= 1x end-to-end gate**: with the cohort event loop lifted into one
-  ``lax.scan`` device program the jax engine must at least match the
-  NumPy engine's wall clock on cells the wave model supports (measured
-  ~20x on this grid; jit compile time is reported separately and never
-  counted — the compiled program is shape-bucketed and amortizes across
-  a campaign).  Throughput parity is asserted at the
-  ``device_loop.all.throughput`` band from :mod:`repro.core.parity`.
-
-``JAX_BENCH_SMOKE=1`` shrinks call counts and the e2e grid for CI.
-Without jax importable, every row degrades to ``SKIPPED:no-jax``
-instead of failing (mirroring ``run_many``'s per-cell fallback).
+``JAX_BENCH_SMOKE=1`` shrinks the e2e grid for CI.  Without jax
+importable, every row degrades to ``SKIPPED:no-jax`` instead of failing
+(mirroring ``run_many``'s per-cell fallback).
 """
 
 from __future__ import annotations
@@ -39,9 +24,7 @@ from __future__ import annotations
 import os
 import time
 
-import numpy as np
-
-from benchmarks.common import Cache, cache_key, plain_key
+from benchmarks.common import Cache, cache_key
 from repro.core.jax_engine import jax_available
 from repro.core.metrics import summarize
 from repro.core.simulator import ExperimentSpec, SimParams
@@ -49,66 +32,15 @@ from repro.core.workloads import DSTREAM
 
 SMOKE = os.environ.get("JAX_BENCH_SMOKE") == "1"
 
-#: the >= 2x compile-amortized kernel gate (PR acceptance)
-KERNEL_SPEEDUP_GATE = 2.0
-
 #: the >= 1x end-to-end gate: the device-programmed jax engine must not
 #: lose to the NumPy cohort loop on wave-supported deployment cells
-#: (compile excluded; measured ~20x once compiled)
+#: (compile excluded)
 E2E_SPEEDUP_GATE = 1.0
-
-#: (calls, cohort, lanes) kernel shapes from the measured deployment-
-#: grid profile: 3-seed groups pad their cohorts into pow2 buckets
-#: dominated by N<=16 at L=3 lanes
-KERNEL_SHAPES = ([(256, 16, 3), (256, 4, 3)] if SMOKE
-                 else [(4096, 16, 3), (4096, 4, 3)])
-REPS = 3 if SMOKE else 7
 
 E2E_SEEDS = (0, 1000, 2000)
 E2E_MSGS = 256 if SMOKE else 2048
 E2E_ARCHS = ("mss",) if SMOKE else ("dts", "prs-haproxy", "mss")
 E2E_TENANTS = 4
-
-
-def _profile_arrays(C: int, N: int, L: int):
-    rng = np.random.default_rng(0)
-    a = np.sort(rng.uniform(0.0, 10.0, (C, N, L)), axis=1)
-    h = rng.uniform(0.0, 1e-3, (C, N, L))
-    return a, h, np.zeros((C, L))
-
-
-def _best_of(fn, reps: int) -> float:
-    best = float("inf")
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        fn()
-        best = min(best, time.perf_counter() - t0)
-    return best
-
-
-def _kernel_cell(C: int, N: int, L: int) -> dict:
-    from repro.core.jax_engine import _kernels
-    from repro.core.vectorized import _fifo_scan
-    K = _kernels()
-    a, h, carry = _profile_arrays(C, N, L)
-
-    t0 = time.perf_counter()
-    out_j = np.asarray(K.fifo_scan_cells(a, h, carry))   # includes compile
-    compile_s = time.perf_counter() - t0
-    out_n = np.stack([_fifo_scan(a[i], h[i], carry[i]) for i in range(C)])
-    np.testing.assert_allclose(out_j, out_n, rtol=1e-12)
-
-    wall_np = _best_of(
-        lambda: [_fifo_scan(a[i], h[i], carry[i]) for i in range(C)], REPS)
-    wall_jx = _best_of(
-        lambda: np.asarray(K.fifo_scan_cells(a, h, carry)), REPS)
-    speedup = wall_np / wall_jx
-    assert speedup >= KERNEL_SPEEDUP_GATE, (
-        f"jax fifo_scan_cells ({C}x{N}x{L}) compile-amortized speedup "
-        f"{speedup:.2f}x < {KERNEL_SPEEDUP_GATE}x gate "
-        f"(numpy {wall_np * 1e3:.2f}ms, jax {wall_jx * 1e3:.2f}ms)")
-    return {"wall_np_s": wall_np, "wall_jax_s": wall_jx,
-            "speedup": speedup, "compile_s": compile_s}
 
 
 def _e2e_specs(arch: str, engine: str) -> list:
@@ -159,25 +91,10 @@ def _e2e_cell(arch: str) -> dict:
 def run(cache: Cache):
     rows = []
     if not jax_available():
-        for C, N, L in KERNEL_SHAPES:
-            rows.append((f"jaxeng/kernel/fifo/c{C}xn{N}xl{L}",
-                         float("nan"), "SKIPPED:no-jax"))
         for arch in E2E_ARCHS:
             rows.append((f"jaxeng/e2e/{arch}/t{E2E_TENANTS}",
                          float("nan"), "SKIPPED:no-jax"))
         return rows
-
-    for C, N, L in KERNEL_SHAPES:
-        c = cache.get_or(
-            plain_key(f"jaxeng|kernel|c{C}|n{N}|l{L}|r{REPS}"),
-            lambda C=C, N=N, L=L: _kernel_cell(C, N, L))
-        rows.append((
-            f"jaxeng/kernel/fifo/c{C}xn{N}xl{L}",
-            1e6 * c["wall_jax_s"] / C,
-            f"speedup={c['speedup']:.1f}x (gate>={KERNEL_SPEEDUP_GATE}x) "
-            f"numpy={c['wall_np_s'] * 1e3:.2f}ms "
-            f"jax={c['wall_jax_s'] * 1e3:.2f}ms "
-            f"compile={c['compile_s'] * 1e3:.0f}ms"))
 
     for arch in E2E_ARCHS:
         c = cache.get_or(

@@ -69,15 +69,16 @@ def resolve_engine(engine: Optional[str] = None, spec=None) -> str:
 def resolve_path(engine: str, spec=None, **params) -> str:
     """Execution path (``RunResult.path``) of a cell on the resolved
     ``engine``.  Only a jax cell has more than one: without its spec it
-    is the per-cohort path, and a cell requesting the whole-run device
-    program must pass its spec so the regime gate can be consulted."""
+    runs on the vectorized engine, and a cell requesting the whole-run
+    device program must pass its spec so the regime gate can be
+    consulted."""
     if engine != "jax":
         return engine
     if spec is None:
         if params.get("jax_device_loop"):
             raise ValueError("a jax cell requesting jax_device_loop needs "
                              "its spec to resolve its execution path")
-        return "jax-cohort"
+        return "vectorized"
     import dataclasses
 
     from repro.core.campaign import resolved_path

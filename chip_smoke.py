@@ -11,9 +11,10 @@ reference engines:
   MSS, three seeds each;
 * B — the device program at facility-fleet size: DTS, 256 producers x
   256 consumers, 65536 messages (256 per producer), three seeds;
-* C — the per-cohort jax kernels on a Fig 7b cell the device program
-  declines: broadcast_gather, MSS, generic workload, 32 consumers, 128
-  messages (Fig 7b runs 384; see ``COHORT``).
+* C — a Fig 7b cell the device program declines, requested with
+  ``engine="jax", jax_device_loop=True``: broadcast_gather, MSS, generic
+  workload, 32 consumers, 384 messages.  It runs on the vectorized
+  engine on the host.
 
 Every phase asserts the path each cell took (``RunResult.path``),
 message conservation, and parity with the reference engine inside the
@@ -47,12 +48,8 @@ GRID = dict(archs=("dts", "prs-haproxy", "mss"), clients=16, tenants=4,
             messages=2048)
 #: phase B: bench_engine_scaling's 256-client fleet at 256 msgs/producer
 FLEET = dict(archs=("dts",), clients=256, tenants=1, messages=65536)
-#: phase C: a Fig 7b cell (bench_fig7_broadcast_gather) at a third of
-#: its 384 messages.  The per-cohort path makes one small device call
-#: per kernel use — about 316k calls at 384 messages, which took 663 s
-#: per call on one TPU v5e chip (~2.1 ms each) and overran the smoke
-#: run's time budget; 128 messages make about 102k calls.
-COHORT = dict(arch="mss", consumers=32, messages=128)
+#: phase C: a Fig 7b cell (bench_fig7_broadcast_gather)
+COHORT = dict(arch="mss", consumers=32, messages=384)
 
 
 def device_check() -> tuple[str, str, int]:
@@ -181,8 +178,9 @@ def oracle_diff(phase: str, arch: str, clients: int, tenants: int,
 
 def cohort_phase(phase: str, arch: str, consumers: int,
                  messages: int) -> None:
-    """The per-cohort jax kernels through ``run_pattern`` on a cell the
-    device program declines, against the heap reference."""
+    """A jax cell the device program declines, through ``run_pattern``:
+    it runs on the vectorized engine, checked against the heap
+    reference."""
     from repro.core.jax_device_loop import device_loop_supported
     from repro.core.metrics import summarize
     from repro.core.parity import band
@@ -198,9 +196,8 @@ def cohort_phase(phase: str, arch: str, consumers: int,
     def run(engine: str) -> Any:
         return run_pattern(*cell, n_runs=1, engine=engine, **kw)[0]
 
-    _, cold = timed(lambda: run("jax"))
-    r, warm = timed(lambda: run("jax"))
-    wall(phase, cold, warm)
+    r, jax_s = timed(lambda: run("jax"))
+    say(phase, f"jax request: host wall time {jax_s:.3f} s")
     h, host_s = timed(lambda: run("heap"))
     say(phase, f"heap reference: host wall time {host_s:.3f} s")
     sj, sh = summarize(r), summarize(h)
@@ -212,7 +209,7 @@ def cohort_phase(phase: str, arch: str, consumers: int,
     say(phase, f"path={r.path} consumed={r.n_consumed}/{expect} "
                f"thr_dev={thr_dev:.6f} (band {thr_band}) "
                f"gather_rtt_dev={rtt_dev:.6f} (band {rtt_band})")
-    assert r.path == "jax-cohort", (phase, r.path)
+    assert r.path == "vectorized", (phase, r.path)
     assert r.n_consumed == h.n_consumed == expect, (phase, r.n_consumed)
     assert thr_dev <= thr_band, (phase, thr_dev)
     assert rtt_dev <= rtt_band, (phase, rtt_dev)

@@ -71,18 +71,19 @@ def params_fingerprint(params: SimParams) -> str:
 
 def resolved_path(spec: ExperimentSpec) -> str:
     """The execution path a spec will *actually* take — the
-    ``RunResult.path`` its run records: ``"heap"``, ``"vectorized"``,
-    ``"jax-cohort"`` or ``"jax-device"``.  A requested ``"jax"`` cell
-    that ``jax_supported`` rejects executes on the vectorized engine
-    (the ``run_many`` fallback); one that requests the whole-run device
-    program but fails its regime gate runs the per-cohort jax kernels.
+    ``RunResult.path`` its run records: ``"heap"``, ``"vectorized"`` or
+    ``"jax-device"``.  A requested ``"jax"`` cell runs on the whole-run
+    device program only when it requests it and passes the regime gate;
+    every other jax cell executes on the vectorized engine, with the
+    same code and results as the identical vectorized cell.
 
     Every cache key MUST be built from this, never from the requested
     ``spec.params.engine`` — keying a fallback cell under ``jax`` both
     poisons the jax namespace (a later run in a jax-capable environment
     is served vectorized numbers) and forks it from the identical
-    vectorized cell (same computation measured twice); keying the two
-    jax paths alike would serve one path's numbers as the other's."""
+    vectorized cell (same computation measured twice); keying the
+    device program alike would serve its numbers as the vectorized
+    engine's."""
     eng = spec.params.engine
     if eng != "jax":
         return eng
@@ -93,12 +94,12 @@ def resolved_path(spec: ExperimentSpec) -> str:
         from repro.core import jax_device_loop
         if jax_device_loop.device_loop_supported(spec)[0]:
             return "jax-device"
-    return "jax-cohort"
+    return "vectorized"
 
 
 def resolved_engine(spec: ExperimentSpec) -> str:
-    """The engine of :func:`resolved_path` (``"jax"`` for both jax
-    paths)."""
+    """The engine of :func:`resolved_path` (``"jax"`` for the device
+    program)."""
     path = resolved_path(spec)
     return "jax" if path.startswith("jax-") else path
 
@@ -283,7 +284,7 @@ def cell_key(cell: CellSpec) -> str:
     cell that falls back to vectorized shares its key (tag *and*
     fingerprint) with the identical genuine-vectorized cell — it ran
     the same computation — and never occupies the jax namespace, and
-    the device program never shares a row with the per-cohort path."""
+    the device program never shares a row with the vectorized engine."""
     exp = cell.experiment()
     p = exp.params
     path = resolved_path(exp)

@@ -1,16 +1,14 @@
 """Whole-run JAX device programs for the StreamSim *wave* regime.
 
-PR 6 put the vectorized engine's hot kernels on JAX devices but kept the
-cohort event loop in Python, so end-to-end the ``jax`` engine dispatched
-thousands of tiny device calls and lost to NumPy (ROADMAP item 1).  This
-module inverts that control flow for the regime every deployment-grid
-cell lives in: it compiles the **entire run** — admission gating by
-publisher-confirm windows, hop-graph resource FIFO serving, the windowed
-broker pump with prefetch gates and batched ack-multiples, feedback
-replies — into one ``lax.scan`` over *generations* of messages, with
-stacked seed-lanes vmapped exactly like the kernel layer and whole cells
-batched by :func:`run_wave_cells` (a ``vmap``-over-cells driver in the
-spirit of ``fifo_scan_cells``).
+The vectorized engine runs a cohort event loop in Python.  For the
+regime every deployment-grid cell lives in, this module compiles the
+**entire run** — admission gating by publisher-confirm windows,
+hop-graph resource FIFO serving, the windowed broker pump with prefetch
+gates and batched ack-multiples, feedback replies — into one
+``lax.scan`` over *generations* of messages, with stacked seed-lanes
+vmapped and whole cells batched by :func:`run_wave_cells` (a
+``vmap`` over cells).  ``engine="jax"`` with
+``jax_device_loop=True`` runs it (:mod:`repro.core.jax_engine`).
 
 **The wave contract.**  The device program is *not* the event loop — it
 is a wave-synchronous re-formulation that is exact where the regime
@@ -41,8 +39,8 @@ makes exactness cheap and banded where it does not:
 
 **Backends.**  The whole program is written once against a tiny ``ops``
 namespace with two implementations: ``jax`` (``lax.scan`` +
-``associative_scan`` segmented FIFO closed forms, jitted under the
-scoped-x64 contract of :mod:`repro.core.jax_engine`) and ``numpy`` (a
+``associative_scan`` segmented FIFO closed forms, jitted under
+:func:`repro.compat.scoped_x64`) and ``numpy`` (a
 plain Python generation loop over the *same* step function).  The NumPy
 backend is the step-for-step oracle: ``tests/test_flow_control_props.py``
 property-tests that both backends produce the same per-generation trace.
@@ -50,13 +48,14 @@ property-tests that both backends produce the same per-generation trace.
 **One pump.**  The pump window assignment (round-robin consumer pick +
 prefetch-ring gate + depart clamp) is the XLA closed form
 :func:`_pump_assign` on every backend.  The clocks are float64 by
-contract (scoped x64, see :mod:`repro.core.jax_engine`), and Mosaic
+contract (:func:`repro.compat.scoped_x64`), and Mosaic
 compiles no 64-bit operands, so a Pallas port cannot compile for a TPU
 while that contract stands.
 
 Pad-and-mask: member axes pad to the next power of two with invalid
 members carrying ``+inf`` clocks, zero holds and dummy carry chains —
-inert by the same contract as the kernel layer (property-tested).
+inert under every op (``tests/test_jax_engine.py`` property-tests the
+ops against their NumPy backend with such pads appended).
 """
 
 from __future__ import annotations
@@ -95,9 +94,9 @@ def device_loop_supported(spec: ExperimentSpec) -> tuple[bool, str]:
     generation size that keeps every consumer under half its prefetch
     per generation, and for feedback a confirm window that never binds,
     a square fleet of 32 to 96 producers and a run of at least 32
-    messages per producer.  Unsupported cells use the ordinary
-    per-cohort jax path (``RunResult.path`` says which ran); this is a
-    *request*, not a demand."""
+    messages per producer.  Unsupported cells run on the vectorized
+    engine (``RunResult.path`` says which ran); this is a *request*, not
+    a demand."""
     from repro.core.jax_engine import jax_available
     from repro.core.simulator import InfeasibleConfiguration
     if not jax_available():

@@ -43,7 +43,7 @@ class Summary:
     #: predates the field
     engine: str = ""
     #: the execution path that produced the result (``RunResult.path``):
-    #: tells the jax device program apart from the per-cohort jax path
+    #: tells the jax device program apart from the vectorized engine
     path: str = ""
 
 

@@ -51,7 +51,7 @@ PARITY_BANDS: dict[str, float] = {
     # so these are modeling bands, not arithmetic-noise bands.  They
     # apply only inside the supported regime (the
     # ``_device_loop_ok`` gate in repro.core.jax_device_loop);
-    # gated cells fall back to the per-cohort path and carry the
+    # gated cells run on the vectorized engine and carry the
     # ordinary engine bands instead
     "device_loop.all.throughput": 0.06,
     "device_loop.all.median_rtt": 0.05,

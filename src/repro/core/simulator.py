@@ -21,9 +21,10 @@ Three engines implement the same experiment contract (the
 :class:`Engine` protocol): this module's heap engine (one event per hop
 — the reference), the batched array engine in
 :mod:`repro.core.vectorized` that computes whole message cohorts with
-prefix-scan FIFO math, and the JAX port of its hot kernels in
-:mod:`repro.core.jax_engine` (``jax.jit`` device programs, vmapped over
-stacked seed-lanes).  The vectorized engine is the default; select via
+prefix-scan FIFO math, and :mod:`repro.core.jax_engine`, which runs
+the cells it is asked to and that pass a regime gate as one whole-run
+``jax.jit`` device program and every other cell on the vectorized
+engine.  The vectorized engine is the default; select via
 ``SimParams(engine="vectorized"|"heap"|"jax")`` (alias
 :data:`SimConfig`).  All model the full flow-control stack, including
 credit-flow confirm withholding and reject-publish overflow with
@@ -97,9 +98,9 @@ class SimParams:
     #: ``lax.scan`` over message generations instead of the Python
     #: cohort loop; see :mod:`repro.core.jax_device_loop`).  True uses
     #: it when the cell is wave-formulated (work_sharing/feedback, no
-    #: flow-control events reachable) and silently keeps the ordinary
-    #: per-cohort jax path otherwise; None/False (default) never uses
-    #: it.  Device-loop results match the cohort engines at the
+    #: flow-control events reachable) and silently runs the vectorized
+    #: engine otherwise; None/False (default) never uses it.
+    #: Device-loop results match the cohort engines at the
     #: ``device_loop.*`` parity bands rather than bit-for-bit.
     jax_device_loop: Optional[bool] = None
     #: chaos schedule — topology-epoch failure injection (link/broker/
@@ -220,8 +221,8 @@ class RunResult:
     rtt_producers: np.ndarray = dataclasses.field(
         default_factory=lambda: np.zeros(0, dtype=np.int64))
     #: the execution path that produced the result: ``"heap"``,
-    #: ``"vectorized"``, ``"jax-cohort"`` (the per-cohort jax kernels)
-    #: or ``"jax-device"`` (the whole-run device program); ``""`` for an
+    #: ``"vectorized"`` (also a jax cell off the device program) or
+    #: ``"jax-device"`` (the whole-run device program); ``""`` for an
     #: infeasible cell, which ran nothing
     path: str = ""
 

@@ -153,14 +153,10 @@ class _VecResource:
     ``(n, lanes)`` time arrays — same FIFO arithmetic per lane, with the
     pilot lane's arrival order deciding the (shared) service order."""
 
-    __slots__ = ("spec", "_free_pipe", "_free_pool", "_scan")
+    __slots__ = ("spec", "_free_pipe", "_free_pool")
 
-    def __init__(self, spec: ResourceSpec, lanes: int = 1,
-                 scan: Optional[Callable] = None) -> None:
+    def __init__(self, spec: ResourceSpec, lanes: int = 1) -> None:
         self.spec = spec
-        #: the FIFO-scan kernel (``_fifo_scan`` or an engine-injected
-        #: port of it, e.g. the JAX engine's jitted scan)
-        self._scan = scan if scan is not None else _fifo_scan
         self._free_pipe = 0.0
         if spec.kind == "pool":
             k = max(1, spec.servers)
@@ -186,7 +182,7 @@ class _VecResource:
         a, h = t_arr[order], hold[order]
         end_sorted = np.empty_like(a)
         if self.spec.kind == "pipe":
-            end_sorted = self._scan(a, h, self._free_pipe)
+            end_sorted = _fifo_scan(a, h, self._free_pipe)
             self._free_pipe = (float(end_sorted[-1]) if a.ndim == 1
                                else end_sorted[-1].copy())
         else:
@@ -200,7 +196,7 @@ class _VecResource:
             k = carry.shape[0]
             n = a.shape[0]
             for c in range(min(k, n)):
-                end_sorted[c::k] = self._scan(a[c::k], h[c::k], carry[c])
+                end_sorted[c::k] = _fifo_scan(a[c::k], h[c::k], carry[c])
                 carry[c] = end_sorted[c + ((n - 1 - c) // k) * k]
             self._free_pool = carry
         out = np.empty_like(end_sorted)
@@ -254,14 +250,6 @@ class VectorizedStreamSim:
     #: bound on the memoized (flow, combos) -> resolved-paths cache
     COMBO_CACHE_MAX = 8192
 
-    #: the FIFO-scan kernel every busy-interval recurrence runs through
-    #: (resources and the consumer processing chains); subclass engines
-    #: (repro.core.jax_engine) swap in their own port
-    _scan_impl = staticmethod(_fifo_scan)
-
-    #: ``RunResult.path`` of the results this engine's event loop makes
-    PATH = "vectorized"
-
     def __init__(self, spec: ExperimentSpec,
                  inventory: Optional[ClusterInventory] = None,
                  arch: Optional[Architecture] = None,
@@ -296,8 +284,7 @@ class VectorizedStreamSim:
                              "params.seed")
         self._rngs = [np.random.default_rng(s) for s in self.stack_seeds]
         self.rng = self._rngs[0]
-        self.resources = {k: _VecResource(s, self._lanes,
-                                          scan=self._scan_impl)
+        self.resources = {k: _VecResource(s, self._lanes)
                           for k, s in self.arch.resources.items()}
         self._proc_s = (self.p.consumer_proc_s
                         if self.p.consumer_proc_s is not None
@@ -1596,7 +1583,7 @@ class VectorizedStreamSim:
                 # serial parse/handle chain on the consumer client
                 o = m[np.argsort(_lane0(t_land)[m], kind="stable")]
                 proc = self._proc_s * (1.0 + self._jit(o.size))
-                ends = self._scan_impl(t_land[o] + recv, proc, ch["free"])
+                ends = _fifo_scan(t_land[o] + recv, proc, ch["free"])
                 seen[o] = ends
                 ch["free"] = (float(ends[-1]) if ends.ndim == 1
                               else ends[-1].copy())
@@ -2278,7 +2265,7 @@ class VectorizedStreamSim:
             blocked_confirms=int(self.blocked[lane]),
             redelivered=int(self._redelivered),
             sim_time=top, n_events=self.n_events,
-            consume_producers=cp, rtt_producers=rp, path=self.PATH)
+            consume_producers=cp, rtt_producers=rp, path="vectorized")
 
 
 ENGINES["vectorized"] = VectorizedStreamSim
@@ -2322,12 +2309,14 @@ def run_many(specs: Sequence[ExperimentSpec],
     pattern/arch/consumer-count/knobs) and heap-engine cells fall back
     to per-cell solo execution.
 
-    ``engine="jax"`` cells stack the same way (the JAX engine shares the
-    stacked-lane contract); cells the JAX engine cannot take — JAX not
-    importable, or an unsupported cell shape — fall back to the
-    vectorized engine, recorded per cell in the result's
+    ``engine="jax"`` cells stack the same way.  Cells the JAX engine
+    cannot take — JAX not importable, or a chaos schedule — fall back to
+    the vectorized engine, recorded per cell in the result's
     ``spec.params.engine`` (campaign summaries surface it as
-    ``Summary.engine``).
+    ``Summary.engine``).  A jax group the whole-run device program
+    declines (not requested, or refused by its regime gate) runs as a
+    vectorized group: the same engine, lane chunks and results as
+    ``engine="vectorized"``, with ``RunResult.path == "vectorized"``.
 
     Infeasible specs come back as ``feasible=False`` results, like
     :func:`~repro.core.simulator.run_experiment`.  Returns one
@@ -2373,17 +2362,20 @@ def run_many(specs: Sequence[ExperimentSpec],
                     results[i] = run_experiment(specs[i], inventory)
                 continue
             cls = get_engine(specs[idxs[0]].params.engine)
-            # one probe per group: feasibility is structural, identical
-            # across the seeds
+            # one probe per group: feasibility and the device program's
+            # regime gate are structural, identical across the seeds
             try:
                 with spans.span("streamsim.engine"):
-                    cls(specs[idxs[0]], inventory)
+                    probe = cls(specs[idxs[0]], inventory)
                 spans.count("streamsim.engines", 1)
             except InfeasibleConfiguration as e:
                 for i in idxs:
                     results[i] = RunResult(spec=specs[i], feasible=False,
                                            infeasible_reason=str(e))
                 continue
+            on_device = getattr(probe, "_use_device_loop", lambda: False)()
+            if not on_device:
+                cls = VectorizedStreamSim
             max_lanes = getattr(cls, "STACK_MAX_LANES", STACK_MAX_LANES)
             for lo in range(0, len(idxs), max_lanes):
                 chunk = idxs[lo:lo + max_lanes]
@@ -2395,7 +2387,7 @@ def run_many(specs: Sequence[ExperimentSpec],
                 with spans.span("streamsim.engine"):
                     sim = cls(specs[chunk[0]], inventory, stack_seeds=seeds)
                 spans.count("streamsim.engines", 1)
-                if getattr(sim, "_use_device_loop", lambda: False)():
+                if on_device:
                     # whole-run device programs batch across *cells* too
                     # (vmap-over-cells; see repro.core.jax_device_loop)
                     deferred.append(chunk)

@@ -63,7 +63,9 @@ def test_versioned_cache_roundtrip_and_key_guard(tmp_path):
 # Fallback-engine resolution (the cache-poisoning regression): a jax
 # cell the run_many fallback downgrades to vectorized must be keyed as
 # vectorized — sharing the entry with the identical genuine-vectorized
-# cell — and must never occupy the jax namespace.
+# cell — and must never occupy the jax namespace.  The genuinely-jax
+# cells request the device program on a cell its gate admits: a jax
+# cell off the device program runs, and is keyed as, vectorized.
 # ---------------------------------------------------------------------------
 
 
@@ -76,7 +78,8 @@ def _force_fallback(monkeypatch):
 def test_cache_key_resolves_fallback_engine(monkeypatch):
     from repro.core.patterns import pattern_spec
     spec = pattern_spec("work_sharing", "dts", "dstream", 2,
-                        total_messages=8, engine="jax")
+                        total_messages=8, engine="jax",
+                        jax_device_loop=True)
     assert "engine=jax" in cache_key("cell", engine="jax", spec=spec)
     _force_fallback(monkeypatch)
     kf = cache_key("cell", engine="jax", spec=spec)
@@ -90,10 +93,11 @@ def test_cell_key_resolves_fallback_engine(monkeypatch):
     from repro.core.campaign import CellSpec, cell_key
     cj = CellSpec(pattern="work_sharing", arch="dts", workload="dstream",
                   n_consumers=2, total_messages=8, seed=0,
-                  overrides=(("engine", "jax"),))
+                  overrides=(("engine", "jax"), ("jax_device_loop", True)))
     cv = CellSpec(pattern="work_sharing", arch="dts", workload="dstream",
                   n_consumers=2, total_messages=8, seed=0,
-                  overrides=(("engine", "vectorized"),))
+                  overrides=(("engine", "vectorized"),
+                             ("jax_device_loop", True)))
     assert "engine=jax" in cell_key(cj)
     _force_fallback(monkeypatch)
     assert cell_key(cj) == cell_key(cv)
@@ -117,7 +121,27 @@ def test_fallback_cells_never_poison_jax_namespace(tmp_path, monkeypatch):
     monkeypatch.undo()
     from repro.core.patterns import pattern_spec
     spec = pattern_spec("work_sharing", "dts", "dstream", 2,
-                        total_messages=64, engine="jax")
+                        total_messages=64, engine="jax",
+                        jax_device_loop=True)
     kj = cache_key("work_sharing|dts|dstream|2|64|1", engine="jax",
                    spec=spec)
     assert "engine=jax" in kj and kj not in c.data
+
+
+def test_declined_jax_cell_shares_the_vectorized_row():
+    """A jax cell off the device program runs the vectorized engine, so
+    it is keyed as the identical vectorized cell, with jax available:
+    one cache row for one computation."""
+    from repro.core.campaign import CellSpec, cell_key
+    from repro.core.patterns import pattern_spec
+
+    def cell(engine):
+        return CellSpec(pattern="work_sharing", arch="dts",
+                        workload="dstream", n_consumers=2,
+                        total_messages=8, seed=0,
+                        overrides=(("engine", engine),))
+    assert cell_key(cell("jax")) == cell_key(cell("vectorized"))
+    spec = pattern_spec("work_sharing", "dts", "dstream", 2,
+                        total_messages=8, engine="jax")
+    assert (cache_key("cell", engine="jax", spec=spec)
+            == cache_key("cell", engine="vectorized"))

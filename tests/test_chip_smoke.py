@@ -38,5 +38,5 @@ def test_chip_smoke_phases_pass_at_small_size(monkeypatch, capsys):
                                "count": 1}}
     seeds = len(chip_smoke.SEEDS)
     assert sum("path=jax-device" in line for line in out) == 4 * seeds
-    assert sum("path=jax-cohort" in line for line in out) == 1
+    assert sum("path=vectorized" in line for line in out) == 1
     assert any("NumPy-mirror oracle" in line for line in out)

@@ -30,11 +30,12 @@ from repro.core.vectorized import _fifo_scan
 ARCHS = ("dts", "prs-haproxy", "mss")
 NC = 8
 
-#: every engine held to the heap reference's parity bands; the jax
-#: engine inherits the vectorized tolerances (same float64 recurrences,
-#: re-associated at worst at the 1e-16 level — see docs/engines.md).
-#: Without jax importable the jax column drops out (run_many would fall
-#: back to vectorized anyway, making the rows redundant).
+#: every engine held to the heap reference's parity bands; these cells
+#: do not request the device program, so the jax rows check that the
+#: class ``engine="jax"`` builds keeps the vectorized engine's results
+#: (and tolerances) where the device program declines.  Without jax
+#: importable the jax column drops out (run_many would fall back to
+#: vectorized anyway, making the rows redundant).
 from repro.core.jax_engine import jax_available  # noqa: E402
 
 VEC_ENGINES = (("vectorized", "jax") if jax_available()

@@ -38,8 +38,9 @@ from repro.core.vectorized import VectorizedStreamSim, _fifo_scan
 from repro.core.workloads import get_workload
 
 #: every lane-resolved invariant below holds for each batched engine;
-#: the jax engine swaps the kernel layer only (masked depart stores,
-#: device admission scan), so it rides the same properties
+#: the device program declines flow-control cells, so the jax rows check
+#: that the class ``engine="jax"`` builds keeps the vectorized engine's
+#: invariants where it runs the vectorized event loop
 VEC_ENGINES = (("vectorized", "jax") if jax_available()
                else ("vectorized",))
 
@@ -101,8 +102,7 @@ def _mini_sim(n_lanes, engine="vectorized"):
 
 
 @pytest.mark.parametrize("engine", VEC_ENGINES)
-# no deadline: on the jax engine an example's first call of each pow2
-# shape bucket includes that kernel's jit compile
+# no deadline: an example runs many admission batches over stacked lanes
 @settings(max_examples=25, deadline=None)
 @given(cap=st.integers(min_value=2, max_value=12),
        lanes=st.integers(min_value=1, max_value=3),
@@ -163,7 +163,7 @@ def test_stacked_overflow_lane_invariants(engine, seeds, cap_msgs, msgs):
     confirm causality + full confirm resolution, backlog high-water
     marks within the cap, drained queues, and a bit-identical pilot
     (the solo reference stays on the vectorized engine, so the jax
-    param also pins jax-pilot == numpy-solo bit-identity)."""
+    param also pins the jax pilot to the vectorized solo run)."""
     spec = _overflow_spec(0, cap_msgs, msgs)
     sim = _engine_cls(engine)(spec, stack_seeds=[0] + seeds)
     results = sim.run_stacked()

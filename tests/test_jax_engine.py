@@ -1,18 +1,19 @@
-"""Jit-boundary unit tests for the JAX engine (``engine="jax"``):
+"""Unit tests for the JAX engine (``engine="jax"``):
 
-* ``_fifo_scan`` NumPy-vs-JAX elementwise equality on hypothesis
-  inputs, solo and lane-stacked — the scan is the same float64 closed
-  form (cumsum + running max), so the two engines may differ only by
-  re-association noise;
-* the **pad-and-mask contract**: pow2 padding with inert values (+inf
-  arrivals, zero holds, consumed depart rows) never perturbs a real
-  lane — at the kernel level and for whole stacked runs (adding a
-  seed-lane leaves the existing lanes bit-identical);
-* **scoped x64**: engine kernels compute in float64 with full
+* the device program's **op layer** (``jax_device_loop._jax_ops``)
+  against its NumPy backend (``_NumpyOps``) on hypothesis inputs, with
+  the **pad-and-mask contract**'s inert pads appended (+inf clocks,
+  masked rows, dummy scatter slots): the backends agree, and the pads
+  never perturb a real row;
+* **scoped x64**: the device ops compute in float64 with full
   time-arithmetic resolution (a 1e-4 s hold survives a 1e3 s clock)
   while the process-global JAX default stays x32 for the model stack;
-* ``run_many``'s per-cell fallback when jax is unavailable, recorded
-  on the result (``Summary.engine``).
+* stacked seed-lanes: adding a lane leaves the existing lanes
+  bit-identical;
+* ``run_many``'s routing: a jax cell runs on the whole-run device
+  program where the regime gate admits it, and otherwise on the
+  vectorized engine, bit for bit; the fallback when jax is unavailable
+  is recorded on the result (``Summary.engine``).
 """
 
 import numpy as np
@@ -21,10 +22,10 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.core import jax_engine
-from repro.core.jax_engine import _pow2, jax_available, jax_supported
+from repro.core.jax_engine import jax_available, jax_supported
 from repro.core.metrics import summarize
 from repro.core.simulator import ExperimentSpec, SimParams
-from repro.core.vectorized import _fifo_scan, run_many
+from repro.core.vectorized import VectorizedStreamSim, run_many
 from repro.core.workloads import get_workload
 
 requires_jax = pytest.mark.skipif(not jax_available(),
@@ -38,110 +39,160 @@ def _spec(seed, engine="jax", msgs=256, nc=2):
         params=SimParams(seed=seed, engine=engine))
 
 
-# -- shape bucketing --------------------------------------------------------
+# -- the device program's op layer: jax vs numpy, pads inert ---------------
+
+#: rows of inert pads appended to every op input (the member axes pad to
+#: the next power of two the same way)
+PADS = 3
+
+#: no subnormals: XLA flushes them to zero, and no clock is one
+_clocks = st.floats(min_value=0.0, max_value=50.0, allow_subnormal=False)
 
 
-def test_pow2_buckets():
-    assert [_pow2(n) for n in (0, 1, 2, 3, 4, 5, 17, 64)] == \
-        [1, 1, 2, 4, 4, 8, 32, 64]
+def _draw_clocks(data, n, L):
+    return np.array(data.draw(st.lists(_clocks, min_size=n * L,
+                                       max_size=n * L)),
+                    dtype=np.float64).reshape(n, L)
 
 
-# -- _fifo_scan: numpy vs jax elementwise ----------------------------------
+def _inf_pads(x):
+    return np.concatenate([x, np.full((PADS,) + x.shape[1:], np.inf)])
+
+
+def _op_case(op, data):
+    """``(args, padded_args, real)`` for one op: the numpy backend runs
+    ``args``, both backends run ``padded_args``, and ``real(out)`` picks
+    the real rows of an output on the padded input."""
+    n = data.draw(st.integers(1, 12), label="n")
+    L = data.draw(st.integers(1, 3), label="lanes")
+    x = _draw_clocks(data, n, L)
+    if op == "lexsort":
+        # (idx, clock, group) as the program sorts members: a unique idx
+        # key breaks every tie; masked rows carry a +inf clock
+        grp = np.array(data.draw(st.lists(st.integers(0, 2), min_size=n,
+                                          max_size=n)))
+        pgrp = np.array(data.draw(st.lists(st.integers(0, 2),
+                                           min_size=PADS, max_size=PADS)))
+        keys = (np.arange(n), x[:, 0], grp)
+        pkeys = (np.arange(n + PADS), _inf_pads(x)[:, 0],
+                 np.concatenate([grp, pgrp]))
+        return (keys,), (pkeys,), lambda o: o[o < n]
+    if op in ("cumsum", "cummax"):
+        pad = (np.concatenate([x, np.zeros((PADS, L))]) if op == "cumsum"
+               else _inf_pads(x))
+        return (x,), (pad,), lambda o: o[:n]
+    if op == "seg_cummax":
+        start = np.array(data.draw(st.lists(st.booleans(), min_size=n,
+                                            max_size=n)))
+        pstart = np.concatenate([start, np.ones(PADS, bool)])
+        return (x, start), (_inf_pads(x), pstart), lambda o: o[:n]
+    m = data.draw(st.integers(n, n + 4), label="slots")
+    arr = _draw_clocks(data, m, L)
+    # one dummy slot per pad row past the real ones, as masked members
+    # scatter to a dummy chain
+    parr = np.concatenate([arr, np.zeros((PADS, L))])
+    if op == "at_set":
+        # unique targets: jax leaves the winner of a duplicate unspecified
+        idx = np.array(data.draw(st.permutations(range(m))))[:n]
+        pidx = np.concatenate([idx, m + np.arange(PADS)])
+    elif op == "at_max":
+        idx = np.array(data.draw(st.lists(st.integers(0, m - 1),
+                                          min_size=n, max_size=n)))
+        pidx = np.concatenate([idx, np.full(PADS, m)])
+    else:
+        raise ValueError(op)
+    return ((arr, idx, x), (parr, pidx, _inf_pads(x)),
+            lambda o: o[:m])
+
+
+def _fifo_step(ops):
+    """The FIFO recurrence as a scan step; masked rows leave the server
+    as they found it."""
+    xp = ops.xp
+
+    def step(free, x):
+        end = xp.maximum(x["a"], free) + x["h"]
+        free = xp.where(x["valid"], end, free)
+        return free, {"end": end, "free": free}
+    return step
 
 
 @requires_jax
-@settings(max_examples=40, deadline=None)   # first call per pow2 bucket compiles
-@given(holds=st.lists(st.floats(min_value=0.0, max_value=5.0),
-                      min_size=1, max_size=33),
-       gaps=st.lists(st.floats(min_value=0.0, max_value=3.0),
-                     min_size=1, max_size=33),
-       carry=st.floats(min_value=0.0, max_value=20.0))
-def test_jax_fifo_scan_matches_numpy_1d(holds, gaps, carry):
-    """Sizes 1..33 sweep across pow2 pad boundaries, so this is also
-    the kernel-level pad-and-mask invariance check."""
-    n = min(len(holds), len(gaps))
-    a = np.cumsum(np.asarray(gaps[:n]))
-    h = np.asarray(holds[:n])
-    got = jax_engine._jax_fifo_scan(a, h, carry)
-    want = _fifo_scan(a, h, carry)
-    assert got.shape == want.shape
-    np.testing.assert_allclose(got, want, rtol=1e-12, atol=1e-12)
+@pytest.mark.parametrize("op", ["lexsort", "cumsum", "cummax",
+                                "seg_cummax", "at_set", "at_max", "scan"])
+@settings(max_examples=25, deadline=None)   # each new shape compiles
+@given(data=st.data())
+def test_device_ops_match_numpy_backend_with_inert_pads(op, data):
+    """Every op of the device program's jax backend computes what its
+    NumPy backend computes, and inert pads appended to the input (+inf
+    clocks, masked rows, dummy scatter slots) leave the real rows as the
+    NumPy backend computes them without pads."""
+    import jax.numpy as jnp
 
-
-@requires_jax
-@settings(max_examples=25, deadline=None)   # first call per pow2 bucket compiles
-@given(holds=st.lists(st.floats(min_value=0.0, max_value=5.0),
-                      min_size=1, max_size=20),
-       gaps=st.lists(st.floats(min_value=0.0, max_value=3.0),
-                     min_size=1, max_size=20),
-       scales=st.lists(st.floats(min_value=0.5, max_value=2.0),
-                       min_size=2, max_size=5),
-       carry=st.floats(min_value=0.0, max_value=10.0))
-def test_jax_fifo_scan_matches_numpy_lane_axis(holds, gaps, scales, carry):
-    n = min(len(holds), len(gaps))
-    sc = np.asarray(scales)
-    a = np.cumsum(np.asarray(gaps[:n]))[:, None] * sc[None, :]
-    h = np.asarray(holds[:n])[:, None] * sc[None, :]
-    got = jax_engine._jax_fifo_scan(a, h, carry * sc)
-    want = _fifo_scan(a, h, carry * sc)
-    np.testing.assert_allclose(got, want, rtol=1e-12, atol=1e-12)
-
-
-@requires_jax
-def test_jax_fifo_scan_broadcasts_scalar_hold_and_carry():
-    a = np.array([[0.0, 0.0], [1.0, 2.0], [1.5, 4.0]])
-    got = jax_engine._jax_fifo_scan(a, 0.5, 0.0)
-    want = _fifo_scan(a, np.full_like(a, 0.5), np.zeros(2))
-    np.testing.assert_allclose(got, want, rtol=1e-12)
-
-
-# -- pad-and-mask invariance -----------------------------------------------
-
-
-@requires_jax
-def test_kernel_pads_are_inert():
-    """Explicitly widening a kernel call with its documented pad values
-    leaves the real prefix bit-identical."""
-    K = jax_engine._kernels()
-    rng = np.random.default_rng(1)
-    a = np.sort(rng.uniform(0, 10, (8, 3)), axis=0)
-    h = rng.uniform(0, 1e-3, (8, 3))
-    c = np.zeros(3)
-    base = np.asarray(K.fifo_scan_lanes(a, h, c))
-    ap = np.vstack([a, np.full((8, 3), np.inf)])
-    hp = np.vstack([h, np.zeros((8, 3))])
-    wide = np.asarray(K.fifo_scan_lanes(ap, hp, c))[:8]
-    assert np.array_equal(base, wide)
-    # masked depart pops: consumed +inf pad rows never count
-    t = np.array([1.0, 3.0, 5.0, np.inf])
-    used = np.array([False, False, False, True])
-    cnt, last, used2 = K.pop_until(t, used, 4.0)
-    assert int(cnt) == 2 and float(last) == 3.0
-    assert np.asarray(used2).tolist() == [True, True, False, True]
-    t2 = np.concatenate([t, np.full(4, np.inf)])
-    u2 = np.concatenate([used, np.ones(4, dtype=bool)])
-    cnt2, last2, _ = K.pop_until(t2, u2, 4.0)
-    assert int(cnt2) == 2 and float(last2) == 3.0
-    assert float(K.next_drain(t, used)) == 1.0
+    from repro.compat import scoped_x64
+    from repro.core import jax_device_loop as dl
+    J, N = dl._jax_ops(), dl._NumpyOps
+    if op == "scan":
+        n = data.draw(st.integers(1, 12), label="n")
+        L = data.draw(st.integers(1, 3), label="lanes")
+        xs = {"a": np.sort(_draw_clocks(data, n, L), axis=0),
+              "h": _draw_clocks(data, n, L) / 50.0,
+              "valid": np.ones((n, 1), bool)}
+        pxs = {"a": _inf_pads(xs["a"]),
+               "h": np.concatenate([xs["h"], np.zeros((PADS, L))]),
+               "valid": np.concatenate([xs["valid"],
+                                        np.zeros((PADS, 1), bool)])}
+        free = _draw_clocks(data, 1, L)[0]
+        want_c, want = N.scan(_fifo_step(N), free, xs, n)
+        np_c, np_ys = N.scan(_fifo_step(N), free, pxs, n + PADS)
+        with scoped_x64():
+            got_c, got = J.scan(_fifo_step(J), jnp.asarray(free),
+                                {k: jnp.asarray(v) for k, v in pxs.items()},
+                                n + PADS)
+            got_c = np.asarray(got_c)
+            got = {k: np.asarray(v) for k, v in got.items()}
+        np.testing.assert_array_equal(got_c, np_c)
+        np.testing.assert_array_equal(got_c, want_c)
+        for k in want:
+            assert got[k].dtype == np.float64, k
+            np.testing.assert_array_equal(got[k], np_ys[k], err_msg=k)
+            np.testing.assert_array_equal(got[k][:n], want[k], err_msg=k)
+        return
+    args, padded, real = _op_case(op, data)
+    want = getattr(N, op)(*args)
+    np_padded = getattr(N, op)(*padded)
+    with scoped_x64():
+        got = np.asarray(getattr(J, op)(*(tuple(jnp.asarray(k) for k in a)
+                                          if isinstance(a, tuple)
+                                          else jnp.asarray(a)
+                                          for a in padded)))
+    assert got.dtype == np_padded.dtype or op == "lexsort", got.dtype
+    if op == "cumsum":
+        # a prefix sum re-associated by the tree scan: equal up to
+        # rounding
+        np.testing.assert_allclose(got, np_padded, rtol=1e-12, atol=1e-12)
+        np.testing.assert_allclose(real(got), want, rtol=1e-12, atol=1e-12)
+    else:
+        np.testing.assert_array_equal(got, np_padded)
+        np.testing.assert_array_equal(real(got), want)
 
 
 @requires_jax
 def test_added_seed_lane_never_perturbs_existing_lanes():
-    """Whole-run pad-and-mask invariance: stacking one more seed-lane
-    leaves the existing lanes' trajectories bit-identical (overflow
-    regime included, so the masked depart store and the admission scan
-    both face real flow-control traffic)."""
+    """Stacking one more seed-lane leaves the existing lanes'
+    trajectories bit-identical (overflow regime included, so the depart
+    heaps and the admission walk both face real flow-control traffic).
+    This is the engine ``engine="jax"`` runs wherever the device program
+    declines, as this overflow cell."""
     from repro.core.patterns import OVERFLOW_STRESS_DEFAULTS
     wl = get_workload("dstream")
     spec = ExperimentSpec(
         pattern="feedback", workload=wl, arch="dts", n_producers=2,
         n_consumers=2, total_messages=512,
-        params=SimParams(seed=0, engine="jax",
-                         queue_max_bytes=64 * wl.payload_bytes,
+        params=SimParams(seed=0, queue_max_bytes=64 * wl.payload_bytes,
                          **OVERFLOW_STRESS_DEFAULTS))
-    two = jax_engine.JaxStreamSim(spec, stack_seeds=[0, 7]).run_stacked()
-    three = jax_engine.JaxStreamSim(
-        spec, stack_seeds=[0, 7, 99]).run_stacked()
+    two = VectorizedStreamSim(spec, stack_seeds=[0, 7]).run_stacked()
+    three = VectorizedStreamSim(spec, stack_seeds=[0, 7, 99]).run_stacked()
     for i in range(2):
         assert np.array_equal(two[i].consume_times,
                               three[i].consume_times), i
@@ -156,17 +207,20 @@ def test_added_seed_lane_never_perturbs_existing_lanes():
 def test_x64_time_arithmetic_roundtrip_without_global_flip():
     import jax
     import jax.numpy as jnp
+
+    from repro.compat import scoped_x64
+    from repro.core import jax_device_loop as dl
     global_x64 = jax.config.jax_enable_x64
     # large-magnitude clocks: a 1e-4 s hold on a 1e3 s base survives
     # only in float64 (f32 resolution at 1e3 is ~6e-5 and accumulates)
-    a = 1e3 + np.cumsum(np.full(32, 1e-4))
-    h = np.full(32, 1e-4)
-    got = jax_engine._jax_fifo_scan(a, h, 1e3)
+    x = np.concatenate([[1e3], np.full(32, 1e-4)])
+    with scoped_x64():
+        got = np.asarray(dl._jax_ops().cumsum(jnp.asarray(x)))
     assert got.dtype == np.float64
-    np.testing.assert_allclose(got, _fifo_scan(a, h, 1e3), rtol=0,
+    np.testing.assert_allclose(got, dl._NumpyOps.cumsum(x), rtol=0,
                                atol=1e-12)
     assert np.all(np.diff(got) > 0)          # holds never vanish
-    # the engine's x64 is scoped per call: the process-global default
+    # the device program's x64 is scoped: the process-global default
     # (the model/kernel stack's x32) is untouched
     assert jax.config.jax_enable_x64 == global_x64
     if not global_x64:
@@ -198,22 +252,10 @@ def test_run_many_falls_back_and_records_vectorized(monkeypatch):
     assert summarize(rs[0]).path == "vectorized"
 
 
-@requires_jax
-def test_jax_matches_vectorized_bitwise_on_smoke_cell():
-    """The jax engine is a kernel-layer port of the same arithmetic:
-    on a smoke cell the two engines agree to the last bit."""
-    j = run_many([_spec(0, "jax")])[0]
-    v = run_many([_spec(0, "vectorized")])[0]
-    np.testing.assert_allclose(j.consume_times, v.consume_times,
-                               rtol=1e-9)
-    np.testing.assert_allclose(j.rtts, v.rtts, rtol=1e-9)
-    assert j.rejected_publishes == v.rejected_publishes
-    assert j.blocked_confirms == v.blocked_confirms
-
-
 # -- whole-run device loop --------------------------------------------------
 # The wave device program (repro.core.jax_device_loop): one lax.scan
-# over message generations replaces the per-cohort Python event loop.
+# over message generations replaces the vectorized engine's Python cohort
+# loop.
 # Contracts under test: the jit program computes exactly what its
 # NumPy-mirror step loop computes; the pow2 cell-axis padding is inert;
 # lane 0 of a stacked run is bit-identical to the solo device run; and
@@ -486,45 +528,74 @@ def test_device_loop_receives_every_reply(arch, nc, msgs, device):
 
 @requires_jax
 def test_device_loop_dispatch_requires_opt_in():
-    """jax_device_loop=None (the default) keeps the cohort-loop jax
-    engine; only the explicit True flag dispatches the wave program."""
+    """jax_device_loop=None (the default) runs the vectorized engine;
+    only the explicit True flag dispatches the wave program."""
     from repro.core import jax_device_loop as dl
     sim = _dl_sim(0)
     ok, why = dl._device_loop_ok(sim)
     assert ok, why
     j_default = run_many([_dl_spec(0, device=None)])[0]
     v = run_many([_dl_spec(0, engine="vectorized", device=None)])[0]
-    # the cohort jax engine is a kernel port: bitwise-close to
-    # vectorized, which the wave program (different schedule) is not
-    np.testing.assert_allclose(j_default.consume_times,
-                               v.consume_times, rtol=1e-9)
+    # off the device program the jax engine is the vectorized engine,
+    # bit for bit, which the wave program (different schedule) is not
+    assert j_default.path == "vectorized"
+    np.testing.assert_array_equal(j_default.consume_times,
+                                  v.consume_times)
 
 
 @requires_jax
 def test_recorded_path_tells_device_program_from_cohort_path():
     """``RunResult.path`` / ``Summary.path`` name the path that ran, and
     the campaign cache key carries it, so the device program and the
-    per-cohort jax path never share a cached row."""
+    vectorized engine never share a cached row; a jax cell off the
+    device program says ``"vectorized"``."""
     from repro.core.campaign import resolved_path
-    dev, coh = (_dl_spec(0, pattern="work_sharing", msgs=64, device=d)
+    dev, vec = (_dl_spec(0, pattern="work_sharing", msgs=64, device=d)
                 for d in (True, None))
     assert resolved_path(dev) == "jax-device"
-    assert resolved_path(coh) == "jax-cohort"
-    rd, rc = run_many([dev])[0], run_many([coh])[0]
+    assert resolved_path(vec) == "vectorized"
+    rd, rv = run_many([dev])[0], run_many([vec])[0]
     assert (rd.path, summarize(rd).path) == ("jax-device", "jax-device")
-    assert (rc.path, summarize(rc).path) == ("jax-cohort", "jax-cohort")
-    assert summarize(rd).engine == summarize(rc).engine == "jax"
-    # a device request the regime gate declines runs, and says, cohort
+    assert (rv.path, summarize(rv).path) == ("vectorized", "vectorized")
+    assert summarize(rd).engine == summarize(rv).engine == "jax"
+    # a device request the regime gate declines runs, and says, vectorized
     gated = _dl_spec(0, pattern="broadcast_gather", msgs=64, npr=1)
-    assert resolved_path(gated) == "jax-cohort"
-    assert run_many([gated])[0].path == "jax-cohort"
+    assert resolved_path(gated) == "vectorized"
+    assert run_many([gated])[0].path == "vectorized"
+
+
+@requires_jax
+def test_declined_device_request_stacks_as_vectorized_group():
+    """A stacked jax request the regime gate declines runs as a
+    vectorized group: 17 seeds chunk at the vectorized engine's 16 lanes
+    (a stack of 16 and a solo run, not one stack of 17) and return what
+    ``engine="vectorized"`` returns, bit for bit."""
+    from repro.core import spans
+    seeds = range(17)
+    # a 4 x 2 feedback fleet: declined by the fleet clause
+    jax_specs = [_dl_spec(s, npr=4, nc=2, msgs=64) for s in seeds]
+    assert "4 x 2 fleet" in jax_engine.JaxStreamSim(
+        jax_specs[0]).device_loop_why
+    with spans.recording() as rec:
+        got = run_many(jax_specs)
+    # the group's probe and one stacked engine: the 17th seed runs solo
+    assert rec.counters["streamsim.engines"] == 2
+    assert "streamsim.programs" not in rec.counters
+    want = run_many([_dl_spec(s, npr=4, nc=2, msgs=64, engine="vectorized",
+                              device=None) for s in seeds])
+    for g, w in zip(got, want):
+        assert g.path == w.path == "vectorized"
+        assert g.spec.params.engine == "jax"
+        for f in ("consume_times", "rtts", "publish_starts"):
+            np.testing.assert_array_equal(getattr(g, f), getattr(w, f),
+                                          err_msg=f)
 
 
 def test_device_loop_regime_gate():
     """The regime gate rejects every shape class whose wave schedule
     measurably diverges from the cohort loop, each with a reason naming
-    the offending quantity (gated cells dispatch to the per-cohort
-    path; see test_device_loop_dispatch_requires_opt_in for the
+    the offending quantity (gated cells run on the vectorized engine;
+    see test_declined_device_request_stacks_as_vectorized_group for the
     dispatch side)."""
     from repro.core import jax_device_loop as dl
 

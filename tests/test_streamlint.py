@@ -22,6 +22,7 @@ from tools.streamlint import run_analysis  # noqa: E402
 HEAP = "src/repro/core/simulator.py"
 VEC = "src/repro/core/vectorized.py"
 JAX = "src/repro/core/jax_engine.py"
+JAX_PROGRAM = "src/repro/core/jax_device_loop.py"
 CAMPAIGN = "src/repro/core/campaign.py"
 PARITY = "src/repro/core/parity.py"
 DOC = "docs/engines.md"
@@ -252,12 +253,12 @@ def test_sl302_host_sync_in_jitted_kernel(tmp_path):
         def kernel(x):
             return float(x.sum()) + np.asarray(x)[0]
     """
-    diags = lint(tmp_path, {JAX: bad}, only={"SL302"})
+    diags = lint(tmp_path, {JAX_PROGRAM: bad}, only={"SL302"})
     assert rules_of(diags) == {"SL302"}
     assert len(diags) == 2  # float() and np.asarray()
     # the same code outside a jitted def is host code: fine
     good = bad.replace("@jax.jit\n        ", "")
-    assert not lint(tmp_path, {JAX: good}, only={"SL302"})
+    assert not lint(tmp_path, {JAX_PROGRAM: good}, only={"SL302"})
 
 
 def test_sl302_wrapped_name_counts_as_jitted(tmp_path):
@@ -273,8 +274,26 @@ def test_sl302_wrapped_name_counts_as_jitted(tmp_path):
 
         scan = x64(jax.vmap(fifo1))
     """
-    diags = lint(tmp_path, {JAX: bad}, only={"SL302"})
+    diags = lint(tmp_path, {JAX_PROGRAM: bad}, only={"SL302"})
     assert rules_of(diags) == {"SL302"}
+
+
+def test_sl302_conditional_transform_counts_as_jitted(tmp_path):
+    # jax.jit(jax.vmap(program) if n else program) marks program jitted,
+    # as _compiled_program wraps the device program
+    bad = """
+        import jax
+
+        def compiled(n):
+            def program(x):
+                return x.block_until_ready()
+            return jax.jit(jax.vmap(program) if n else program)
+    """
+    diags = lint(tmp_path, {JAX_PROGRAM: bad}, only={"SL302"})
+    assert rules_of(diags) == {"SL302"}
+    # the engine module is not scanned for jitted code
+    assert not lint(tmp_path, {JAX: bad, JAX_PROGRAM: "x = 1\n"},
+                    only={"SL302"})
 
 
 def test_sl303_data_dependent_branch(tmp_path):
@@ -289,7 +308,7 @@ def test_sl303_data_dependent_branch(tmp_path):
                 x = x - 1
             return x
     """
-    diags = lint(tmp_path, {JAX: bad}, only={"SL303"})
+    diags = lint(tmp_path, {JAX_PROGRAM: bad}, only={"SL303"})
     assert rules_of(diags) == {"SL303"}
     assert len(diags) == 2  # the if and the while
     # shape/ndim dispatch resolves at trace time: allowed
@@ -304,7 +323,7 @@ def test_sl303_data_dependent_branch(tmp_path):
                 return x + m
             return x * m
     """
-    assert not lint(tmp_path, {JAX: good}, only={"SL303"})
+    assert not lint(tmp_path, {JAX_PROGRAM: good}, only={"SL303"})
 
 
 # -- SL4xx: determinism ----------------------------------------------------

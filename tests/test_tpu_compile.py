@@ -19,7 +19,6 @@ import pytest
 
 from repro.compat import scoped_x64
 from repro.core import jax_device_loop as dl
-from repro.core import jax_engine
 from repro.core.simulator import ExperimentSpec, SimParams
 from repro.core.vectorized import VectorizedStreamSim
 from repro.core.workloads import get_workload
@@ -59,56 +58,34 @@ def _compile(fn, args, sharding):
         return jax.jit(fn).lower(*_shapes(args, sharding)).compile()
 
 
-f8, i8 = np.zeros((), np.float64), np.zeros((), np.int64)
+#: every (pattern, arch) pair the regime gate admits, each at the small
+#: shape the device tests use: work sharing on a 4 x 2 fleet with 64
+#: msgs, feedback on the smallest fleet it takes, 32 x 32 with 32
+#: msgs/producer under a window that never binds
+DEVICE_CELLS = [(pattern, arch) for pattern in ("work_sharing", "feedback")
+                for arch in ("dts", "prs-haproxy", "mss")]
 
 
-def _a(*shape, like=f8):
-    return np.zeros(shape, like.dtype)
-
-
-def _b(*shape):
-    return np.zeros(shape, bool)
-
-
-#: every kernel of jax_engine._kernels() at small pow2 shapes: cohort
-#: 16, lanes 4, queues 2, consumers 4
-KERNEL_ARGS = {
-    "fifo_scan_1d": (_a(16), _a(16), f8),
-    "fifo_scan_lanes": (_a(16, 4), _a(16, 4), _a(4)),
-    "fifo_scan_cells": (_a(4, 16, 4), _a(4, 16, 4), _a(4, 4)),
-    "pop_until": (_a(16), _b(16), f8),
-    "pop_k": (_a(16), _b(16), i8),
-    "next_drain": (_a(16), _b(16)),
-    "admit_walk": (_a(16), _b(16), _a(2, 8), _a(2, like=i8),
-                   _a(2, like=i8), _a(2, like=i8), _a(2, like=i8)),
-    "rr_assign": (_a(16, 4), _a(4, like=i8), _a(4, like=i8),
-                  _a(4, 8, 4), i8),
-    "assign_chunk": (_a(8, 4), _a(8), _b(8), _a(4, 4), _a(4, like=i8),
-                     _a(4, like=i8), _a(4, 16, 4), i8),
-}
-
-
-@pytest.mark.parametrize("name", sorted(KERNEL_ARGS))
-def test_cohort_kernel_compiles_for_v5e(name, one_chip):
-    kernel = getattr(jax_engine._kernels(), name)
-    compiled = _compile(kernel.__wrapped__, KERNEL_ARGS[name], one_chip)
-    assert "tpu_custom_call" not in compiled.as_text()
-
-
-def test_device_program_compiles_for_v5e(one_chip):
-    """The whole-run device program at a small feedback cell the regime
-    gate admits, so all four legs (publish, delivery, reply-publish,
-    reply-delivery), both pumps and the pending-reply buffer are in
-    it."""
+@pytest.mark.parametrize("pattern,arch", DEVICE_CELLS)
+def test_device_program_compiles_for_v5e(pattern, arch, one_chip):
+    """The whole-run device program at a small cell the regime gate
+    admits, for each pattern and architecture it takes; the feedback
+    cells carry all four legs (publish, delivery, reply-publish,
+    reply-delivery), both pumps and the pending-reply buffer."""
+    fb = pattern == "feedback"
+    n_producers, n_consumers = (32, 32) if fb else (4, 2)
     spec = ExperimentSpec(
-        pattern="feedback", workload=get_workload("dstream"), arch="dts",
-        n_producers=32, n_consumers=32, total_messages=1024,
-        params=SimParams(seed=0, confirm_window=128))
+        pattern=pattern, workload=get_workload("dstream"), arch=arch,
+        n_producers=n_producers, n_consumers=n_consumers,
+        total_messages=1024 if fb else 64,
+        params=SimParams(seed=0, confirm_window=128 if fb else 32))
     sim = VectorizedStreamSim(spec)
     ok, why = dl._device_loop_ok(sim)
     assert ok, why
     ws = dl.build_static(sim)
-    assert ws.meta["feedback"] and ws.meta["S_rp"] and ws.meta["S_rd"]
+    if fb:
+        assert ws.meta["S_rp"] and ws.meta["S_rd"]
+    assert bool(ws.meta["feedback"]) == fb
     fn = dl._compiled_program(ws.signature(), dl._meta_key(ws.meta), False)
     compiled = _compile(fn, (ws.xs, ws.inv, dl.draw_jitter(sim, ws)),
                         one_chip)

@@ -125,6 +125,9 @@ class Config:
     heap_engine: str = "src/repro/core/simulator.py"
     vectorized_engine: str = "src/repro/core/vectorized.py"
     jax_engine: str = "src/repro/core/jax_engine.py"
+    #: the module whose jitted code SL302/SL303 scan (the whole-run
+    #: device program)
+    jax_program: str = "src/repro/core/jax_device_loop.py"
     campaign: str = "src/repro/core/campaign.py"
     bench_common: str = "benchmarks/common.py"
     parity_constants: str = "src/repro/core/parity.py"
@@ -135,7 +138,7 @@ class Config:
         "tests/test_chaos.py")
     #: path prefixes whose modules count as deterministic engine paths
     determinism_scope: tuple[str, ...] = ("src/repro/core/",)
-    #: names that wrap a function into a jitted kernel in the jax module
+    #: names that wrap a function into a jitted kernel in jax_program
     jit_wrappers: tuple[str, ...] = ("x64", "jit")
 
 

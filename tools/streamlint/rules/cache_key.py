@@ -18,8 +18,8 @@ cache entry — silent result poisoning across resumes.
 * SL204 — a cache-key builder that keys on the *requested* engine
   instead of the *resolved* execution path.  ``run_many`` downgrades
   unsupported jax cells to the vectorized engine, and the device
-  program's regime gate sends declined cells to the per-cohort jax
-  path; a key built before that resolution caches one path's numbers
+  program's regime gate sends declined cells there too; a key built
+  before that resolution caches one path's numbers
   under another's name (poisoning later runs of the other path) and
   forks them from the identical cell.  ``campaign.cell_key`` and
   ``benchmarks.common.resolve_path`` must route through
@@ -136,8 +136,8 @@ def sl204(project: Project,
                 rule="SL204", file=camp.path, line=func.lineno,
                 message=("cell_key never calls resolved_path: a jax "
                          "cell the run_many fallback downgrades to "
-                         "vectorized (or the device-program gate sends "
-                         "to the per-cohort path) would be cached under "
+                         "vectorized (or the device-program gate "
+                         "declines) would be cached under "
                          "the path it did not run, poisoning later "
                          "runs of that path"))
     bench = project.file(project.config.bench_common)

@@ -1,10 +1,10 @@
-"""SL3xx — jit/x64 purity in the jax engine.
+"""SL3xx — jit/x64 purity in the jax device program.
 
 The jax backend owes its parity story to two disciplines: x64 is
-enabled *scoped* (the ``repro.compat.scoped_x64`` context inside the
-``x64`` kernel wrapper), never via the process-global config flip that would silently
-retrace every other jax user in the process; and the jitted kernel
-seams stay pure — no host syncs, no Python control flow on traced
+enabled *scoped* (the ``repro.compat.scoped_x64`` context around every
+program call), never via the process-global config flip that would
+silently retrace every other jax user in the process; and the jitted
+code stays pure — no host syncs, no Python control flow on traced
 values (shape/ndim dispatch is fine: it is resolved at trace time).
 
 * SL301 — global x64 flip: ``jax.config.update("jax_enable_x64", …)``
@@ -17,10 +17,12 @@ values (shape/ndim dispatch is fine: it is resolved at trace time).
   ``while``/``assert`` whose test involves anything beyond shapes,
   dtypes, ``len()``/``isinstance()`` and constants.
 
-"Jitted kernel" means, within the configured jax-engine module: any
-def decorated with a jit wrapper (``x64``, ``jit``, ``jax.jit``), any
-def whose name is passed into such a wrapper (including through
-``jax.vmap(...)``), and every def nested inside one.
+"Jitted kernel" means, within the configured device-program module
+(``Config.jax_program``): any def decorated with a jit wrapper
+(``x64``, ``jit``, ``jax.jit``), any def whose name is passed into such
+a wrapper (including through ``jax.vmap(...)`` or either branch of a
+conditional expression), and every def nested inside one.  The check
+reads those defs' own bodies, not the functions they call.
 """
 
 from __future__ import annotations
@@ -76,7 +78,8 @@ def _wrapper_hit(node: ast.AST, wrappers: tuple[str, ...]) -> bool:
 def _names_fed_to_wrappers(tree: ast.Module,
                            wrappers: tuple[str, ...]) -> set[str]:
     """Function names passed into jit wrappers, unwrapping nested
-    transforms (``x64(jax.vmap(fifo1))`` feeds ``fifo1``)."""
+    transforms (``x64(jax.vmap(fifo1))`` feeds ``fifo1``) and
+    conditional expressions (``jax.jit(jax.vmap(f) if n else f)``)."""
     roots: set[str] = set()
 
     def harvest(arg: ast.AST) -> None:
@@ -85,6 +88,9 @@ def _names_fed_to_wrappers(tree: ast.Module,
         elif isinstance(arg, ast.Call):
             for sub in arg.args:
                 harvest(sub)
+        elif isinstance(arg, ast.IfExp):
+            harvest(arg.body)
+            harvest(arg.orelse)
 
     for node in ast.walk(tree):
         if isinstance(node, ast.Call) and _wrapper_hit(node.func, wrappers):
@@ -187,8 +193,8 @@ def sl303(project: Project,
 def _kernel_findings(project: Project, scanned: list[SourceFile],
                      rule_id: str) -> Iterator[Diagnostic]:
     cfg = project.config
-    sf = next((s for s in scanned if s.path == cfg.jax_engine), None) \
-        or project.file(cfg.jax_engine)
+    sf = next((s for s in scanned if s.path == cfg.jax_program), None) \
+        or project.file(cfg.jax_program)
     if sf is None:
         return
     seen: set[tuple[str, int, str]] = set()
